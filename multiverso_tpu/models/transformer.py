@@ -157,7 +157,8 @@ def _attention(q, k, v, n_heads: int, impl: str = "reference"):
             # Few-program calls (the standalone 8-program sweep ran
             # 0.44-0.63x below seq 1536, docs/TPU_VALIDATE.json) keep the
             # 1536 default; the 64-program gate is the measured boundary's
-            # conservative side.
+            # conservative side. (All measured on an earlier device
+            # set-up; crossover to be re-measured by a benchmark PR.)
             fn = partial(fn, min_flash_seq=512)
     elif impl == "flash_force":
         from ..ops.flash_attention import flash_attention as fn
@@ -240,7 +241,7 @@ def loss_fn(cfg: TransformerConfig, params: Dict[str, Any],
 # leaks into a response. Cache layout: [n_layers, B, max_seq, d_model],
 # pre-head-split (the head split is a free reshape).
 
-_NEG_INF = jnp.float32(-1e30)
+_NEG_INF = -1e30
 
 
 def _cached_attention(q, k_cache, v_cache, n_heads: int, pos) -> jax.Array:

@@ -713,9 +713,11 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, precision, res, g):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-# Measured on-chip crossover (docs/TPU_VALIDATE.json): XLA-fused reference
-# attention wins below ~1.5k sequence, the Pallas kernel above. Override by
-# passing min_flash_seq to best_attention (or monkeypatching this).
+# Measured on-chip crossover (docs/TPU_VALIDATE.json — measured on an
+# earlier device set-up; crossover to be re-measured by a benchmark PR):
+# XLA-fused reference attention wins below ~1.5k sequence, the Pallas
+# kernel above. Override by passing min_flash_seq to best_attention (or
+# monkeypatching this).
 FLASH_CROSSOVER_SEQ = 1536
 
 

@@ -32,8 +32,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-from .._compat import shard_map
-
 EXPERT_AXIS = "expert"
 
 
@@ -113,7 +111,7 @@ def moe_apply(
         lambda leaf: P(axis, *(None,) * (np.ndim(leaf) - 1)), expert_params)
     x_spec = P(axis, *(None,) * (x.ndim - 1))
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_spec, P(), x_spec),
              out_specs=(x_spec, P()),
              check_vma=False)

@@ -26,8 +26,6 @@ import numpy as np
 from ..topology import SEQ_AXIS
 from .flash_attention import flash_attention_partial, merge_partials
 
-from .._compat import shard_map
-
 from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30
@@ -130,7 +128,7 @@ def ring_attention(
         return (acc / denom).astype(q_blk.dtype), lse
 
     if impl == "xla":
-        @partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                  out_specs=spec, check_vma=False)
         def _ring(q_blk, k_blk, v_blk):
             my_idx = jax.lax.axis_index(axis)
@@ -148,7 +146,7 @@ def ring_attention(
         return _ring_pallas_fwd(q, k, v)[0]
 
     def _ring_pallas_fwd(q, k, v):
-        @partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                  out_specs=(spec, lse_spec), check_vma=False)
         def _fwd(q_blk, k_blk, v_blk):
             my_idx = jax.lax.axis_index(axis)
@@ -160,7 +158,7 @@ def ring_attention(
     def _ring_pallas_bwd(res, g):
         q, k, v, out, lse = res
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(spec, spec, spec, spec, spec, lse_spec),
                  out_specs=(spec, spec, spec), check_vma=False)
         def _bwd(q_blk, k_blk, v_blk, out_blk, g_blk, lse_blk):
@@ -257,7 +255,7 @@ def ring_prefill_attention(q, kc, vc, n_heads: int, offset, mesh,
     dh = D // n_heads
     perm = [(i, (i + 1) % n) for i in range(n)]
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis, None), P(axis, None), P(axis, None), P()),
              out_specs=P(axis, None), check_vma=False)
     def _ring(q_blk, k_blk, v_blk, off):

@@ -31,7 +31,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-from .._compat import shard_map
 from ..topology import SEQ_AXIS
 from .ring_attention import _prefix_chunk_attn, reference_attention
 
@@ -81,7 +80,7 @@ def ulysses_attention(
     else:
         _local_attn = reference_attention
 
-    @partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
              out_specs=spec, check_vma=False)
     def _ulysses(q_blk, k_blk, v_blk):
         # [seq/S, H, d] -> [seq, H/S, d]: gather the full sequence for a
@@ -131,7 +130,7 @@ def ulysses_prefill_attention(q, kc, vc, n_heads: int, offset, mesh,
     hl = n_heads // n
     dh = D // n_heads
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis, None), P(None, axis), P(None, axis), P()),
              out_specs=P(axis, None), check_vma=False)
     def _ulysses_sp(q_blk, k_blk, v_blk, off):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..topology import WORKER_AXIS
-from .collectives import _mesh, shard_map
+from .collectives import _mesh
 
 from jax.sharding import PartitionSpec as P
 
@@ -138,7 +138,7 @@ class AllreduceEngine:
         (``AllreduceEngine::Allgather``, Bruck)."""
         spec = P(self.axis, *(None,) * (np.ndim(x) - 1))
 
-        @partial(shard_map, mesh=self.mesh, in_specs=(spec,),
+        @partial(jax.shard_map, mesh=self.mesh, in_specs=(spec,),
                  out_specs=P(*(None,) * np.ndim(x)), check_vma=False)
         def _ag(shard):
             return self._bruck_gather(shard)
@@ -156,7 +156,7 @@ class AllreduceEngine:
         in_spec = P(self.axis, *(None,) * (np.ndim(x) - 1))
         out_spec = P(self.axis, *(None,) * (np.ndim(x) - 2))
 
-        @partial(shard_map, mesh=self.mesh, in_specs=(in_spec,),
+        @partial(jax.shard_map, mesh=self.mesh, in_specs=(in_spec,),
                  out_specs=out_spec, check_vma=False)
         def _rs(shard):
             return self._reduce_scatter_shard(shard[0])
@@ -181,7 +181,7 @@ class AllreduceEngine:
         spec = P(self.axis, *(None,) * (np.ndim(x) - 1))
 
         if payload < self.SMALL_PAYLOAD_BYTES or k < n:
-            @partial(shard_map, mesh=self.mesh, in_specs=(spec,),
+            @partial(jax.shard_map, mesh=self.mesh, in_specs=(spec,),
                      out_specs=spec, check_vma=False)
             def _ar_small(shard):
                 gathered = self._bruck_gather(shard)  # [n, k...]
@@ -189,8 +189,8 @@ class AllreduceEngine:
 
             return _ar_small(x)
 
-        @partial(shard_map, mesh=self.mesh, in_specs=(spec,), out_specs=spec,
-                 check_vma=False)
+        @partial(jax.shard_map, mesh=self.mesh, in_specs=(spec,),
+                 out_specs=spec, check_vma=False)
         def _ar(shard):
             # Ravel so the scatter dimension is the full element count (the
             # trailing dims of a multi-dim payload need not divide n), and

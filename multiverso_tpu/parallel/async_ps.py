@@ -150,14 +150,10 @@ def _deserialize(data: bytes):
 
 
 def _kv_get_int(client, key: str, default: int = 0) -> int:
-    """Best-effort int read covering both KV client generations:
-    ``key_value_try_get`` is absent on jax<=0.4.x's
-    DistributedRuntimeClient (PR 12 finding), so fall back to a short
-    blocking get."""
+    """Best-effort int read: an absent key or a transport error reads
+    as ``default``."""
     try:
-        if hasattr(client, "key_value_try_get"):
-            return int(str(client.key_value_try_get(key)))
-        return int(str(client.blocking_key_value_get(key, 200)))
+        return int(str(client.key_value_try_get(key)))
     except Exception:
         return default
 
@@ -178,33 +174,14 @@ def claim_epoch(client, key: str = "mvps/epoch") -> int:
     defaulting to 0 would rewind the key and turn the legitimately
     restarted trainer into a permanent zombie (every publish below the
     fleet's fence). Only a genuinely ABSENT key reads as 0."""
-    if hasattr(client, "key_value_try_get"):
-        try:
-            cur = int(str(client.key_value_try_get(key)))
-        except Exception as exc:
-            if "NOT_FOUND" not in str(exc) \
-                    and not isinstance(exc, KeyError):
-                Log.fatal(f"claim_epoch: cannot read fence key {key!r} "
-                          f"({exc}) — claiming blindly could regress "
-                          f"the epoch and fence out this trainer")
-            cur = 0
-    else:
-        # jax<=0.4.x clients: no try_get — a short blocking get whose
-        # timeout means "absent" (the first claim). The real
-        # DistributedRuntimeClient raises XlaRuntimeError
-        # ("DEADLINE_EXCEEDED...") rather than TimeoutError, so match
-        # the timeout by MESSAGE too; anything else still fails loudly.
-        try:
-            cur = int(str(client.blocking_key_value_get(key, 2_000)))
-        except Exception as exc:
-            msg = str(exc)
-            if (isinstance(exc, TimeoutError) or "DEADLINE" in msg
-                    or "NOT_FOUND" in msg):
-                cur = 0
-            else:
-                Log.fatal(f"claim_epoch: cannot read fence key {key!r} "
-                          f"({exc}) — claiming blindly could regress "
-                          f"the epoch and fence out this trainer")
+    try:
+        cur = int(str(client.key_value_try_get(key)))
+    except Exception as exc:
+        if "NOT_FOUND" not in str(exc) and not isinstance(exc, KeyError):
+            Log.fatal(f"claim_epoch: cannot read fence key {key!r} "
+                      f"({exc}) — claiming blindly could regress "
+                      f"the epoch and fence out this trainer")
+        cur = 0
     nxt = cur + 1
     client.key_value_set(key, str(nxt), allow_overwrite=True)
     return nxt

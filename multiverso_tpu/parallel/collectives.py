@@ -30,8 +30,6 @@ from ..topology import WORKER_AXIS
 
 from jax.sharding import PartitionSpec as P
 
-from .._compat import shard_map
-
 
 def _mesh(mesh=None):
     return mesh if mesh is not None else Session.get().mesh
@@ -46,7 +44,7 @@ def allreduce(x, axis: str = WORKER_AXIS, mesh=None, mean: bool = False):
     mesh = _mesh(mesh)
     spec = P(axis, *(None,) * (np.ndim(x) - 1))
 
-    @partial(shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec,
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec,
              check_vma=False)
     def _reduce(shard):
         total = jax.lax.psum(shard, axis)
@@ -65,7 +63,8 @@ def allreduce_replicated(x, axis: str = WORKER_AXIS, mesh=None, mean: bool = Fal
     other = tuple(a for a in all_axes if a != axis)
     spec = P()
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(axis, *(None,) * np.ndim(x)),),
+    @partial(jax.shard_map, mesh=mesh,
+             in_specs=(P(axis, *(None,) * np.ndim(x)),),
              out_specs=spec, check_vma=False)
     def _reduce(shard):
         total = jax.lax.psum(shard[0], axis)
@@ -83,7 +82,7 @@ def all_gather(x, axis: str = WORKER_AXIS, mesh=None):
     mesh = _mesh(mesh)
     spec = P(axis, *(None,) * (np.ndim(x) - 1))
 
-    @partial(shard_map, mesh=mesh, in_specs=(spec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
              out_specs=P(*(None,) * np.ndim(x)), check_vma=False)
     def _gather(shard):
         return jax.lax.all_gather(shard, axis, axis=0, tiled=True)
@@ -106,7 +105,7 @@ def reduce_scatter(x, axis: str = WORKER_AXIS, mesh=None):
     in_spec = P(axis, *(None,) * (np.ndim(x) - 1))
     out_spec = P(axis, *(None,) * (np.ndim(x) - 2))
 
-    @partial(shard_map, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
+    @partial(jax.shard_map, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
              check_vma=False)
     def _rs(shard):
         return jax.lax.psum_scatter(shard[0], axis, scatter_dimension=0,
@@ -123,7 +122,7 @@ def ring_shift(x, axis: str, mesh=None, shift: int = 1):
     spec = P(axis, *(None,) * (np.ndim(x) - 1))
     perm = [(i, (i + shift) % n) for i in range(n)]
 
-    @partial(shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec,
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec,
              check_vma=False)
     def _shift(shard):
         return jax.lax.ppermute(shard, axis, perm)

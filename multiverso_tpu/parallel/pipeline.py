@@ -32,8 +32,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-from .._compat import shard_map
-
 STAGE_AXIS = "stage"
 
 
@@ -87,7 +85,7 @@ def pipeline_apply(
     param_spec = jax.tree.map(
         lambda leaf: P(axis, *(None,) * (np.ndim(leaf) - 1)), params)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_spec, P()), out_specs=P(),
              check_vma=False)
     def _pipelined(p_shard, xs_rep):
@@ -196,7 +194,7 @@ def pipeline_value_and_grad(
     # t = (2S - 1 - 0) + (n_micro - 1) = n_micro + 2S - 2, inclusive
     n_ticks = n_micro + 2 * n_stages - 1
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_spec, P(), P()),
              out_specs=(P(), param_spec),
              check_vma=False)

@@ -12,6 +12,7 @@ and lifecycle (init / barrier / shutdown with a dashboard dump,
 
 from __future__ import annotations
 
+import os
 import threading
 from .analysis import lockwatch
 from typing import Any, Dict, List, Optional, Sequence
@@ -25,6 +26,26 @@ from .log import Log, LogLevel
 _ROLE_NONE, _ROLE_WORKER, _ROLE_SERVER, _ROLE_ALL = 0, 1, 2, 3
 _ROLES = {"none": _ROLE_NONE, "worker": _ROLE_WORKER,
           "server": _ROLE_SERVER, "default": _ROLE_ALL, "all": _ROLE_ALL}
+
+# The persistent compile cache lives beside the package unless the
+# environment places it: the directory is part of the cache key, so it
+# must be the same path in every process and every run of one checkout.
+_JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def _place_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on before the first
+    compile and return its directory. ``JAX_COMPILATION_CACHE_DIR``,
+    where set, is JAX's own to read and nothing is set in code."""
+    import jax
+
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", _JAX_CACHE_DIR)
+    return _JAX_CACHE_DIR
 
 
 class Session:
@@ -44,6 +65,7 @@ class Session:
         self.failure_detector: Optional[Any] = None  # -failure_timeout_s
         self.metrics_exporter: Optional[Any] = None  # -metrics_jsonl
         self.obs_agent: Optional[Any] = None  # -obs_plane fleet agent
+        self.compile_cache_dir: Optional[str] = None  # set by start()
         # stop() handshake: the claiming caller's completion event +
         # thread id, so a concurrent stop() can wait for the teardown
         # to finish without wedging the Session lock behind it
@@ -87,6 +109,7 @@ class Session:
             if self.started:
                 return rest
             self.role = _ROLES.get(config.get_flag("ps_role"), _ROLE_ALL)
+            self.compile_cache_dir = _place_compile_cache()
             self.topo = topology.discover()
             if self.topo.num_workers % self.topo.size != 0:
                 Log.fatal(
@@ -207,7 +230,9 @@ class Session:
                 self.started = False
                 topo, self.topo = self.topo, None
                 servers, self.servers = self.servers, []
-                tables, self.tables = self.tables, []
+                # the registry stays readable through the teardown: the
+                # bus drain applies in-flight remote deltas by table id
+                tables = self.tables
                 detector, self.failure_detector = self.failure_detector, None
                 bus, self.async_bus = self.async_bus, None
                 wal, self.wal = self.wal, None
@@ -221,6 +246,8 @@ class Session:
             self._teardown(topo, servers, tables, detector, bus, exporter,
                            obs, wal)
         finally:
+            with self._lock:
+                self.tables = []
             done.set()
 
     def _teardown(self, topo, servers, tables, detector, bus,
@@ -290,7 +317,7 @@ class Session:
                 flush()
         if wal is not None:
             # after the table flushes: no apply path can append anymore
-            # (the registry was emptied when the state was claimed)
+            # (the bus has stopped)
             wal.close()
         if exporter is not None:
             # final report: the shutdown snapshot lands in the JSONL

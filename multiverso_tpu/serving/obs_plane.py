@@ -76,6 +76,7 @@ from ..dashboard import (BUCKET_REL_ERROR, Dashboard, bucket_breach_frac,
                          bucket_percentile, merge_buckets,
                          render_prometheus, snapshot_deltas)
 from ..log import Log
+from ..parallel.async_ps import _kv_get_int
 
 WIRE_VERSION = 1
 
@@ -956,21 +957,8 @@ class ObsAgent:
         self.reports += 1
 
     def _read_ack(self) -> int:
-        key = f"{self._label}/ack/{self._rank}"
-        client = self._client
-        try:
-            if hasattr(client, "key_value_try_get"):
-                raw = client.key_value_try_get(key)
-            else:
-                # jax <= 0.4.x DistributedRuntimeClient has NO try-get
-                # (verified: blocking_key_value_get/_set are the whole
-                # KV surface) — a short blocking get does the job: a
-                # missing key (no ack yet) surfaces as an exception
-                # after the timeout instead of wedging the loop
-                raw = client.blocking_key_value_get(key, 200)
-            return int(str(raw))
-        except Exception:
-            return self._released
+        return _kv_get_int(self._client, f"{self._label}/ack/{self._rank}",
+                           self._released)
 
     def _drain_peers(self) -> None:
         """Pop every ready record from every peer stream and ack what

@@ -51,6 +51,7 @@ import numpy as np
 
 from .. import config, trace
 from ..log import Log
+from ..parallel.async_ps import _kv_get_int
 from .batcher import OverloadedError
 from .faultinject import FaultPlan
 
@@ -179,12 +180,7 @@ class ReplicaServer:
 
     # -- kv helpers ----------------------------------------------------------
     def _read_kv_int(self, key: str, default: int) -> int:
-        try:
-            if hasattr(self._client, "key_value_try_get"):
-                return int(str(self._client.key_value_try_get(key)))
-            return int(str(self._client.blocking_key_value_get(key, 200)))
-        except Exception:
-            return default
+        return _kv_get_int(self._client, key, default)
 
     # -- publish side --------------------------------------------------------
     def _publish(self, msg: Dict[str, Any]) -> None:

@@ -80,6 +80,7 @@ import numpy as np
 from .. import config, trace
 from ..dashboard import Dashboard
 from ..log import Log
+from ..parallel.async_ps import _kv_get_int
 from ..parallel.p2p import reconnect_backoff_s
 from . import kv_transfer
 from .batcher import DeadlineExceededError, OverloadedError
@@ -1056,13 +1057,7 @@ class FleetRouter:
         self._publish_head()
 
     def _read_ack(self, r: int) -> int:
-        key = f"{self._label}/ack/{r}"
-        try:
-            if hasattr(self._client, "key_value_try_get"):
-                return int(str(self._client.key_value_try_get(key)))
-            return int(str(self._client.blocking_key_value_get(key, 100)))
-        except Exception:
-            return 0
+        return _kv_get_int(self._client, f"{self._label}/ack/{r}", 0)
 
     def _apply_resolutions(self, resolutions) -> None:
         """Fire future results/exceptions OUTSIDE every router lock —

@@ -195,47 +195,42 @@ def _maybe_init_distributed() -> None:
     JAX coordination service. Bootstrap sources, in order: the explicit
     net_bind/net_connect API (the reference's machine-file/ZMQ mode), then
     the MV_*/JAX_* coordinator env vars. Single-process runs skip this.
+
+    A caller that asked for a group gets one or a fatal error: carrying
+    on as ``rank 0/1`` would train alone and report success.
     """
     _survivor_mode_prep()
     # Read the env BEFORE touching any jax API: probing jax.process_count()
     # would itself initialise the local backend, after which
     # jax.distributed.initialize() raises.
     if "coordinator" in _explicit_net and "rank" in _explicit_net:
-        import jax
-
-        try:
-            jax.distributed.initialize(
-                coordinator_address=_explicit_net["coordinator"],
-                num_processes=int(_explicit_net["num"]),
-                process_id=int(_explicit_net["rank"]),
-            )
-        except RuntimeError as exc:
-            Log.debug("jax.distributed.initialize skipped: %s", exc)
-        Log.info("process group (explicit net): rank %d/%d via %s",
-                 jax.process_index(), jax.process_count(),
-                 _explicit_net["coordinator"])
-        return
-    coord = os.environ.get("MV_COORDINATOR_ADDRESS") or os.environ.get(
-        "JAX_COORDINATOR_ADDRESS"
-    )
-    nproc = os.environ.get("MV_NUM_PROCESSES")
-    if not (coord and nproc):
-        return
+        source = "explicit net"
+        coord = str(_explicit_net["coordinator"])
+        nproc = int(_explicit_net["num"])
+        rank = int(_explicit_net["rank"])
+    else:
+        source = "env"
+        coord = os.environ.get("MV_COORDINATOR_ADDRESS") or os.environ.get(
+            "JAX_COORDINATOR_ADDRESS")
+        nproc = os.environ.get("MV_NUM_PROCESSES")
+        if not (coord and nproc):
+            return
+        nproc = int(nproc)
+        rank = int(os.environ.get("MV_PROCESS_ID", "0"))
     import jax
 
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coord,
-            num_processes=int(nproc),
-            process_id=int(os.environ.get("MV_PROCESS_ID", "0")),
-        )
-    except RuntimeError as exc:
-        # Already initialised (by the launcher or a prior init()) is fine.
-        Log.debug("jax.distributed.initialize skipped: %s", exc)
-    Log.info(
-        "process group: rank %d/%d via %s",
-        jax.process_index(), jax.process_count(), coord,
-    )
+    # a second init() in a process whose group is already up (by the
+    # launcher or a prior init()) keeps that group
+    if not jax.distributed.is_initialized():
+        try:
+            jax.distributed.initialize(coordinator_address=coord,
+                                       num_processes=nproc, process_id=rank)
+        except RuntimeError as exc:
+            Log.fatal(f"process group ({source}) requested via {coord} "
+                      f"(rank {rank}/{nproc}) but jax.distributed."
+                      f"initialize failed: {exc}")
+    Log.info("process group (%s): rank %d/%d via %s", source,
+             jax.process_index(), jax.process_count(), coord)
 
 
 def discover(mesh_shape: Optional[Sequence[int]] = None) -> Topology:

@@ -150,40 +150,6 @@ def test_claim_epoch_fails_loudly_on_broken_kv():
         async_ps.claim_epoch(BrokenKV())
 
 
-def test_claim_epoch_legacy_client_absent_key_reads_as_zero():
-    """jax<=0.4.x clients (no key_value_try_get) raise XlaRuntimeError
-    ('DEADLINE_EXCEEDED...') — a RuntimeError, not TimeoutError — when
-    the key is absent; the first-ever claim must still succeed. A
-    non-timeout error still fails loudly."""
-    import pytest
-
-    from multiverso_tpu.log import FatalError
-
-    class LegacyKV:
-        def __init__(self):
-            self.d = {}
-
-        def blocking_key_value_get(self, k, timeout_ms):
-            if k not in self.d:
-                raise RuntimeError(
-                    "DEADLINE_EXCEEDED: Timed out waiting for key")
-            return self.d[k]
-
-        def key_value_set(self, k, v, allow_overwrite=False):
-            self.d[k] = v
-
-    kv = LegacyKV()
-    assert async_ps.claim_epoch(kv) == 1     # absent -> first claim
-    assert async_ps.claim_epoch(kv) == 2
-
-    class LegacyBroken(LegacyKV):
-        def blocking_key_value_get(self, k, timeout_ms):
-            raise RuntimeError("UNAVAILABLE: coordinator down")
-
-    with pytest.raises(FatalError):
-        async_ps.claim_epoch(LegacyBroken())
-
-
 def test_part_records_reassemble_to_one_apply():
     """Wire chunking: PART records at consecutive seqs reassemble into ONE
     logical record and apply exactly once; an out-of-order part is a broken

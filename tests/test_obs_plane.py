@@ -521,40 +521,6 @@ def test_wire_drops_whole_reports_past_outstanding_cap():
         trace.collector().clear()
 
 
-def test_wire_acks_work_without_key_value_try_get():
-    """Review finding, environment-confirmed: jax's
-    DistributedRuntimeClient (<= 0.4.x) exposes NO key_value_try_get —
-    only blocking_key_value_get/key_value_set. The ack read must fall
-    back to a short blocking get instead of silently never releasing
-    (which turned into permanent report drops after MAX_OUTSTANDING)."""
-    class _JaxLikeKV:
-        """Exactly the jaxlib 0.4.36 surface the plane touches."""
-
-        def __init__(self):
-            self._inner = _KV()
-            self.key_value_set = self._inner.key_value_set
-            self.blocking_key_value_get = self._inner.blocking_key_value_get
-
-    kv = _JaxLikeKV()
-    assert not hasattr(kv, "key_value_try_get")
-    agent = ObsAgent(rank=1, size=2, client=kv, report_ms=60,
-                     label=f"nt{os.getpid()}", engines=lambda: {},
-                     start=False)
-    try:
-        agent.tick()
-        agent.tick()
-        assert agent._released == 0
-        # the collector's ack lands via plain key_value_set — the
-        # fallback blocking read must pick it up and release
-        kv.key_value_set(f"nt{os.getpid()}/ack/1", "2")
-        assert agent._release_acked_and_can_ship()
-        assert agent._released == 2
-        with agent._transport._lock:
-            assert agent._transport._retained == {}
-    finally:
-        agent.stop(final_report=False)
-
-
 def test_agent_final_report_keeps_engines_after_discovery_goes_dark():
     """Review finding: Session.stop() empties the server registry
     BEFORE the teardown ships the obs agent's final report, so live

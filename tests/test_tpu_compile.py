@@ -1,0 +1,339 @@
+"""The main path's kernels and serving programs, compiled for a v5e.
+
+No chip is needed: the TPU compiler is installed and compiles for a
+DESCRIBED ``v5e:2x2`` (on-chip-measurement guide, section 2, rehearsal
+3). It refuses what the chip would refuse — a slice off the tiling, too
+much VMEM, a program that does not fit HBM — which interpret mode never
+shows. A compile that passes is not a chip run; ``chip_smoke.py`` is.
+
+Everything that touches the topology happens inside fixtures and tests
+of THIS file: only one process may load libtpu, xdist gives a file to
+one worker, and a module that loaded it at import would leave the
+other workers with nothing to collect. Each case asserts what it
+compiled: off-TPU ``interpret=None`` lowers the INTERPRETED kernel,
+which compiles fine and proves nothing, so every case either passes
+``interpret=False`` or steers ``_interpret_default``, and looks for the
+Mosaic custom call in the compiled text.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+from functools import partial
+
+import numpy as np
+import pytest
+
+# the chip_smoke.py widths (tools/lm_mfu.py flagship LM, engine defaults)
+_LM = dict(vocab_size=256, d_model=768, n_heads=12, n_layers=12, d_ff=3072,
+           max_seq=2048)
+_SLOTS, _MAX_PROMPT, _MAX_NEW, _BLOCK, _CHUNK = 32, 1024, 64, 16, 32
+_KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described topology, with the persistent compile cache off
+    while this file's tests run (an entry compiled for a described chip
+    cannot be read back without one, and the next compile would warn)."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+    if log_dir is None:
+        os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Callers that leave ``interpret=None`` (the LM's ``_attention``,
+    ``ring_attention``) ask ``_interpret_default``; here the backend is
+    the CPU, so steer it to the answer the chip gives. The submodule is
+    reached through ``sys.modules``: ``ops.flash_attention`` the
+    attribute is the re-exported function."""
+    import multiverso_tpu.ops  # noqa: F401  (registers the submodule)
+
+    module = sys.modules["multiverso_tpu.ops.flash_attention"]
+    monkeypatch.setattr(module, "_interpret_default", lambda: False)
+
+
+def _compile(fn, *args, **jit_kwargs):
+    import jax
+
+    return jax.jit(fn, **jit_kwargs).lower(*args).compile()
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count(_KERNEL)
+
+
+def _qkv(sharding, seq, heads, dim, dtype, batch=None):
+    import jax
+
+    shape = (seq, heads, dim) if batch is None else (batch, seq, heads * dim)
+    return (jax.ShapeDtypeStruct(shape, dtype, sharding=sharding),) * 3
+
+
+# (seq, heads, head_dim, dtype): the LM-step shape first, then the
+# ragged / small cases tools/tpu_validate.py checks numerically
+_FLASH_SHAPES = [
+    (2048, 12, 64, "bfloat16"),
+    (256, 4, 64, "float32"),
+    (512, 8, 128, "float32"),
+    (1024, 2, 128, "float32"),
+    (384, 4, 64, "float32"),
+]
+
+
+@pytest.mark.parametrize("seq,heads,dim,dtype", _FLASH_SHAPES)
+def test_flash_attention_forward_compiles(one_chip, seq, heads, dim, dtype):
+    from multiverso_tpu.ops import flash_attention
+
+    compiled = _compile(
+        partial(flash_attention, causal=True, interpret=False),
+        *_qkv(one_chip, seq, heads, dim, np.dtype(dtype)))
+    assert _kernel_calls(compiled) >= 1
+
+
+@pytest.mark.parametrize("seq,heads,dim,dtype", _FLASH_SHAPES)
+def test_flash_attention_backward_compiles(one_chip, seq, heads, dim, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        *_qkv(one_chip, seq, heads, dim, np.dtype(dtype)))
+    # forward + the backward kernels (one fused, or dq and dk/dv)
+    assert _kernel_calls(compiled) >= 2
+
+
+def test_lm_attention_call_compiles(one_chip, compiled_kernels):
+    """The call exactly as ``models.transformer._attention`` makes it at
+    the chip_smoke LM step: vmapped over batch 4, ``attention="flash"``
+    crossing over to the kernel at seq 2048."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models.transformer import _attention
+
+    heads = _LM["n_heads"]
+    args = _qkv(one_chip, _LM["max_seq"], heads, _LM["d_model"] // heads,
+                jnp.bfloat16, batch=4)
+    fwd = _compile(lambda q, k, v: _attention(q, k, v, heads, "flash"),
+                   *args)
+    assert _kernel_calls(fwd) >= 1
+
+    def loss(q, k, v):
+        return jnp.sum(_attention(q, k, v, heads, "flash")
+                       .astype(jnp.float32) ** 2)
+
+    bwd = _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    assert _kernel_calls(bwd) >= 2
+
+
+def test_lm_attention_below_crossover_is_xla(one_chip, compiled_kernels):
+    """The other side of the dispatch: below ``FLASH_CROSSOVER_SEQ`` the
+    same call holds no kernel — so the assertion above means something."""
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models.transformer import _attention
+
+    heads = _LM["n_heads"]
+    args = _qkv(one_chip, 1024, heads, _LM["d_model"] // heads, jnp.bfloat16,
+                batch=4)
+    compiled = _compile(lambda q, k, v: _attention(q, k, v, heads, "flash"),
+                        *args)
+    assert _kernel_calls(compiled) == 0
+
+
+def test_ring_attention_pallas_compiles_on_four_chips(topo,
+                                                      compiled_kernels):
+    """``flash_attention_partial`` as ``ring_attention(impl="pallas")``
+    calls it (traced block offsets, default ``interpret``), the sequence
+    sharded over all four described chips."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from multiverso_tpu.ops.ring_attention import ring_attention
+    from multiverso_tpu.topology import SEQ_AXIS
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (SEQ_AXIS,))
+    seq_sharded = NamedSharding(mesh, P(SEQ_AXIS, None, None))
+    args = (jax.ShapeDtypeStruct((4096, 12, 64), jnp.bfloat16,
+                                 sharding=seq_sharded),) * 3
+    compiled = _compile(
+        lambda q, k, v: ring_attention(q, k, v, mesh, causal=True,
+                                       impl="pallas"), *args)
+    text = compiled.as_text()
+    assert _KERNEL in text
+    assert "collective-permute" in text
+
+
+# -- the decode engine's paged programs at the serving phase's shapes ----------
+@pytest.fixture(scope="module")
+def serving_shapes(one_chip):
+    """(cfg, params, k_pool, v_pool, block_tables) as ShapeDtypeStructs on
+    one described chip, sized as ``register_decoder(slots=32,
+    max_prompt=1024, max_new=64)`` sizes them: T = 1088, 68 blocks of 16
+    per slot, a contiguous-equivalent pool plus the scratch block."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models.transformer import (TransformerConfig,
+                                                   init_params)
+
+    cfg = TransformerConfig(dtype=jnp.bfloat16, **_LM)
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(cfg)))
+    per_slot = -(-(_MAX_PROMPT + _MAX_NEW) // _BLOCK)
+    pool = jax.ShapeDtypeStruct(
+        (cfg.n_layers, _SLOTS * per_slot + 1, _BLOCK, cfg.d_model),
+        cfg.dtype, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((_SLOTS, per_slot), jnp.int32,
+                                  sharding=one_chip)
+    return cfg, params, pool, pool, tables
+
+
+def _ints(sharding, *shape):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _fits_hbm(compiled, budget=16 * 2 ** 30) -> bool:
+    mem = compiled.memory_analysis()
+    # donated pools alias their outputs: count them once
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    return held < budget
+
+
+def test_decode_step_paged_compiles(one_chip, serving_shapes):
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models.transformer import decode_step_paged
+
+    cfg, params, kc, vc, bt = serving_shapes
+    active = jax.ShapeDtypeStruct((_SLOTS,), jnp.bool_, sharding=one_chip)
+    compiled = _compile(
+        lambda p, kc, vc, bt, tok, pos, act: decode_step_paged(
+            cfg, p, kc, vc, bt, tok, pos, act,
+            t_logical=_MAX_PROMPT + _MAX_NEW),
+        params, kc, vc, bt, _ints(one_chip, _SLOTS), _ints(one_chip, _SLOTS),
+        active, donate_argnums=(1, 2))
+    assert _fits_hbm(compiled)
+    # the engine donates both pools on the chip: the compiler must alias
+    # them, or every token step copies the whole cache
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * np.prod(
+        kc.shape) * 2
+
+
+def test_prefill_chunk_paged_compiles(one_chip, serving_shapes):
+    from multiverso_tpu.models.transformer import prefill_chunk_paged
+
+    cfg, params, kc, vc, bt = serving_shapes
+    compiled = _compile(
+        lambda p, kc, vc, bt, slot, toks, off, n: prefill_chunk_paged(
+            cfg, p, kc, vc, bt, slot, toks, off, n,
+            t_logical=_MAX_PROMPT + _MAX_NEW),
+        params, kc, vc, bt, _ints(one_chip), _ints(one_chip, _CHUNK),
+        _ints(one_chip), _ints(one_chip), donate_argnums=(1, 2))
+    assert _fits_hbm(compiled)
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * np.prod(
+        kc.shape) * 2
+
+
+def test_verify_step_paged_compiles(one_chip, serving_shapes):
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models.transformer import verify_step_paged
+
+    cfg, params, kc, vc, bt = serving_shapes
+    window = 5                                  # spec_k = 4
+    active = jax.ShapeDtypeStruct((_SLOTS,), jnp.bool_, sharding=one_chip)
+    compiled = _compile(
+        lambda p, kc, vc, bt, toks, pos, act, nv: verify_step_paged(
+            cfg, p, kc, vc, bt, toks, pos, act, nv,
+            t_logical=_MAX_PROMPT + _MAX_NEW),
+        params, kc, vc, bt, _ints(one_chip, _SLOTS, window),
+        _ints(one_chip, _SLOTS), active, _ints(one_chip, _SLOTS),
+        donate_argnums=(1, 2))
+    assert _fits_hbm(compiled)
+
+
+def test_cow_block_copy_compiles(one_chip, serving_shapes):
+    from multiverso_tpu.models.transformer import cow_block_copy
+
+    _, _, kc, vc, _ = serving_shapes
+    compiled = _compile(cow_block_copy, kc, vc, _ints(one_chip),
+                        _ints(one_chip), donate_argnums=(0, 1))
+    mem = compiled.memory_analysis()
+    # in place: a copy-on-write of one block must not copy the pools
+    assert mem.alias_size_in_bytes >= 2 * np.prod(kc.shape) * 2
+    assert mem.temp_size_in_bytes < np.prod(kc.shape) * 2
+
+
+def test_sharded_decode_programs_compile_on_two_chips(topo, serving_shapes):
+    """The ``decode_tp=2`` engine's pre-partitioned step and chunk
+    programs (``chip_smoke.py --chips 4`` serves through them): pools
+    head-sharded, so each chip holds half the cache, and the two
+    all-reduces per layer are the collectives the compiler puts in."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from multiverso_tpu.models.transformer import (
+        DECODE_TP_AXIS, make_sharded_decode_programs)
+
+    cfg, params, kc, _, bt = serving_shapes
+    mesh = Mesh(np.asarray(topo.devices[:2]), (DECODE_TP_AXIS,))
+    progs = make_sharded_decode_programs(
+        cfg, mesh, _MAX_PROMPT + _MAX_NEW, donate=True)
+    rep = NamedSharding(mesh, P())
+    place = lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
+    params = jax.tree.map(place, params, progs["param_shardings"])
+    pool = place(kc, progs["pool_sharding"])
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                               sharding=rep)
+    active = jax.ShapeDtypeStruct((_SLOTS,), jnp.bool_, sharding=rep)
+    step = progs["step"].lower(params, pool, pool, place(bt, rep),
+                               ints(_SLOTS), ints(_SLOTS), active).compile()
+    chunk = progs["chunk"].lower(params, pool, pool, place(bt, rep), ints(),
+                                 ints(_CHUNK), ints(), ints()).compile()
+    pool_bytes = int(np.prod(kc.shape)) * 2
+    for compiled in (step, chunk):
+        assert "all-reduce" in compiled.as_text()
+        mem = compiled.memory_analysis()      # per device
+        assert mem.alias_size_in_bytes >= pool_bytes      # 2 pools / 2 chips
+        assert mem.argument_size_in_bytes < 2 * pool_bytes
