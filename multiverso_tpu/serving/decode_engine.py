@@ -1790,7 +1790,8 @@ class DecodeEngine:
                        and not self._active.any()
                        and not self._splice_q
                        and not self._stop.is_set()):
-                    self._cv.wait()
+                    with trace.phase("engine.wait"):
+                        self._cv.wait()
                 if (self._stop.is_set() and not self._q
                         and self._pf is None and not self._active.any()):
                     # release any splice waiters before the loop dies —
@@ -1801,6 +1802,24 @@ class DecodeEngine:
                         info["skipped"] = "stopped"
                         done.set()
                     return
+                # Phases on the profiler's clock (trace.phase; the shared
+                # NULL_SPAN with no session). A pass that did work is ONE
+                # engine.iter, to the end of _record_iteration, and opens
+                # with engine.admit, to its first dispatch. With
+                # something live, prefilling or to splice the work is
+                # sure and both open here; else the queue decides below:
+                # an arrival opens them late, and a pass that finds only
+                # block-starved or expired waiters is engine.wait. They
+                # outlive this lock's block, so they are entered and
+                # left by hand
+                it_phase = admit_phase = trace.NULL_SPAN
+                sure = bool(self._splice_q or self._pf is not None
+                            or self._active.any())
+                if sure:
+                    it_phase = trace.phase("engine.iter")
+                    it_phase.__enter__()
+                    admit_phase = trace.phase("engine.admit")
+                    admit_phase.__enter__()
                 if self._splice_q:
                     splices = list(self._splice_q)
                     self._splice_q.clear()
@@ -1833,6 +1852,13 @@ class DecodeEngine:
                         if self._paged:
                             reserved += self._reservation_blocks(req)
                         arrivals.append(req)
+                if not sure:
+                    it_phase = trace.phase(
+                        "engine.iter" if arrivals else "engine.wait")
+                    it_phase.__enter__()
+                    if arrivals:
+                        admit_phase = trace.phase("engine.admit")
+                        admit_phase.__enter__()
             if expired:
                 self._drop_expired(expired)
             # the progress clock restarts when the loop picks work up:
@@ -1891,11 +1917,13 @@ class DecodeEngine:
                             # the request was requeued — retry next
                             # iteration rather than spinning here
                             break
+                    admit_phase.__exit__(None, None, None)
                     if self._pf is not None:
                         # AT MOST one budget-sized chunk per iteration:
                         # the stall an admission can add to every live
                         # generation's next token is one chunk of work
-                        self._prefill_one_chunk()
+                        with trace.phase("engine.prefill_chunk"):
+                            self._prefill_one_chunk()
                         worked = True
                 else:
                     if arrivals:
@@ -1905,21 +1933,26 @@ class DecodeEngine:
                         finally:
                             self._admitting = False
                         worked = True
+                    admit_phase.__exit__(None, None, None)
                 live = int(self._active.sum()) + (self._pf is not None)
                 if live > self.peak_live:
                     self.peak_live = live
                 if self._active.any():
                     t_step0 = time.monotonic()
-                    self._step()
+                    with trace.phase("engine.step"):
+                        self._step()
                     step_ms = (time.monotonic() - t_step0) * 1e3
                     worked = True
             except Exception as exc:          # pragma: no cover - defensive
                 # arrivals are already popped from the queue but may not
                 # be slotted yet — include them so their futures fail too
                 self._fail_all(exc, arrivals)
+                admit_phase.__exit__(None, None, None)
+                it_phase.__exit__(None, None, None)
                 return
             if worked:
-                self._record_iteration(t_work0, step_ms)
+                with trace.phase("engine.record"):
+                    self._record_iteration(t_work0, step_ms)
             elif not arrivals and not expired:
                 # nothing live and nothing admissible: the queue holds
                 # only block-starved waiters (a budget-exhausted
@@ -1927,6 +1960,7 @@ class DecodeEngine:
                 # yield briefly instead of hot-spinning until blocks
                 # free
                 time.sleep(0.0005)
+            it_phase.__exit__(None, None, None)
 
     def _record_iteration(self, t_work0: float, step_ms: float) -> None:
         """One iteration retired: bump the progress clock/counters and
@@ -2290,7 +2324,8 @@ class DecodeEngine:
         # spike the budget exists to prevent (measured: p99 went from
         # ~1 chunk+step to >100 ms under ramp). One chunk per iteration,
         # retired per iteration, keeps the bound honest.
-        jax.block_until_ready(self._k_cache)
+        with trace.phase("engine.prefill_chunk.sync"):
+            jax.block_until_ready(self._k_cache)
         req.pf_off = off + n
         req.pf_chunks += 1
         if sp:
@@ -2343,7 +2378,9 @@ class DecodeEngine:
             return
         # final chunk: the prompt's last real position's logits are the
         # first generated token (exactly the monolithic prefill's gather)
-        tok0 = int(np.argmax(np.asarray(logits)))
+        with trace.phase("engine.prefill_chunk.sync"):
+            logits = np.asarray(logits)
+        tok0 = int(np.argmax(logits))
         now = time.monotonic()
         if req.resumed:
             # preemption recompute: TTFT already happened in the first
@@ -2913,7 +2950,17 @@ class DecodeEngine:
             self._k_cache, self._v_cache, nxt, _ = self._step_fn(
                 self._pinned, self._k_cache, self._v_cache,
                 self._tok, self._pos, self._active)
-        nxt = np.array(nxt)       # [S] or [S, K+1]; the host sync point
+        with trace.phase("engine.step.sync"):
+            nxt = np.array(nxt)   # [S] or [S, K+1]; the host sync point
+        with trace.phase("engine.step.book"):
+            self._book_step(nxt, spec_toks, n_valid, t_it0, tracing)
+
+    def _book_step(self, nxt, spec_toks, n_valid, t_it0: float,
+                   tracing: bool) -> None:
+        """What a step's synced tokens mean on the host: each live
+        slot's emissions booked, histograms and ledger charged, finished
+        requests released and resolved."""
+        ledger_on = self.ledger is not None
         now = time.monotonic()
         self.steps_counter.inc()
         if ledger_on:

@@ -29,16 +29,19 @@ Design constraints, in order:
   across the submit->batcher->engine thread boundaries, and two u64
   header fields carry it inside async-bus wire records so a peer's
   apply span links to the publisher's trace.
-* **one timebase** — span timestamps are ``time.monotonic()`` seconds
-  (the clock the serving layer already stamps ``t_enq`` with), rebased
-  to epoch microseconds at export via an anchor captured at
-  ``enable()``; host spans and device (xprof) captures can then be
-  merged by time range (``tools/trace_summary.py --host-trace``).
+* **two clocks, kept apart** — span timestamps are ``time.monotonic()``
+  seconds (the clock the serving layer already stamps ``t_enq`` with),
+  rebased to epoch microseconds at export via an anchor captured at
+  ``enable()``: the flight recorder's clock, so the ring lines up with
+  its counter tracks in Perfetto. A profiler capture counts nanoseconds
+  from the start of its own session, so ring spans do NOT line up with
+  device ops. What has to is a :func:`phase`: a
+  ``jax.profiler.TraceAnnotation`` entered and left by the thread doing
+  the work, which lands in the profiler's own file beside the ops.
 
 Export is Chrome trace-event JSON (``{"traceEvents": [...]}``) with
 B/E event pairs, one synthetic track per (trace id, recording thread)
-— loadable in Perfetto / ``chrome://tracing`` next to an xprof device
-capture (docs/OBSERVABILITY.md).
+— loadable in Perfetto / ``chrome://tracing`` (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -56,6 +59,7 @@ __all__ = [
     "enabled", "enable", "disable", "resume", "start_span", "span",
     "record_span", "current_span", "current_context", "export_chrome",
     "span_from_dict", "validate_chrome_events",
+    "PROFILER_PREFIX", "phase",
 ]
 
 # Span/trace ids: process-unique, allocation-cheap. itertools.count is
@@ -594,6 +598,29 @@ def record_span(name: str, parent: Optional[SpanContext], t0: float,
     sp = Span(name, trace_id, _new_id(), parent_id, t0, attrs or None)
     sp.t1 = t1
     _COLLECTOR.record(sp)
+
+
+# -- loop phases on the profiler's clock -------------------------------------
+
+# The prefix the benchmark's trace reduction admits host events by
+# (benchmarks/tracered.py: SPAN_PREFIX; a test holds the two equal).
+# Under it a phase names device-idle gaps in the ledger's breakdown and
+# is summed for the per-layer readers, with no edit to the benchmark.
+PROFILER_PREFIX = "bench."
+
+
+def phase(name: str):
+    """``with phase("engine.step"):`` -- a phase of a loop thread,
+    written into the running profiler session as a host event named
+    ``PROFILER_PREFIX + name``; :data:`NULL_SPAN` with no session. A
+    phase serves every live request at once, so it stays out of the
+    per-request ring; it takes no attributes, because the profiler
+    folds them into the event's name and readers key on the name."""
+    from jax.profiler import TraceAnnotation
+
+    if not TraceAnnotation.is_enabled():
+        return NULL_SPAN
+    return TraceAnnotation(PROFILER_PREFIX + name)
 
 
 def export_chrome(path: Optional[str] = None) -> dict:

@@ -28,9 +28,16 @@ def test_check_macros():
 
 def test_log_file_sink(tmp_path):
     path = str(tmp_path / "mv.log")
+    # the level is process-global: an earlier test of this xdist worker
+    # that ran mv.init([..., "-log_level=error"]) would silence info()
+    level = Log.logger().level
+    Log.reset_log_level(LogLevel.INFO)
     Log.reset_log_file(path)
-    Log.info("hello file sink")
-    Log.reset_log_file("")  # detach
+    try:
+        Log.info("hello file sink")
+    finally:
+        Log.reset_log_file("")  # detach
+        Log.reset_log_level(level)
     with open(path) as f:
         content = f.read()
     assert "hello file sink" in content

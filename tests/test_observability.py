@@ -461,13 +461,18 @@ def test_tracing_disabled_no_decode_hot_loop_overhead(mv_session,
     """The overhead guard: with the collector OFF (the default), a full
     generation through the engine must not construct one Span, record
     one event, or touch the collector — the hot loop's only tracing
-    cost is the ``enabled()`` attribute read."""
+    cost is the ``enabled()`` attribute read. The same holds for the
+    loop's profiler phases: with no ``jax.profiler`` session running no
+    ``TraceAnnotation`` is built and every phase is ``NULL_SPAN``."""
+    import jax
+
     from multiverso_tpu.models.transformer import (TransformerConfig,
                                                    TransformerLM)
     from multiverso_tpu.serving import InferenceServer
 
     assert not trace.enabled()
-    calls = {"span": 0, "record": 0}
+    assert trace.phase("engine.step") is trace.NULL_SPAN
+    calls = {"span": 0, "record": 0, "annotation": 0}
     real_span_init = trace.Span.__init__
 
     def counting_init(self, *a, **kw):
@@ -480,6 +485,15 @@ def test_tracing_disabled_no_decode_hot_loop_overhead(mv_session,
         calls["record"] += 1
         return real_record(self, sp)
 
+    real_annotation_init = jax.profiler.TraceAnnotation.__init__
+
+    def counting_annotation(self, name, *a, **kw):
+        # jax annotates its own compiles; the program's carry the prefix
+        calls["annotation"] += name.startswith(trace.PROFILER_PREFIX)
+        return real_annotation_init(self, name, *a, **kw)
+
+    monkeypatch.setattr(jax.profiler.TraceAnnotation, "__init__",
+                        counting_annotation)
     monkeypatch.setattr(trace.Span, "__init__", counting_init)
     monkeypatch.setattr(trace.TraceCollector, "record", counting_record)
 
@@ -491,7 +505,7 @@ def test_tracing_disabled_no_decode_hot_loop_overhead(mv_session,
     out = srv.submit("lm", np.arange(1, 6, dtype=np.int32)).result(
         timeout=60)
     assert len(out["result"]) == 8               # 7 decode iterations ran
-    assert calls == {"span": 0, "record": 0}
+    assert calls == {"span": 0, "record": 0, "annotation": 0}
     assert trace.collector().spans() == []
     # the ALWAYS-ON flight recorder was live the whole time — proving
     # the zero-Span guarantee holds with black-box recording running —

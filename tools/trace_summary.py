@@ -20,16 +20,15 @@ FILE is a Chrome trace JSON from ``multiverso_tpu.trace`` (e.g.
 trace id) the report breaks host wall time into queue wait, admission/
 prefill, batch execution and decode iterations — the stages that explain
 a p99 outlier. Given BOTH a host trace and an xprof TRACE_DIR, the two
-are merged by time range: device-op time whose timeline falls inside a
-request's root-span window is attributed to that request (the captures
-must cover the same run; the tool aligns the two clocks by their first
-events, so co-captured traces line up within scheduling jitter).
+reports print one after the other and are NOT merged: the ring's clock
+is the host's, a capture's counts from its own session's start. What
+has to line up with device ops is a ``trace.phase``, in the capture.
 
 Usage::
 
     python tools/trace_summary.py TRACE_DIR [--top 20] [--by op|source]
     python tools/trace_summary.py --host-trace serve.json [TRACE_DIR]
-        [--top 20] [--sort total|queue|device] [--slo-ms 250]
+        [--top 20] [--sort total|queue] [--slo-ms 250]
 
 ``--slo-ms`` flags (``!``) and counts requests whose total exceeds the
 objective; on tail-sampled captures (``-trace_tail``) a ``keep`` column
@@ -137,8 +136,8 @@ _STAGE_COLUMNS = (
 )
 
 
-def request_report(spans, device_events=None):
-    """Per-request rows from host spans (+ optional device-time merge).
+def request_report(spans):
+    """Per-request rows from host spans.
 
     A request = one root span (no parent_id) and every span sharing its
     **(node, trace id)** — node being the recording pid (the node rank
@@ -147,30 +146,13 @@ def request_report(spans, device_events=None):
     rows silently vanished under the != 1 roots guard), and a
     cross-process ``bus.publish``/``bus.apply`` pair SHARES one trace id
     by design — per-node grouping keeps each node's half its own row,
-    and the ``node`` column says which replica served what. Device
-    events (xprof, ``device_duration_ps``) are merged BY TIME RANGE:
-    the two timelines are aligned on their first events, then device-op
-    time inside a request's window is attributed to it (overlapping
-    requests both count a shared interval — attribution, not
-    accounting).
+    and the ``node`` column says which replica served what.
     """
     by_trace: dict = {}
     for sp in spans:
         if sp["trace_id"] is not None:
             by_trace.setdefault((sp.get("node"), sp["trace_id"]),
                                 []).append(sp)
-    device = []
-    offset = 0.0
-    if device_events:
-        xs = [e for e in device_events
-              if e.get("ph") == "X"
-              and "device_duration_ps" in e.get("args", {})]
-        if xs and spans:
-            offset = (min(s["ts"] for s in spans)
-                      - min(float(e.get("ts", 0.0)) for e in xs))
-        device = [(float(e["ts"]) + offset,
-                   float(e["ts"]) + offset + float(e.get("dur", 0.0)),
-                   int(e["args"]["device_duration_ps"]) / 1e9) for e in xs]
     rows = []
     for (node, trace_id), group in by_trace.items():
         roots = [s for s in group if s["parent_id"] is None]
@@ -262,20 +244,14 @@ def request_report(spans, device_events=None):
         if accts and "tenant" in accts[0]["args"]:
             row["tenant"] = accts[0]["args"]["tenant"]
             row["cost"] = accts[0]["args"].get("cost", 0.0)
-        if device:
-            w0, w1 = root["ts"], root["ts"] + root["dur"]
-            row["device_ms"] = sum(
-                d for (t0, t1, d) in device if t0 < w1 and t1 > w0)
         rows.append(row)
     return rows
 
 
 def print_request_report(rows, top: int, sort: str,
                          slo_ms: float = 0.0) -> None:
-    key = {"total": "total_ms", "queue": "queue_ms",
-           "device": "device_ms"}.get(sort, "total_ms")
+    key = {"total": "total_ms", "queue": "queue_ms"}.get(sort, "total_ms")
     rows = sorted(rows, key=lambda r: r.get(key, 0.0), reverse=True)
-    has_dev = any("device_ms" in r for r in rows)
     has_blocks = any("blocks" in r for r in rows)
     has_prefix = any("prefix_hit_blocks" in r for r in rows)
     has_tp = any("decode_tp" in r for r in rows)
@@ -316,8 +292,6 @@ def print_request_report(rows, top: int, sort: str,
         hdr += f" {'xfblk':>6} {'xfkb':>8} {'dedup':>6}"
     if has_tenant:
         hdr += f" {'tenant':>10} {'cost':>9}"
-    if has_dev:
-        hdr += f" {'device':>9}"
     if has_keep:
         hdr += f" {'keep':>6}"
     print(hdr + "  trace_id [model]")
@@ -356,8 +330,6 @@ def print_request_report(rows, top: int, sort: str,
                          f"{r.get('cost', 0.0):9.3f}")
             else:
                 line += f" {'-':>10} {'-':>9}"
-        if has_dev:
-            line += f" {r.get('device_ms', 0.0):9.3f}"
         if has_keep:
             line += f" {r.get('keep') or '-':>6}"
         # non-request roots (snapshot.pin, table.add, bus.publish) label
@@ -373,8 +345,8 @@ def main(argv=None):
     ap.add_argument("--by", choices=["source", "op"], default="source")
     ap.add_argument("--host-trace", default=None,
                     help="multiverso_tpu.trace Chrome JSON: per-request "
-                         "host breakdown (+ device merge with TRACE_DIR)")
-    ap.add_argument("--sort", choices=["total", "queue", "device"],
+                         "host breakdown")
+    ap.add_argument("--sort", choices=["total", "queue"],
                     default="total", help="request-report sort column")
     ap.add_argument("--slo-ms", type=float, default=0.0,
                     help="flag requests whose total exceeds this latency "
@@ -387,7 +359,7 @@ def main(argv=None):
     events = load_events(args.trace_dir) if args.trace_dir else None
     if args.host_trace is not None:
         spans = load_host_spans(args.host_trace)
-        rows = request_report(spans, events)
+        rows = request_report(spans)
         print_request_report(rows, args.top, args.sort, args.slo_ms)
         if events is None:
             return 0
