@@ -249,23 +249,33 @@ def _cached_attention(q, k_cache, v_cache, n_heads: int, pos) -> jax.Array:
 
     ``pos`` [B] is each example's current position; cache entries at
     positions <= pos are live (prompt + previously generated tokens),
-    everything past is masked. Math matches :func:`ops.reference_attention`
-    (1/sqrt(dh) scale, f32 softmax) so cached decode is numerically the
-    training forward's argmax path.
+    everything past is masked. Same products and rounding points as
+    :func:`ops.reference_attention` (1/sqrt(dh) scale, f32 accumulation,
+    f32 softmax, probabilities rounded to the cache's dtype), but both
+    products are written as MATRIX products over the cache as it lies,
+    ``[B, T, D]``: ``q`` is spread block-diagonally to ``[B, h, D]``
+    (head ``h``'s ``dh`` columns, exact zeros elsewhere), so the scores
+    are ``[h, D] x [D, T]`` and the values ``[h, T] x [T, D]`` per
+    example, and head ``h`` keeps its own columns of its row. A one-row
+    product per (example, head) is no matrix product to the TPU
+    compiler: it multiplies and reduces on the vector units, over a
+    float32 copy of the whole cache. The added terms are exact zeros, so
+    only the order of the f32 accumulation is the compiler's.
     """
-    B, D = q.shape
+    D = q.shape[1]
     T = k_cache.shape[1]
     dh = D // n_heads
-    qh = q.reshape(B, n_heads, dh)
-    kh = k_cache.reshape(B, T, n_heads, dh)
-    vh = v_cache.reshape(B, T, n_heads, dh)
-    scores = jnp.einsum("bhd,bthd->bht", qh, kh,
+    own = jnp.arange(n_heads)[:, None] == jnp.arange(D)[None, :] // dh
+    q_heads = jnp.where(own[None], q[:, None, :], 0)
+    scores = jnp.einsum("bhD,btD->bht", q_heads, k_cache,
                         preferred_element_type=jnp.float32) / np.sqrt(dh)
     mask = (jnp.arange(T)[None, :] <= pos[:, None])[:, None, :]
     scores = jnp.where(mask, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bht,bthd->bhd", probs.astype(vh.dtype), vh)
-    return out.reshape(B, D).astype(q.dtype)
+    full = jnp.einsum("bht,btD->bhD", probs.astype(v_cache.dtype), v_cache,
+                      preferred_element_type=jnp.float32)
+    out = jnp.sum(jnp.where(own[None], full, 0.0), axis=1)
+    return out.astype(q.dtype)
 
 
 def prefill(cfg: TransformerConfig, params: Dict[str, Any],
@@ -458,12 +468,31 @@ def prefill_chunk(cfg: TransformerConfig, params: Dict[str, Any],
 #   prefill garbage lands. Nothing a live attention mask can reach ever
 #   maps there — a slot's reservation covers prompt + max_new positions, so
 #   every position <= pos resolves to a real allocated block.
-# * gathered per-slot views are SLICED to the engine's logical cache length
-#   ``t_logical`` (= max_prompt + max_new) before attention, so the paged
-#   attention operand has the exact shape (and therefore the exact reduction
-#   order, hence bit-exact outputs) of the contiguous cache it replaces —
-#   the gather's tail positions past a slot's allocation hold scratch
-#   garbage, masked off exactly like the contiguous strips' dead writes.
+# * per-slot views are built by ONE helper, :func:`_paged_view`, and SLICED
+#   to the engine's logical cache length ``t_logical`` (= max_prompt +
+#   max_new) before attention, so the paged attention operand has the exact
+#   shape of the contiguous cache it replaces and both layouts run the same
+#   attention on it (hence bit-exact outputs across layouts) — the gather's
+#   tail positions past a slot's allocation hold scratch garbage, masked
+#   off exactly like the contiguous strips' dead writes.
+
+
+def _paged_view(pool: jax.Array, layer: int, tables: jax.Array) -> jax.Array:
+    """Layer ``layer``'s blocks named by ``tables`` ([S, M] or [M] block
+    ids), gathered straight from the ``[L, N, Bs, D]`` pool into a
+    contiguous ``[..., M * Bs, D]`` view in the pool's dtype.
+
+    The layer index goes INTO the gather (the pool seen as ``[L * N, Bs,
+    D]``, a bitcast, indexed by ``layer * N + tables``): slicing
+    ``pool[layer]`` out of a pool that was just scattered into copies
+    the layer's whole ``[N, Bs, D]`` to read ``M`` blocks of it. Table
+    entries are pool ids (scratch 0 included), always in bounds, so the
+    gather clips instead of testing every element against the bounds.
+    """
+    L, N, Bs, D = pool.shape
+    blocks = jnp.take(pool.reshape(L * N, Bs, D), layer * N + tables,
+                      axis=0, mode="clip")
+    return blocks.reshape(tables.shape[:-1] + (tables.shape[-1] * Bs, D))
 
 
 def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
@@ -487,7 +516,6 @@ def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
 
     Returns ``(k_pool, v_pool, next_tok [S], pos [S])``.
     """
-    S = tok.shape[0]
     Bs = k_pool.shape[2]
     M = block_tables.shape[1]
     T = M * Bs if t_logical is None else int(t_logical)
@@ -503,12 +531,11 @@ def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
         q, k, v = x @ layer["w_q"], x @ layer["w_k"], x @ layer["w_v"]
         k_pool = k_pool.at[i, write_blk, write_off].set(k)
         v_pool = v_pool.at[i, write_blk, write_off].set(v)
-        # gather each slot's blocks into a contiguous [S, T, D] view —
-        # the same operand shape as the contiguous cache, so the
-        # attention math (and its reduction order) is unchanged
-        kv_shape = (S, M * Bs, -1)
-        kc = jnp.take(k_pool[i], block_tables, axis=0).reshape(kv_shape)
-        vc = jnp.take(v_pool[i], block_tables, axis=0).reshape(kv_shape)
+        # each slot's blocks as a contiguous [S, T, D] view — the operand
+        # shape of the contiguous cache, so both layouts run the same
+        # attention (same products, same rounding points)
+        kc = _paged_view(k_pool, i, block_tables)
+        vc = _paged_view(v_pool, i, block_tables)
         h = h + _cached_attention(
             q, kc[:, :T], vc[:, :T], cfg.n_heads, pos) @ layer["w_o"]
         x = _rmsnorm(h, layer["ln2_g"])
@@ -564,8 +591,8 @@ def prefill_chunk_paged(cfg: TransformerConfig, params: Dict[str, Any],
         q, k, v = x @ layer["w_q"], x @ layer["w_k"], x @ layer["w_v"]
         k_pool = k_pool.at[i, blk, off].set(k)
         v_pool = v_pool.at[i, blk, off].set(v)
-        kc = jnp.take(k_pool[i], bt_row, axis=0).reshape(M * Bs, -1)
-        vc = jnp.take(v_pool[i], bt_row, axis=0).reshape(M * Bs, -1)
+        kc = _paged_view(k_pool, i, bt_row)
+        vc = _paged_view(v_pool, i, bt_row)
         h = h + _chunk_attention(
             q, kc[:T], vc[:T], cfg.n_heads, offset) @ layer["w_o"]
         x = _rmsnorm(h, layer["ln2_g"])
@@ -625,8 +652,8 @@ def prefill_chunk_paged_sp(cfg: TransformerConfig, params: Dict[str, Any],
         q, k, v = x @ layer["w_q"], x @ layer["w_k"], x @ layer["w_v"]
         k_pool = k_pool.at[i, blk, off].set(k)
         v_pool = v_pool.at[i, blk, off].set(v)
-        kc = jnp.take(k_pool[i], bt_row, axis=0).reshape(M * Bs, -1)
-        vc = jnp.take(v_pool[i], bt_row, axis=0).reshape(M * Bs, -1)
+        kc = _paged_view(k_pool, i, bt_row)
+        vc = _paged_view(v_pool, i, bt_row)
         if backend == "ring":
             attn = ring_prefill_attention(q, kc[:T], vc[:T], cfg.n_heads,
                                           offset, mesh, axis=tp_axis)
@@ -761,7 +788,7 @@ def verify_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
     the correction token, so every iteration emits at least the one
     token the plain step would have.
     """
-    S, K1 = toks.shape
+    K1 = toks.shape[1]
     Bs = k_pool.shape[2]
     M = block_tables.shape[1]
     T = M * Bs if t_logical is None else int(t_logical)
@@ -780,9 +807,8 @@ def verify_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
         q, k, v = x @ layer["w_q"], x @ layer["w_k"], x @ layer["w_v"]
         k_pool = k_pool.at[i, blk, off].set(k)
         v_pool = v_pool.at[i, blk, off].set(v)
-        kv_shape = (S, M * Bs, -1)
-        kc = jnp.take(k_pool[i], block_tables, axis=0).reshape(kv_shape)
-        vc = jnp.take(v_pool[i], block_tables, axis=0).reshape(kv_shape)
+        kc = _paged_view(k_pool, i, block_tables)
+        vc = _paged_view(v_pool, i, block_tables)
         h = h + _verify_attention(
             q, kc[:, :T], vc[:, :T], cfg.n_heads, pos) @ layer["w_o"]
         x = _rmsnorm(h, layer["ln2_g"])
@@ -1010,11 +1036,11 @@ def decode_step_paged_q(cfg: TransformerConfig, params: Dict[str, Any],
         v_pool, v_scales = v_pool.at[i].set(vp), v_scales.at[i].set(vs)
         kv_shape = (S, M * Bs, -1)
         kc = _kv_q_dequant(
-            jnp.take(k_pool[i], block_tables, axis=0),
+            _paged_view(k_pool, i, block_tables).reshape(S, M, Bs, -1),
             jnp.take(k_scales[i], block_tables, axis=0)
         ).astype(h.dtype).reshape(kv_shape)
         vc = _kv_q_dequant(
-            jnp.take(v_pool[i], block_tables, axis=0),
+            _paged_view(v_pool, i, block_tables).reshape(S, M, Bs, -1),
             jnp.take(v_scales[i], block_tables, axis=0)
         ).astype(h.dtype).reshape(kv_shape)
         h = h + _cached_attention(

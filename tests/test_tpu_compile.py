@@ -197,29 +197,32 @@ def test_ring_attention_pallas_compiles_on_four_chips(topo,
 
 
 # -- the decode engine's paged programs at the serving phase's shapes ----------
-@pytest.fixture(scope="module")
-def serving_shapes(one_chip):
+def _paged_shapes(one_chip, slots, per_slot, **lm):
     """(cfg, params, k_pool, v_pool, block_tables) as ShapeDtypeStructs on
-    one described chip, sized as ``register_decoder(slots=32,
-    max_prompt=1024, max_new=64)`` sizes them: T = 1088, 68 blocks of 16
-    per slot, a contiguous-equivalent pool plus the scratch block."""
+    one described chip: ``per_slot`` blocks of ``_BLOCK`` a slot, a
+    contiguous-equivalent pool plus the scratch block."""
     import jax
     import jax.numpy as jnp
 
     from multiverso_tpu.models.transformer import (TransformerConfig,
                                                    init_params)
 
-    cfg = TransformerConfig(dtype=jnp.bfloat16, **_LM)
+    cfg = TransformerConfig(dtype=jnp.bfloat16, **dict(_LM, **lm))
     on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                              sharding=one_chip)
     params = jax.tree.map(on_chip, jax.eval_shape(lambda: init_params(cfg)))
-    per_slot = -(-(_MAX_PROMPT + _MAX_NEW) // _BLOCK)
     pool = jax.ShapeDtypeStruct(
-        (cfg.n_layers, _SLOTS * per_slot + 1, _BLOCK, cfg.d_model),
+        (cfg.n_layers, slots * per_slot + 1, _BLOCK, cfg.d_model),
         cfg.dtype, sharding=one_chip)
-    tables = jax.ShapeDtypeStruct((_SLOTS, per_slot), jnp.int32,
-                                  sharding=one_chip)
-    return cfg, params, pool, pool, tables
+    return cfg, params, pool, pool, _ints(one_chip, slots, per_slot)
+
+
+@pytest.fixture(scope="module")
+def serving_shapes(one_chip):
+    """Sized as ``register_decoder(slots=32, max_prompt=1024,
+    max_new=64)`` sizes them: T = 1088, 68 blocks of 16 per slot."""
+    return _paged_shapes(one_chip, _SLOTS,
+                         -(-(_MAX_PROMPT + _MAX_NEW) // _BLOCK))
 
 
 def _ints(sharding, *shape):
@@ -237,40 +240,84 @@ def _fits_hbm(compiled, budget=16 * 2 ** 30) -> bool:
     return held < budget
 
 
-def test_decode_step_paged_compiles(one_chip, serving_shapes):
+def _compile_step(one_chip, shapes, t_logical):
+    """``decode_step_paged`` as the engine jits it: pools donated."""
     import jax
     import jax.numpy as jnp
 
     from multiverso_tpu.models.transformer import decode_step_paged
 
-    cfg, params, kc, vc, bt = serving_shapes
-    active = jax.ShapeDtypeStruct((_SLOTS,), jnp.bool_, sharding=one_chip)
-    compiled = _compile(
+    cfg, params, kc, vc, bt = shapes
+    slots = bt.shape[0]
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    return _compile(
         lambda p, kc, vc, bt, tok, pos, act: decode_step_paged(
-            cfg, p, kc, vc, bt, tok, pos, act,
-            t_logical=_MAX_PROMPT + _MAX_NEW),
-        params, kc, vc, bt, _ints(one_chip, _SLOTS), _ints(one_chip, _SLOTS),
+            cfg, p, kc, vc, bt, tok, pos, act, t_logical=t_logical),
+        params, kc, vc, bt, _ints(one_chip, slots), _ints(one_chip, slots),
         active, donate_argnums=(1, 2))
-    assert _fits_hbm(compiled)
+
+
+def _compile_chunk(one_chip, shapes, t_logical, chunk):
+    """``prefill_chunk_paged`` as the engine jits it: pools donated."""
+    from multiverso_tpu.models.transformer import prefill_chunk_paged
+
+    cfg, params, kc, vc, bt = shapes
+    return _compile(
+        lambda p, kc, vc, bt, slot, toks, off, n: prefill_chunk_paged(
+            cfg, p, kc, vc, bt, slot, toks, off, n, t_logical=t_logical),
+        params, kc, vc, bt, _ints(one_chip), _ints(one_chip, chunk),
+        _ints(one_chip), _ints(one_chip), donate_argnums=(1, 2))
+
+
+def _pools_aliased(compiled, pool) -> bool:
     # the engine donates both pools on the chip: the compiler must alias
     # them, or every token step copies the whole cache
-    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * np.prod(
-        kc.shape) * 2
+    return compiled.memory_analysis().alias_size_in_bytes >= 2 * np.prod(
+        pool.shape) * 2
+
+
+def test_decode_step_paged_compiles(one_chip, serving_shapes):
+    compiled = _compile_step(one_chip, serving_shapes,
+                             _MAX_PROMPT + _MAX_NEW)
+    assert _fits_hbm(compiled)
+    assert _pools_aliased(compiled, serving_shapes[2])
 
 
 def test_prefill_chunk_paged_compiles(one_chip, serving_shapes):
-    from multiverso_tpu.models.transformer import prefill_chunk_paged
-
-    cfg, params, kc, vc, bt = serving_shapes
-    compiled = _compile(
-        lambda p, kc, vc, bt, slot, toks, off, n: prefill_chunk_paged(
-            cfg, p, kc, vc, bt, slot, toks, off, n,
-            t_logical=_MAX_PROMPT + _MAX_NEW),
-        params, kc, vc, bt, _ints(one_chip), _ints(one_chip, _CHUNK),
-        _ints(one_chip), _ints(one_chip), donate_argnums=(1, 2))
+    compiled = _compile_chunk(one_chip, serving_shapes,
+                              _MAX_PROMPT + _MAX_NEW, _CHUNK)
     assert _fits_hbm(compiled)
-    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * np.prod(
-        kc.shape) * 2
+    assert _pools_aliased(compiled, serving_shapes[2])
+
+
+# the serving cell's shapes (benchmarks/traffic/closed128.json on
+# benchmarks/configs/gpt2-small.json): 128 slots of 64 blocks of 16, the
+# 50,257-wide head, 512-token chunks, T = 896 + 128
+_CELL = dict(slots=128, per_slot=64, vocab_size=50257, max_seq=1024)
+_CELL_T, _CELL_CHUNK = 1024, 512
+# what must not come back: a float32 copy of the slots x T view (the
+# one-token products were no matrix products to the compiler) and a copy
+# of one layer's whole pool (``pool[i]`` of a pool just scattered into)
+_CELL_FORBIDDEN = ("= f32[128,64,16,768]", "= f32[128,1024,768]",
+                   "= f32[128,1024,12,64]", "= bf16[8193,16,768]")
+
+
+@pytest.mark.parametrize("program,temp_mb", [("step", 650), ("chunk", 50)])
+def test_serving_cell_programs_move_kv_once(one_chip, program, temp_mb):
+    """``decode_step_paged`` and ``prefill_chunk_paged`` at the CELL's
+    shapes: K and V go from the pool to the attention products once, in
+    bfloat16 (1,230 MB and 222 MB of temporaries before that)."""
+    shapes = _paged_shapes(one_chip, **_CELL)
+    if program == "step":
+        compiled = _compile_step(one_chip, shapes, _CELL_T)
+    else:
+        compiled = _compile_chunk(one_chip, shapes, _CELL_T, _CELL_CHUNK)
+    text = compiled.as_text()
+    for array in _CELL_FORBIDDEN:
+        assert array not in text, array
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 10 ** 6
+    assert _pools_aliased(compiled, shapes[2])
+    assert _fits_hbm(compiled)
 
 
 def test_verify_step_paged_compiles(one_chip, serving_shapes):

@@ -5,6 +5,7 @@ framework's parallelism-showcase model family.)"""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from multiverso_tpu.models.transformer import (TransformerConfig,
                                                TransformerLM, forward,
@@ -169,3 +170,67 @@ def test_lm_app_cli(mv_session, tmp_path, monkeypatch):
     t6 = _os.path.getmtime(_os.path.join(ckpt, "step_6", "manifest.json"))
     t9 = _os.path.getmtime(_os.path.join(ckpt, "step_9", "manifest.json"))
     assert t6 < t9 and (t9 - t6) > 1.0   # step_6 untouched by run 2
+
+
+# -- serving: the path of K and V from the paged pool to the attention --------
+def _per_head_attention(q, k, v, n_heads, pos):
+    """The plain form: per (example, head) one row of scores, float32."""
+    B, D = q.shape
+    T, dh = k.shape[1], D // n_heads
+    qh = q.astype(jnp.float32).reshape(B, n_heads, dh)
+    kh = k.astype(jnp.float32).reshape(B, T, n_heads, dh)
+    vh = v.astype(jnp.float32).reshape(B, T, n_heads, dh)
+    scores = (qh[:, None] * kh).sum(-1).transpose(0, 2, 1) / np.sqrt(dh)
+    live = jnp.arange(T)[None, None, :] <= pos[:, None, None]
+    probs = jax.nn.softmax(jnp.where(live, scores, -1e30), axis=-1)
+    # the program rounds the probabilities to the cache's type: so here
+    probs = probs.astype(v.dtype).astype(jnp.float32)
+    out = (probs.transpose(0, 2, 1)[..., None] * vh).sum(1)
+    return out.reshape(B, D)
+
+
+@pytest.mark.parametrize("n_heads", [1, 4, 12])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_cached_attention_matches_per_head_reference(dtype, n_heads):
+    from multiverso_tpu.models.transformer import _cached_attention
+
+    B, T, dh = 5, 24, 8
+    D = n_heads * dh
+    rng = np.random.default_rng(n_heads)
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+               .astype(dtype)
+               for shape in ((B, D), (B, T, D), (B, T, D)))
+    pos = jnp.asarray([0, T - 1, 7, 1, T - 2], jnp.int32)   # ragged
+    got = _cached_attention(q, k, v, n_heads, pos)
+    assert got.dtype == q.dtype and got.shape == (B, D)
+    want = np.asarray(_per_head_attention(q, k, v, n_heads, pos))
+    got = np.asarray(got.astype(jnp.float32))
+    if dtype == "float32":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    else:
+        # the rounding of one bfloat16 output: half a unit in its last
+        # place, at most 2**-8 of the value (8 significant bits)
+        np.testing.assert_array_less(
+            np.abs(got - want), 2.0 ** -8 * np.abs(want) + 1e-6)
+    # pos 0 attends the first entry alone
+    np.testing.assert_allclose(
+        got[0], np.asarray(v[0, 0].astype(jnp.float32)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("table_shape", [(6, 5), (5,)])
+def test_paged_view_equals_take_of_the_layer(table_shape, pool_dtype):
+    from multiverso_tpu.models.transformer import _paged_view
+
+    L, N, Bs, D = 3, 11, 4, 8
+    rng = np.random.default_rng(len(table_shape))
+    pool = jnp.asarray(rng.integers(-100, 100, (L, N, Bs, D)), pool_dtype)
+    tables = rng.integers(0, N, table_shape).astype(np.int32)
+    tables.flat[0], tables.flat[-1] = 0, N - 1   # scratch and last block
+    tables = jnp.asarray(tables)
+    for layer in range(L):
+        view = _paged_view(pool, layer, tables)
+        want = jnp.take(pool[layer], tables, axis=0).reshape(
+            table_shape[:-1] + (table_shape[-1] * Bs, D))
+        assert view.dtype == pool.dtype and view.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(view), np.asarray(want))
