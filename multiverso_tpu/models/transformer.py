@@ -1463,6 +1463,211 @@ def make_sharded_decode_programs(cfg: TransformerConfig, mesh,
     return progs
 
 
+def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
+    """``TransformerLM``'s side of the engine's seam
+    (``serving/programs.py``): two ``[L, N + 1, Bs, d_model]`` K/V
+    pools (contiguous: ``[L, S, T, d_model]`` strips; ``kv_quant="int8"``:
+    int8 pools and two ``[L, N + 1]`` float32 scale arrays) and the
+    programs of this module over them, each jitted ONCE here, at engine
+    construction (RT106). ``spec`` is the engine's resolved
+    :class:`serving.programs.EngineSpec`."""
+    from ..serving.block_pool import kv_bytes_per_block
+    from ..serving.programs import ServingPrograms
+    from ..serving.snapshot import (quantize_decode_params,
+                                    replicate_for_decode, shard_for_decode)
+
+    who = f"DecodeEngine {spec.name!r}"
+    T = spec.cache_len
+    if T > cfg.max_seq:
+        Log.fatal(f"{who}: max_prompt {spec.max_prompt} + "
+                  f"max_new {spec.max_new} exceeds max_seq {cfg.max_seq}")
+    L, D, S = cfg.n_layers, cfg.d_model, spec.slots
+    paged = spec.block_size > 0
+    quant = spec.kv_quant == "int8"
+    pool_shape = ((L, spec.pool_blocks + 1, spec.block_size, D) if paged
+                  else (L, S, T, D))
+    pool_dtype = jnp.dtype(jnp.int8 if quant else cfg.dtype)
+    pools = [(pool_shape, pool_dtype)] * 2
+    if quant:
+        # per-(layer, block) fp32 scales, one array per pool; zeros from
+        # birth: scale 0 marks a never-written block
+        pools += [((L, spec.pool_blocks + 1), jnp.dtype(jnp.float32))] * 2
+    n = len(pools)
+    out = ServingPrograms(
+        pools=tuple(pools), step=None,
+        bytes_per_block=(kv_bytes_per_block(
+            L, D, spec.block_size, np.dtype(cfg.dtype), quant=spec.kv_quant)
+            if paged else 0),
+        scale_pools=(2, 3) if quant else ())
+    prequant = (quantize_decode_params if spec.param_quant == "int8"
+                else (lambda value: value))
+
+    if spec.tp > 1:
+        # decode-mesh programs, pre-partitioned: every program is jitted
+        # ONCE with matched in/out_shardings, so the partitioner runs at
+        # compile and never again; params arrive resharded by the pin
+        # (shard_for_decode) and the pools round-trip with their
+        # sharding intact. Copy-on-write rides the same mesh.
+        validate_decode_tp(cfg, spec.tp, name=who)
+        progs = make_sharded_decode_programs(
+            cfg, spec.mesh, T, donate=spec.donate, kv_quant=spec.kv_quant,
+            param_quant=spec.param_quant, prefill_sp=spec.prefill_sp)
+        out.param_shardings = progs["param_shardings"]
+        # on a sharded engine the scales REPLICATE: [L, N] has no head
+        # slice to shard, and every shard needs every block's scale
+        out.pool_targets = (progs["pool_sharding"],) * 2 + (
+            (NamedSharding(spec.mesh, P()),) * 2 if quant else ())
+        out.admit, out.chunk, out.step = (progs["admit"], progs["chunk"],
+                                          progs["step"])
+        out.chunk_sp = progs.get("chunk_sp")
+        out.cow = progs["cow"] if spec.prefix else None
+        out.verify = progs["verify"] if spec.spec_k else None
+        shardings, mesh = out.param_shardings, spec.mesh
+        out.pin = lambda value: shard_for_decode(prequant(value), mesh,
+                                                 shardings)
+    else:
+        out.pin = lambda value: replicate_for_decode(prequant(value))
+        # cache donation is real only where XLA implements input
+        # aliasing; the quant programs thread (kc, vc, ksc, vsc) after
+        # params, so the donate tuple covers all of the pools
+        donate = tuple(range(1, n + 1)) if spec.donate else ()
+        cow_donate = tuple(range(n)) if spec.donate else ()
+        # param-dequant fold (decode_param_quant=int8): the pinned
+        # pytree arrives as {"q": int8, "s": fp32} leaves and every
+        # program dequantizes at COMPILE time
+        pf = ((lambda p: dequantize_decode_params(p, cfg.dtype))
+              if spec.param_quant == "int8" else (lambda p: p))
+        # every program wraps in a FRESH lambda: jit caches key on the
+        # function object, so jitting a shared module-level function
+        # directly would pool every engine's compiled traces on one
+        # handle and break per-engine one-trace accounting
+        if paged and quant:
+            out.admit = jax.jit(
+                lambda params, kc, vc, ksc, vsc, bts, toks, lengths:
+                admit_insert_paged_q(cfg, pf(params), kc, vc, ksc, vsc,
+                                     bts, toks, lengths),
+                donate_argnums=donate)
+            out.chunk = jax.jit(
+                lambda params, kc, vc, ksc, vsc, bt, slot, toks, off, n:
+                prefill_chunk_paged_q(cfg, pf(params), kc, vc, ksc, vsc,
+                                      bt, slot, toks, off, n, t_logical=T),
+                donate_argnums=donate)
+            out.step = jax.jit(
+                lambda params, kc, vc, ksc, vsc, bt, tok, pos, active:
+                decode_step_paged_q(cfg, pf(params), kc, vc, ksc, vsc, bt,
+                                    tok, pos, active, t_logical=T),
+                donate_argnums=donate)
+            if spec.spec_k:
+                out.verify = jax.jit(
+                    lambda params, kc, vc, ksc, vsc, bt, toks, pos,
+                    active, nv:
+                    verify_step_paged_q(cfg, pf(params), kc, vc, ksc, vsc,
+                                        bt, toks, pos, active, nv,
+                                        t_logical=T),
+                    donate_argnums=donate)
+            if spec.prefix:
+                # the scale columns duplicate WITH the block: a CoW'd
+                # block must dequantize identically to its src
+                out.cow = jax.jit(
+                    lambda kc, vc, ksc, vsc, src, dst:
+                    cow_block_copy_q(kc, vc, ksc, vsc, src, dst),
+                    donate_argnums=cow_donate)
+        elif paged:
+            # block tables ride every call as DATA ([S, M] int32, fixed
+            # shape): which blocks a slot owns never touches an aval, so
+            # the one-trace-per-config invariant survives paging
+            out.admit = jax.jit(
+                lambda params, kc, vc, bts, toks, lengths:
+                admit_insert_paged(cfg, pf(params), kc, vc, bts, toks,
+                                   lengths),
+                donate_argnums=donate)
+            out.chunk = jax.jit(
+                lambda params, kc, vc, bt, slot, toks, off, n:
+                prefill_chunk_paged(cfg, pf(params), kc, vc, bt, slot,
+                                    toks, off, n, t_logical=T),
+                donate_argnums=donate)
+            if spec.prefill_sp != "none":
+                # tp=1 seqpar rides a ONE-device decode mesh: the
+                # collectives degenerate (n=1) but the shard_map path is
+                # genuinely exercised, and the chunk size equals the
+                # budget so the math coincides with the single-lane
+                # program exactly
+                from ..topology import make_mesh
+
+                sp_mesh = make_mesh((1,), axis_names=(DECODE_TP_AXIS,),
+                                    devices=jax.devices()[:1])
+                sp_backend = spec.prefill_sp
+                out.chunk_sp = jax.jit(
+                    lambda params, kc, vc, bt, slot, toks, off, n:
+                    prefill_chunk_paged_sp(cfg, pf(params), kc, vc, bt,
+                                           slot, toks, off, n, sp_mesh,
+                                           sp_backend, t_logical=T,
+                                           tp_axis=DECODE_TP_AXIS),
+                    donate_argnums=donate)
+            out.step = jax.jit(
+                lambda params, kc, vc, bt, tok, pos, active:
+                decode_step_paged(cfg, pf(params), kc, vc, bt, tok, pos,
+                                  active, t_logical=T),
+                donate_argnums=donate)
+            if spec.spec_k:
+                # the fixed-K verify step: the [S, spec_k + 1] window is
+                # the only static: ONE compiled trace serves every draft
+                # mix and acceptance outcome
+                out.verify = jax.jit(
+                    lambda params, kc, vc, bt, toks, pos, active, nv:
+                    verify_step_paged(cfg, pf(params), kc, vc, bt, toks,
+                                      pos, active, nv, t_logical=T),
+                    donate_argnums=donate)
+            if spec.prefix:
+                # copy-on-write: duplicate one block (both pools) before
+                # a write lands in a shared one; src/dst traced scalars
+                out.cow = jax.jit(
+                    lambda kc, vc, src, dst: cow_block_copy(kc, vc, src,
+                                                            dst),
+                    donate_argnums=cow_donate)
+        else:
+            def _admit_insert(params, kc, vc, slots, toks, lengths):
+                logits, ks, vs = prefill(cfg, pf(params), toks)
+                last = jnp.take_along_axis(
+                    logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
+                first = jnp.argmax(last, axis=-1).astype(toks.dtype)
+                kc, vc = cache_insert(kc, vc, slots, ks, vs)
+                return first, kc, vc
+
+            out.admit = jax.jit(_admit_insert, donate_argnums=donate)
+            out.chunk = jax.jit(
+                lambda params, kc, vc, slot, toks, off, n: prefill_chunk(
+                    cfg, pf(params), kc, vc, slot, toks, off, n),
+                donate_argnums=donate)
+            # THE fused step: all shapes fixed by the engine config ->
+            # exactly one compiled trace no matter which slots are live
+            out.step = jax.jit(
+                lambda params, kc, vc, tok, pos, active: decode_step(
+                    cfg, pf(params), kc, vc, tok, pos, active),
+                donate_argnums=donate)
+
+    # -- KV transfer plane (disaggregated prefill/decode) --------------------
+    # two programs, prefix-cache engines only: FETCH pulls one block's
+    # slices off the pools (host-materialized into the wire payload),
+    # SPLICE writes one received block into a freshly allocated pool
+    # slot. The block id is a TRACED scalar in both: one compiled trace
+    # each. Splice donates like the step/CoW; fetch cannot (the pools
+    # survive it).
+    if spec.prefix:
+        out.fetch = jax.jit(
+            lambda *a: tuple(
+                jax.lax.dynamic_index_in_dim(pool, a[n], axis=1,
+                                             keepdims=False)
+                for pool in a[:n]))
+        out.splice = jax.jit(
+            lambda *a: tuple(
+                jax.lax.dynamic_update_index_in_dim(pool, piece, a[n],
+                                                    axis=1)
+                for pool, piece in zip(a[:n], a[n + 1:])),
+            donate_argnums=tuple(range(n)) if spec.donate else ())
+    return out
+
+
 def cache_insert(k_cache: jax.Array, v_cache: jax.Array, slots: jax.Array,
                  ks: jax.Array, vs: jax.Array
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -1624,3 +1829,8 @@ class TransformerLM:
     def logits(self, tokens: np.ndarray) -> jax.Array:
         return forward(self.config, self.params,
                        jnp.asarray(tokens, jnp.int32))
+
+    def serving_programs(self, spec) -> Any:
+        """The decode engine's seam: cache layout and paged programs
+        (:func:`make_serving_programs`)."""
+        return make_serving_programs(self.config, spec)

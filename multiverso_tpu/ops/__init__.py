@@ -3,8 +3,8 @@
 from .embedding import embedding_lookup, scatter_add_rows, segment_mean_rows
 from .flash_attention import (flash_attention, flash_attention_partial,
                               merge_partials)
-from .moe import (EXPERT_AXIS, init_moe_params, mlp_expert, moe_apply,
-                  top1_gating)
+from .moe import (EXPERT_AXIS, held_expert_layer, init_moe_params, mlp_expert,
+                  moe_apply, route_topk, swiglu, top1_gating)
 from .ring_attention import (reference_attention, ring_attention,
                              ring_prefill_attention)
 from .ulysses import ulysses_attention, ulysses_prefill_attention
@@ -17,6 +17,9 @@ __all__ = [
     "flash_attention_partial",
     "merge_partials",
     "EXPERT_AXIS",
+    "held_expert_layer",
+    "route_topk",
+    "swiglu",
     "init_moe_params",
     "mlp_expert",
     "moe_apply",

@@ -19,6 +19,13 @@ canonical Switch-Transformer dispatch):
 
 Everything is expressed with einsums over one-hot tensors, so the layer is
 differentiable end-to-end (gate weights carry the gradient through routing).
+
+The second half of the module is the serving-side expert layer of a chip
+that holds a SHARE of a layer's experts (:func:`route_topk`,
+:func:`held_expert_layer`): top-k routing over every router output with
+no capacity and no dropped token, identity ("zero-compute") experts, and
+the part of the layer's result that the held experts give. It runs
+without an exchange: what the absent experts would add is left out.
 """
 
 from __future__ import annotations
@@ -154,3 +161,96 @@ def init_moe_params(rng: np.random.Generator, n_experts: int, d_model: int,
             dtype),
     }
     return router_w, expert_params
+
+
+# -- serving: one chip's share of a top-k expert layer -------------------------
+# ``held_expert_layer``'s counts: this many scalars, then a load a held expert
+COUNT_SCALARS = 4
+
+
+def route_topk(u: jax.Array, router_w: jax.Array, router_bias: jax.Array,
+               top_k: int, scale: float) -> Tuple[jax.Array, jax.Array]:
+    """Softmax router over ALL of the layer's outputs, in float32.
+
+    ``p = softmax(float32(u) @ router_w)``; the ``top_k`` largest of
+    ``p + router_bias`` are chosen (the bias moves the choice, never the
+    gate); a chosen output's gate is ``scale * p`` and is not
+    renormalised. Returns ``(idx [T, k] int32, gates [T, k] float32)``.
+    No capacity: every token keeps all of its picks."""
+    logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    _, idx = jax.lax.top_k(p + router_bias.astype(jnp.float32), top_k)
+    gates = scale * jnp.take_along_axis(p, idx, axis=-1)
+    return idx.astype(jnp.int32), gates
+
+
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+           w_down: jax.Array) -> jax.Array:
+    """``(silu(x w_gate) * (x w_up)) w_down``, float32 accumulation,
+    result in float32."""
+    f32 = jnp.float32
+    g = jnp.dot(x, w_gate, preferred_element_type=f32)
+    v = jnp.dot(x, w_up, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * v).astype(x.dtype)
+    return jnp.dot(h, w_down, preferred_element_type=f32)
+
+
+def held_expert_layer(u: jax.Array, idx: jax.Array, gates: jax.Array,
+                      experts: Any, n_ffn_experts: int, expert_offset: int,
+                      identity: bool = True, valid: jax.Array | None = None
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """This chip's part of ``sum_i g_i Expert_i(u)`` for tokens ``u``
+    [T, D] routed by :func:`route_topk`.
+
+    Router outputs ``< n_ffn_experts`` are SwiGLU experts, of which this
+    chip holds ``experts["w_gate"].shape[0]`` starting at
+    ``expert_offset`` (``experts``: ``w_gate``/``w_up`` [E, D, F],
+    ``w_down`` [E, F, D]); outputs ``>= n_ffn_experts`` are identity
+    experts, which hold no weights and are applied where the token lives
+    (``identity=False`` leaves them to another share, so that shares can
+    be summed with the identity part counted once). Picks of absent
+    experts add nothing.
+
+    Dense over the held experts, with a gate mask: EVERY held expert
+    runs over EVERY token (one batched product a matrix), and its output
+    is multiplied by the token's gate, exact zero where the token did
+    not pick it. Nothing is sorted or grouped, so the picks change no
+    shape and no FLOP: no token can be dropped, and on one chip's share
+    a decode step's products are bound by reading the held experts'
+    weights, whatever the routing; a prefill chunk pays ``E x T`` rows
+    where routing sends ``~T k E / outputs`` (a sorted or ragged product
+    is the fix, PERF.md section 7).
+
+    Returns ``(y [T, D] float32, counts [COUNT_SCALARS + E] float32)``: over the
+    ``valid`` tokens, their number, their picks of FFN experts, of
+    identity experts, of HELD experts, and each held expert's load."""
+    f32 = jnp.float32
+    E = experts["w_gate"].shape[0]
+    T = u.shape[0]
+    local = idx - expert_offset                                   # [T, k]
+    held = (local >= 0) & (local < E) & (idx < n_ffn_experts)
+    # [T, E] gate of each held expert for each token (0 = not picked)
+    gate_held = jnp.sum(
+        jnp.where(held[..., None],
+                  jax.nn.one_hot(local, E, dtype=f32) * gates[..., None],
+                  0.0), axis=1)
+    g = jnp.einsum("td,edf->etf", u, experts["w_gate"],
+                   preferred_element_type=f32)
+    v = jnp.einsum("td,edf->etf", u, experts["w_up"],
+                   preferred_element_type=f32)
+    h = jax.nn.silu(g) * v * gate_held.T[:, :, None]
+    y = jnp.einsum("etf,efd->td", h.astype(u.dtype), experts["w_down"],
+                   preferred_element_type=f32)
+    is_id = idx >= n_ffn_experts
+    if identity:
+        y = y + jnp.sum(jnp.where(is_id, gates, 0.0), axis=-1,
+                        keepdims=True) * u.astype(f32)
+    live = (jnp.ones((T,), f32) if valid is None else valid.astype(f32))
+    counts = jnp.concatenate([
+        jnp.stack([jnp.sum(live),
+                   jnp.sum(live[:, None] * (~is_id)),
+                   jnp.sum(live[:, None] * is_id),
+                   jnp.sum(live[:, None] * held)]),
+        jnp.sum(live[:, None] * (gate_held > 0), axis=0)])
+    return y, counts

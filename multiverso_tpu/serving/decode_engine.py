@@ -159,11 +159,10 @@ from .batcher import (DeadlineExceededError, OverloadedError, bucket_for,
                       shape_buckets)
 from . import accounting
 from . import kv_transfer
-from .block_pool import (SCRATCH_BLOCK, BlockPool, chain_hashes,
-                         kv_bytes_per_block)
+from .block_pool import SCRATCH_BLOCK, BlockPool, chain_hashes
 from .flight_recorder import FlightRecorder
-from .snapshot import (SnapshotManager, quantize_decode_params,
-                       replicate_for_decode, shard_for_decode)
+from .programs import EngineSpec
+from .snapshot import SnapshotManager
 from .watchdog import EngineWatchdog, WatchdogConfig
 from .workloads import _jit_cache_size
 
@@ -661,29 +660,9 @@ class DecodeEngine:
 
     def __init__(self, name: str, lm, config: Optional[DecodeEngineConfig]
                  = None) -> None:
-        from ..models.transformer import (admit_insert_paged,
-                                          admit_insert_paged_q,
-                                          cache_insert, cow_block_copy,
-                                          cow_block_copy_q, decode_step,
-                                          decode_step_paged,
-                                          decode_step_paged_q,
-                                          dequantize_decode_params,
-                                          make_sharded_decode_programs,
-                                          prefill, prefill_chunk,
-                                          prefill_chunk_paged,
-                                          prefill_chunk_paged_q,
-                                          prefill_chunk_paged_sp,
-                                          verify_step_paged,
-                                          verify_step_paged_q)
-
         self.name = name
         self.config = config or DecodeEngineConfig()
-        cfg = lm.config
-        self._model_cfg = cfg
         ec = self.config
-        if ec.max_prompt + ec.max_new > cfg.max_seq:
-            Log.fatal(f"DecodeEngine {name!r}: max_prompt {ec.max_prompt} + "
-                      f"max_new {ec.max_new} exceeds max_seq {cfg.max_seq}")
         self._prompt_buckets = ec.resolved_prompt_buckets()
         if self._prompt_buckets[-1] < ec.max_prompt:
             Log.fatal(f"DecodeEngine {name!r}: largest prompt bucket "
@@ -692,7 +671,6 @@ class DecodeEngine:
         # admission-group batch buckets (an admission wave is <= slots)
         self._batch_buckets = shape_buckets(ec.slots)
         S = ec.slots
-        L, D = cfg.n_layers, cfg.d_model
         self._cache_len = ec.max_prompt + ec.max_new
         T = self._cache_len
 
@@ -754,14 +732,11 @@ class DecodeEngine:
         # (~10x step wall, the PR 2 gate this replaces)
         self._tp = int(ec._resolved("decode_tp"))
         self._decode_mesh = None
-        self._param_shardings = None     # decode-mesh pin target (tp > 1)
-        self._cache_sharding = None      # device_put target for the pools
         if self._tp < 1:
             Log.fatal(f"DecodeEngine {name!r}: decode_tp must be >= 1, "
                       f"got {self._tp}")
         if self._tp > 1:
-            from ..models.transformer import (DECODE_TP_AXIS,
-                                              validate_decode_tp)
+            from ..models.transformer import DECODE_TP_AXIS
             from ..topology import make_mesh
 
             if not self._paged:
@@ -769,7 +744,6 @@ class DecodeEngine:
                           f"needs the paged KV cache (kv_block_size > 0) "
                           f"— the sharded programs partition the block "
                           f"pools over the head slice of D")
-            validate_decode_tp(cfg, self._tp, name=f"DecodeEngine {name!r}")
             ndev = len(jax.devices())
             if self._tp > ndev:
                 Log.fatal(f"DecodeEngine {name!r}: decode_tp {self._tp} "
@@ -798,15 +772,6 @@ class DecodeEngine:
         # snapshot VERSION, so a drain/re-pin cycle (or a forced
         # re-publish) without a version move is copy-free (tested)
         self.pin_copies = 0
-
-        # cache donation is real only where XLA implements input aliasing
-        # (TPU/GPU). On CPU a donated arg forces a defensive copy AND a
-        # second compiled trace — measured 2.4 ms -> 22 ms per fused step
-        # — so the engine only donates off-CPU.
-        donate = (1, 2) if jax.default_backend() != "cpu" else ()
-        # quant programs thread (kc, vc, ksc, vsc) after params — the
-        # donate tuple shifts to cover all four pool arrays
-        q_donate = (1, 2, 3, 4) if donate else ()
 
         # -- jitted programs ------------------------------------------------
         # chunked admission budget: a fixed-size chunk prefilled straight
@@ -900,295 +865,57 @@ class DecodeEngine:
             Log.fatal(f"DecodeEngine {name!r}: negative sched_lookahead "
                       f"{self._lookahead}")
 
-        # fused admission: prefill a group of prompts (padded to a batch
-        # bucket x prompt bucket), gather each last REAL position's logits
-        # -> first tokens, and insert every prompt's K/V into its free
-        # slot, all in ONE dispatch. Placement is traced either way — slot
-        # indices for the contiguous DUS chain, per-row block tables for
-        # the paged scatter — so there is one trace per (batch bucket,
-        # prompt bucket), shared by every slot/block choice.
-        def _first_tokens(logits, lengths, dtype):
-            last = jnp.take_along_axis(
-                logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-            return jnp.argmax(last, axis=-1).astype(dtype)
-
-        if self._tp > 1:
-            # decode-mesh programs, pre-partitioned: every program is
-            # jitted ONCE here (construction time — the RT106 contract)
-            # with matched in/out_shardings, so the partitioner runs at
-            # compile and never again; params arrive resharded by the
-            # pin (shard_for_decode) and the pools round-trip with their
-            # sharding intact. Copy-on-write rides the same mesh: the
-            # one write that can touch a shared block stays one site.
-            progs = make_sharded_decode_programs(
-                cfg, self._decode_mesh, T, donate=bool(donate),
-                kv_quant=self._kv_quant_mode,
-                param_quant=self._param_quant,
-                prefill_sp=self._sp_backend if self._sp else "none")
-            self._param_shardings = progs["param_shardings"]
-            self._cache_sharding = progs["pool_sharding"]
-            self._admit_fn = progs["admit"]
-            self._chunk_fn = progs["chunk"]
-            # the seqpar chunk program rides the same builder (same
-            # matched in/out_shardings and donation as "chunk"), so the
-            # partitioner runs at compile time here too
-            self._chunk_sp_fn = progs.get("chunk_sp")
-            self._step_fn = progs["step"]
-            self._cow_fn = progs["cow"] if self._prefix else None
-            # the verify step pins and partitions like the fused step
-            # (the builder's in/out_shardings match); K rides the fixed
-            # [S, spec_k + 1] window shape, so dispatching it is one
-            # compiled trace exactly like the step
-            self._verify_fn = progs["verify"] if self._spec else None
-        else:
-            # param-dequant fold (decode_param_quant=int8): the pinned
-            # pytree arrives as {"q": int8, "s": fp32} leaves and every
-            # program dequantizes at COMPILE time — the call signatures,
-            # donation and trace counts are exactly the fp path's
-            pf = ((lambda p: dequantize_decode_params(p, cfg.dtype))
-                  if self._param_quant == "int8" else (lambda p: p))
-            if self._paged and self._kv_quant:
-                # quant admission threads both pools' scale arrays as
-                # traced data right after the pools themselves
-                def _admit_insert(params, kc, vc, ksc, vsc, bts, toks,
-                                  lengths):
-                    return admit_insert_paged_q(cfg, pf(params), kc, vc,
-                                                ksc, vsc, bts, toks,
-                                                lengths)
-            elif self._paged:
-                # the ONE paged admission body (prefill + last-real-
-                # position gather + table-scatter insert) lives in
-                # transformer.admit_insert_paged — the sharded variant
-                # jits the same function, so the two paths cannot drift
-                def _admit_insert(params, kc, vc, bts, toks, lengths):
-                    return admit_insert_paged(cfg, pf(params), kc, vc,
-                                              bts, toks, lengths)
-            else:
-                def _admit_insert(params, kc, vc, slots, toks, lengths):
-                    logits, ks, vs = prefill(cfg, pf(params), toks)
-                    first = _first_tokens(logits, lengths, toks.dtype)
-                    kc, vc = cache_insert(kc, vc, slots, ks, vs)
-                    return first, kc, vc
-
-            self._admit_fn = jax.jit(
-                _admit_insert,
-                donate_argnums=q_donate if self._kv_quant else donate)
-            if self._prefix:
-                # copy-on-write: duplicate one block (both pools) before
-                # a write lands in a shared one. src/dst are traced
-                # scalars — ONE compiled trace per engine config,
-                # dispatched host-side at admission before the table
-                # ever reaches the fused step.
-                # the lambda is load-bearing: jitting the shared
-                # module-level function directly would pool every
-                # engine's compile cache on one handle (jit caches key
-                # on the function object), breaking the per-engine
-                # one-trace accounting
-                if self._kv_quant:
-                    # the scale columns duplicate WITH the block — a
-                    # CoW'd block must dequantize identically to its src
-                    self._cow_fn = jax.jit(
-                        lambda kc, vc, ksc, vsc, src, dst:
-                        cow_block_copy_q(kc, vc, ksc, vsc, src, dst),
-                        donate_argnums=(0, 1, 2, 3) if donate else ())
-                else:
-                    self._cow_fn = jax.jit(
-                        lambda kc, vc, src, dst: cow_block_copy(
-                            kc, vc, src, dst),
-                        donate_argnums=(0, 1) if donate else ())
-            else:
-                self._cow_fn = None
-            if self._paged and self._kv_quant:
-                # the quant programs mirror the fp paged ones exactly —
-                # block tables AND scale arrays ride as fixed-shape
-                # data, so the one-trace-per-config invariant survives
-                # quantization the same way it survived paging
-                self._chunk_fn = jax.jit(
-                    lambda params, kc, vc, ksc, vsc, bt, slot, toks,
-                    off, n:
-                    prefill_chunk_paged_q(cfg, pf(params), kc, vc, ksc,
-                                          vsc, bt, slot, toks, off, n,
-                                          t_logical=T),
-                    donate_argnums=q_donate)
-                self._step_fn = jax.jit(
-                    lambda params, kc, vc, ksc, vsc, bt, tok, pos, active:
-                    decode_step_paged_q(cfg, pf(params), kc, vc, ksc,
-                                        vsc, bt, tok, pos, active,
-                                        t_logical=T),
-                    donate_argnums=q_donate)
-                if self._spec:
-                    self._verify_fn = jax.jit(
-                        lambda params, kc, vc, ksc, vsc, bt, toks, pos,
-                        active, nv:
-                        verify_step_paged_q(cfg, pf(params), kc, vc, ksc,
-                                            vsc, bt, toks, pos, active,
-                                            nv, t_logical=T),
-                        donate_argnums=q_donate)
-                else:
-                    self._verify_fn = None
-            elif self._paged:
-                # block tables ride every call as DATA ([S, M] int32,
-                # fixed shape): which blocks a slot owns never touches an
-                # aval, so the one-trace-per-config invariant survives
-                # paging. The gathered views are sliced to T inside the
-                # kernels, keeping the attention operand (and outputs)
-                # bit-identical to the contiguous layout's.
-                self._chunk_fn = jax.jit(
-                    lambda params, kc, vc, bt, slot, toks, off, n:
-                    prefill_chunk_paged(cfg, pf(params), kc, vc, bt, slot,
-                                        toks, off, n, t_logical=T),
-                    donate_argnums=donate)
-                if self._sp:
-                    # tp=1 seqpar rides a ONE-device decode mesh: the
-                    # collectives degenerate (n=1) but the shard_map
-                    # path is genuinely exercised, and the chunk size
-                    # equals the budget so the math coincides with the
-                    # single-lane program exactly
-                    from ..models.transformer import DECODE_TP_AXIS
-                    from ..topology import make_mesh
-
-                    sp_mesh = make_mesh(
-                        (1,), axis_names=(DECODE_TP_AXIS,),
-                        devices=jax.devices()[:1])
-                    sp_backend = self._sp_backend
-                    self._chunk_sp_fn = jax.jit(
-                        lambda params, kc, vc, bt, slot, toks, off, n:
-                        prefill_chunk_paged_sp(cfg, pf(params), kc, vc,
-                                               bt, slot, toks, off, n,
-                                               sp_mesh, sp_backend,
-                                               t_logical=T,
-                                               tp_axis=DECODE_TP_AXIS),
-                        donate_argnums=donate)
-                self._step_fn = jax.jit(
-                    lambda params, kc, vc, bt, tok, pos, active:
-                    decode_step_paged(cfg, pf(params), kc, vc, bt, tok,
-                                      pos, active, t_logical=T),
-                    donate_argnums=donate)
-                if self._spec:
-                    # the fixed-K verify step: the [S, spec_k + 1]
-                    # window is the only static — drafts, valid counts
-                    # and block tables are data, so ONE compiled trace
-                    # serves every draft mix and acceptance outcome
-                    # (fresh lambda per engine, same as the step)
-                    self._verify_fn = jax.jit(
-                        lambda params, kc, vc, bt, toks, pos, active, nv:
-                        verify_step_paged(cfg, pf(params), kc, vc, bt,
-                                          toks, pos, active, nv,
-                                          t_logical=T),
-                        donate_argnums=donate)
-                else:
-                    self._verify_fn = None
-            else:
-                self._verify_fn = None
-                self._chunk_fn = jax.jit(
-                    lambda params, kc, vc, slot, toks, off, n:
-                    prefill_chunk(
-                        cfg, pf(params), kc, vc, slot, toks, off, n),
-                    donate_argnums=donate)
-                # THE fused step: all shapes fixed by the engine config
-                # -> exactly one compiled trace no matter which slots
-                # are live
-                self._step_fn = jax.jit(
-                    lambda params, kc, vc, tok, pos, active: decode_step(
-                        cfg, pf(params), kc, vc, tok, pos, active),
-                    donate_argnums=donate)
-
-        # -- KV transfer plane (disaggregated prefill/decode) ---------------
-        # two construction-time programs, prefix-cache engines only (the
-        # transfer plane ships chain-addressed FULL blocks, so it rides
-        # the same gate): FETCH pulls one block's K/V slices off both
-        # pools (prefill side — the result is host-materialized into the
-        # wire payload), SPLICE writes one received block into a freshly
-        # allocated pool slot (decode side). The block id is a TRACED
-        # scalar in both, so each is exactly one compiled trace per
-        # engine (transfer_cache_size() asserts 2 after warmup) — a
-        # static index would recompile per pool position. Splice donates
-        # like the step/CoW (it reassigns both pools); fetch cannot
-        # donate (the pools survive it). Fresh lambdas per engine for
-        # the same per-engine compile-cache accounting as the CoW above.
-        if self._prefix and self._kv_quant:
-            # quant fetch/splice move the block's scale columns with its
-            # int8 bytes — same traced block id, same one-trace count;
-            # the [L] scale row updates in-place via the rank-reduced DUS
-            self._fetch_fn = jax.jit(
-                lambda kc, vc, ksc, vsc, b: (
-                    jax.lax.dynamic_index_in_dim(kc, b, axis=1,
-                                                 keepdims=False),
-                    jax.lax.dynamic_index_in_dim(vc, b, axis=1,
-                                                 keepdims=False),
-                    jax.lax.dynamic_index_in_dim(ksc, b, axis=1,
-                                                 keepdims=False),
-                    jax.lax.dynamic_index_in_dim(vsc, b, axis=1,
-                                                 keepdims=False)))
-            self._splice_fn = jax.jit(
-                lambda kc, vc, ksc, vsc, b, k, v, ks, vs: (
-                    jax.lax.dynamic_update_index_in_dim(kc, k, b, axis=1),
-                    jax.lax.dynamic_update_index_in_dim(vc, v, b, axis=1),
-                    jax.lax.dynamic_update_index_in_dim(ksc, ks, b,
-                                                        axis=1),
-                    jax.lax.dynamic_update_index_in_dim(vsc, vs, b,
-                                                        axis=1)),
-                donate_argnums=(0, 1, 2, 3) if donate else ())
-        elif self._prefix:
-            self._fetch_fn = jax.jit(
-                lambda kc, vc, b: (
-                    jax.lax.dynamic_index_in_dim(kc, b, axis=1,
-                                                 keepdims=False),
-                    jax.lax.dynamic_index_in_dim(vc, b, axis=1,
-                                                 keepdims=False)))
-            self._splice_fn = jax.jit(
-                lambda kc, vc, b, k, v: (
-                    jax.lax.dynamic_update_index_in_dim(kc, k, b, axis=1),
-                    jax.lax.dynamic_update_index_in_dim(vc, v, b, axis=1)),
-                donate_argnums=(0, 1) if donate else ())
-        else:
-            self._fetch_fn = None
-            self._splice_fn = None
+        # -- the model's side: cache layout and programs -------------------
+        # the engine resolved ITS knobs above; what a cache row is and
+        # how a token is computed against it is the model's
+        # (serving/programs.py). Every program is jitted there, ONCE,
+        # inside this constructor (the RT106 contract); a feature the
+        # model lacks is refused there, by name. Cache donation is real
+        # only where XLA implements input aliasing (TPU/GPU): on CPU a
+        # donated arg forces a defensive copy AND a second compiled
+        # trace (measured 2.4 ms -> 22 ms per fused step)
+        progs = lm.serving_programs(EngineSpec(
+            name=name, slots=S, max_prompt=ec.max_prompt,
+            max_new=ec.max_new, cache_len=T, block_size=self._block_size,
+            blocks_per_seq=self._blocks_per_seq,
+            pool_blocks=self._pool.capacity if self._paged else 0,
+            budget=self._budget, prefix=self._prefix, tp=self._tp,
+            mesh=self._decode_mesh, kv_quant=self._kv_quant_mode,
+            param_quant=self._param_quant, spec_k=self._spec,
+            prefill_sp=self._sp_backend if self._sp else "none",
+            donate=jax.default_backend() != "cpu"))
+        self._progs = progs
+        self._admit_fn = progs.admit
+        self._chunk_fn = progs.chunk
+        self._chunk_sp_fn = progs.chunk_sp
+        self._step_fn = progs.step
+        self._cow_fn = progs.cow
+        self._verify_fn = progs.verify
+        # the KV transfer plane's two programs (prefix-cache engines of
+        # a model that has them): one compiled trace each
+        self._fetch_fn = progs.fetch
+        self._splice_fn = progs.splice
+        if self._budget > 0 and self._chunk_fn is None:
+            Log.fatal(f"DecodeEngine {name!r}: the model has no prefill "
+                      f"chunk program (prefill_token_budget > 0)")
+        if self._budget == 0 and self._admit_fn is None:
+            Log.fatal(f"DecodeEngine {name!r}: the model has no monolithic "
+                      f"admission program (prefill_token_budget = 0)")
 
         # -- device state (owned by the loop thread after start) -------------
-        # committed placement from birth: warmup scratch caches use the
-        # same put, so the traces warmup compiles ARE the serving traces
-        # (an uncommitted zeros here would retrace on the first live call)
-        if self._paged:
-            cache_shape = (L, self._pool.capacity + 1, self._block_size, D)
-        else:
-            cache_shape = (L, S, T, D)
-        # mesh-aware placement: sharded engines commit the pools to the
-        # decode mesh's pool sharding (matching the programs'
-        # in_shardings — a plain devices()[0] put would be rejected as
-        # an incompatible committed placement); replicated engines keep
-        # the single-device put
-        self._cache_target = (self._cache_sharding
-                              if self._cache_sharding is not None
-                              else jax.devices()[0])
-        cache_dtype = jnp.int8 if self._kv_quant else cfg.dtype
-        self._k_cache = jax.device_put(
-            jnp.zeros(cache_shape, cache_dtype), self._cache_target)
-        self._v_cache = jax.device_put(
-            jnp.zeros(cache_shape, cache_dtype), self._cache_target)
-        if self._kv_quant:
-            # per-(layer, block) fp32 scales, one array per pool. Zeros
-            # from birth: scale 0 marks a never-written block (the
-            # kernels' zero-divide guard dequantizes it as exact zeros),
-            # which is also what quant_scale_blocks counts against. On a
-            # sharded engine the scales REPLICATE — [L, N] has no head
-            # slice to shard, and every shard needs every block's scale
-            scale_shape = (L, self._pool.capacity + 1)
-            if self._cache_sharding is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                self._scale_target = NamedSharding(self._decode_mesh,
-                                                   PartitionSpec())
-            else:
-                self._scale_target = jax.devices()[0]
-            self._k_scales = jax.device_put(
-                jnp.zeros(scale_shape, jnp.float32), self._scale_target)
-            self._v_scales = jax.device_put(
-                jnp.zeros(scale_shape, jnp.float32), self._scale_target)
-        else:
-            self._scale_target = None
-            self._k_scales = None
-            self._v_scales = None
+        # committed placement from birth: the programs' traces are
+        # compiled against operands that carry it (an uncommitted zeros
+        # here would retrace on the first live call). Sharded engines
+        # commit each pool to the placement the model names (matching
+        # the programs' in_shardings); others to the first device
+        self._pool_targets = (progs.pool_targets
+                              or (jax.devices()[0],) * len(progs.pools))
+        self._pools = tuple(
+            jax.device_put(jnp.zeros(shape, dtype), target)
+            for (shape, dtype), target in zip(progs.pools,
+                                              self._pool_targets))
+        # window base of the model's own counters (reset_stats)
+        self._counters_base = None
         # -- host state -----------------------------------------------------
         self._slot_req: List[Optional[_Request]] = [None] * S
         # explicit free-slot set, maintained at admit/complete (the loop
@@ -1213,11 +940,11 @@ class DecodeEngine:
         # hostage to force pool pressure; excluded from the watchdog's
         # leaked-reservation heuristic
         self._squeezed: List[int] = []
-        # inbound KV transfers awaiting the loop thread: the caches are
-        # loop-thread-owned (donation reassigns them per dispatch), so
-        # splice() parks (payload, done-event, out-dict) triples here
-        # and the loop applies them between iterations
-        self._splice_q: Deque = collections.deque()
+        # work awaiting the loop thread: the pools are loop-thread-owned
+        # (donation reassigns them per dispatch), so splice() and
+        # warmup() park (work, done-event, out-dict) triples here and
+        # the loop runs them between iterations
+        self._loop_work: Deque = collections.deque()
         self._lock = lockwatch.lock("serving.DecodeEngine._lock")
         self._cv = threading.Condition(self._lock)
         self._stop = threading.Event()
@@ -1328,10 +1055,7 @@ class DecodeEngine:
         if bool(ec._resolved("cost_ledger")):
             self.ledger = accounting.CostLedger(
                 name,
-                block_bytes=(kv_bytes_per_block(
-                    cfg.n_layers, cfg.d_model, self._block_size,
-                    np.dtype(cfg.dtype), quant=self._kv_quant_mode)
-                    if self._paged else 0))
+                block_bytes=progs.bytes_per_block)
         # per-iteration scratch the recorder drains (reused, not realloc'd)
         self._it_admitted: List[int] = []
         self._it_completed: List[int] = []
@@ -1502,7 +1226,7 @@ class DecodeEngine:
         exactly the prefix-cache gate (paged + chunked + prefix_cache):
         without the content index there is nothing to splice INTO, and
         without chunked prefill nothing block-granular to fetch FROM."""
-        return self._prefix
+        return self._prefix and self._fetch_fn is not None
 
     def submit_prefill(self, prompt: np.ndarray,
                        known_hashes: Sequence[str] = (),
@@ -1524,7 +1248,8 @@ class DecodeEngine:
             raise RuntimeError(
                 f"decode engine {self.name!r} cannot serve prefill-only "
                 f"admissions (needs paged KV + chunked prefill + "
-                f"prefix_cache — the transfer plane's gate)")
+                f"prefix_cache, and a model with KV transfer programs "
+                f"— the transfer plane's gate)")
         self.validate(prompt, None)
         p = np.asarray(prompt, np.int32).ravel()
         # max_new=1 keeps the reservation arithmetic in-range; the
@@ -1568,7 +1293,7 @@ class DecodeEngine:
         accounting ``{"xfer_blocks", "xfer_bytes", "dedup_blocks"}``
         (plus ``"skipped"`` when nothing could apply). BLOCKING and
         thread-safe: the caches are loop-thread-owned, so the payload
-        parks on ``_splice_q`` and the loop applies it between
+        parks on ``_loop_work`` and the loop applies it between
         iterations — callers (the replica's drain thread) wait so the
         follow-up ``submit`` of the same prompt is guaranteed to see
         the warm prefix. Degrades, never raises: an unsupported engine,
@@ -1583,12 +1308,13 @@ class DecodeEngine:
         with self._cv:
             if self._stop.is_set():
                 return dict(zero, skipped="stopped")
-            self._splice_q.append((payload, done, info))
+            self._loop_work.append(
+                (lambda: self._apply_splice(payload), done, info))
             self._cv.notify()
         if not done.wait(timeout_s):
             return dict(zero, skipped="timeout")
         out = dict(zero)
-        out.update(info)
+        out.update((k, v) for k, v in info.items() if k != "error")
         return out
 
     def queue_depth(self) -> int:
@@ -1788,7 +1514,7 @@ class DecodeEngine:
             with self._cv:
                 while (not self._q and self._pf is None
                        and not self._active.any()
-                       and not self._splice_q
+                       and not self._loop_work
                        and not self._stop.is_set()):
                     with trace.phase("engine.wait"):
                         self._cv.wait()
@@ -1797,8 +1523,8 @@ class DecodeEngine:
                     # release any splice waiters before the loop dies —
                     # a blocked replica drain thread must not hang on a
                     # transfer the loop will never apply
-                    while self._splice_q:
-                        _, done, info = self._splice_q.popleft()
+                    while self._loop_work:
+                        _, done, info = self._loop_work.popleft()
                         info["skipped"] = "stopped"
                         done.set()
                     return
@@ -1813,16 +1539,16 @@ class DecodeEngine:
                 # outlive this lock's block, so they are entered and
                 # left by hand
                 it_phase = admit_phase = trace.NULL_SPAN
-                sure = bool(self._splice_q or self._pf is not None
+                sure = bool(self._loop_work or self._pf is not None
                             or self._active.any())
                 if sure:
                     it_phase = trace.phase("engine.iter")
                     it_phase.__enter__()
                     admit_phase = trace.phase("engine.admit")
                     admit_phase.__enter__()
-                if self._splice_q:
-                    splices = list(self._splice_q)
-                    self._splice_q.clear()
+                if self._loop_work:
+                    splices = list(self._loop_work)
+                    self._loop_work.clear()
                 # admission pops through the weighted-fair lane
                 # scheduler (expired deadlines dropped at pop,
                 # bounded lookahead past a block-starved head) onto
@@ -1879,11 +1605,12 @@ class DecodeEngine:
                 # this (loop) thread — the only thread allowed to
                 # reassign the donated caches. A bad payload degrades
                 # (accounting says so); the waiter is released either way
-                for payload, done, info in splices:
+                for work, done, info in splices:
                     try:
-                        info.update(self._apply_splice(payload))
+                        info.update(work())
                     except Exception as exc:    # pragma: no cover
-                        info["skipped"] = f"splice failed: {exc}"
+                        info["skipped"] = f"failed on the loop thread: {exc}"
+                        info["error"] = exc
                     finally:
                         done.set()
                     worked = True
@@ -2017,6 +1744,17 @@ class DecodeEngine:
             # sequence-parallel program
             self._it_sp_chunks if self._sp else -1))
 
+    def _tables_arg(self) -> tuple:
+        """The block tables as the programs take them: one traced
+        ``[S, M]`` argument on a paged engine, none on strips."""
+        return (self._block_tables,) if self._paged else ()
+
+    def _xfer_block_shape(self) -> tuple:
+        """One block of the first pool as the transfer plane ships it:
+        ``(layers, block_size, width)``."""
+        shape = self._pools[0].shape
+        return (int(shape[0]), self._block_size, int(shape[3]))
+
     def _seed_for(self, version: int) -> bytes:
         """Hash-chain seed for a pinned snapshot version. kv_quant tags
         the seed: cached K/V bytes are a function of (token prefix,
@@ -2063,20 +1801,13 @@ class DecodeEngine:
                 # the pre-partitioned programs' in_shardings exactly
                 with trace.span("snapshot.pin", engine=self.name,
                                 version=snap.version):
-                    # decode_param_quant=int8: quantize HOST-side before
-                    # the device_put — the pin ships ~4x fewer bytes and
-                    # the programs dequantize at compile time. Host
-                    # numpy on purpose: this runs on the loop thread,
-                    # where building a jit would be an RT106 hazard.
-                    value = (quantize_decode_params(snap.value)
-                             if self._param_quant == "int8"
-                             else snap.value)
-                    if self._tp > 1:
-                        self._pinned = shard_for_decode(
-                            value, self._decode_mesh,
-                            self._param_shardings)
-                    else:
-                        self._pinned = replicate_for_decode(value)
+                    # the model's pin (serving/programs.py): a replica
+                    # on one device, a reshard onto the decode mesh
+                    # (host-quantized first under decode_param_quant=
+                    # int8: host numpy on purpose, building a jit on the
+                    # loop thread would be an RT106 hazard), or, for a
+                    # serve-only model, the weights themselves
+                    self._pinned = self._progs.pin(snap.value)
                 self._pinned_version = snap.version
                 self.pin_copies += 1
             self._snap = snap
@@ -2132,16 +1863,8 @@ class DecodeEngine:
             if req.full_hit and not req.pf_only:
                 shared_last = matched[-1]
                 dup = self._pool.alloc(1)[0]
-                if self._kv_quant:
-                    (self._k_cache, self._v_cache, self._k_scales,
-                     self._v_scales) = self._cow_fn(
-                        self._k_cache, self._v_cache, self._k_scales,
-                        self._v_scales, np.int32(shared_last),
-                        np.int32(dup))
-                else:
-                    self._k_cache, self._v_cache = self._cow_fn(
-                        self._k_cache, self._v_cache,
-                        np.int32(shared_last), np.int32(dup))
+                self._pools = tuple(self._cow_fn(
+                    *self._pools, np.int32(shared_last), np.int32(dup)))
                 self._pool.decref([shared_last])
                 matched[-1] = dup
                 full_hit_cow = True
@@ -2301,22 +2024,11 @@ class DecodeEngine:
         toks[: n] = req.prompt[off: off + n]
         tracing = trace.enabled()
         t0 = time.monotonic() if tracing else 0.0
-        if self._paged and self._kv_quant:
-            (self._k_cache, self._v_cache, self._k_scales,
-             self._v_scales, logits) = self._chunk_fn(
-                self._pinned, self._k_cache, self._v_cache,
-                self._k_scales, self._v_scales, self._block_tables,
-                np.int32(req.slot), toks, np.int32(off), np.int32(n))
-        elif self._paged:
-            chunk_fn = self._chunk_sp_fn if sp else self._chunk_fn
-            self._k_cache, self._v_cache, logits = chunk_fn(
-                self._pinned, self._k_cache, self._v_cache,
-                self._block_tables, np.int32(req.slot), toks,
-                np.int32(off), np.int32(n))
-        else:
-            self._k_cache, self._v_cache, logits = self._chunk_fn(
-                self._pinned, self._k_cache, self._v_cache,
-                np.int32(req.slot), toks, np.int32(off), np.int32(n))
+        chunk_fn = self._chunk_sp_fn if sp else self._chunk_fn
+        *pools, logits = chunk_fn(
+            self._pinned, *self._pools, *self._tables_arg(),
+            np.int32(req.slot), toks, np.int32(off), np.int32(n))
+        self._pools = tuple(pools)
         # block per chunk: letting chunk dispatches run ahead
         # asynchronously looks free, but an idle->busy transition can
         # queue several chunks on the device and the NEXT fused step's
@@ -2325,7 +2037,7 @@ class DecodeEngine:
         # ~1 chunk+step to >100 ms under ramp). One chunk per iteration,
         # retired per iteration, keeps the bound honest.
         with trace.phase("engine.prefill_chunk.sync"):
-            jax.block_until_ready(self._k_cache)
+            jax.block_until_ready(self._pools[0])
         req.pf_off = off + n
         req.pf_chunks += 1
         if sp:
@@ -2447,9 +2159,7 @@ class DecodeEngine:
         # scoped the hashes to the same encoding)
         payload = kv_transfer.new_payload(
             len(req.prompt), self._block_size, req.version,
-            (self._model_cfg.n_layers, self._block_size,
-             self._model_cfg.d_model),
-            np.int8 if self._kv_quant else self._model_cfg.dtype)
+            self._xfer_block_shape(), self._pools[0].dtype)
         if req.tenant:
             # the receiving engine's ledger charges the splice-in bytes
             # to the originating tenant; absent key = default tenant
@@ -2462,18 +2172,11 @@ class DecodeEngine:
                 # prefix — the hash rides, the bytes stay home
                 kv_transfer.add_block(payload, hx)
                 continue
-            if self._kv_quant:
-                k, v, ks, vs = self._fetch_fn(
-                    self._k_cache, self._v_cache, self._k_scales,
-                    self._v_scales, np.int32(req.blocks[i]))
-                kv_transfer.add_block(payload, hx, np.asarray(k),
-                                      np.asarray(v), np.asarray(ks),
-                                      np.asarray(vs))
-            else:
-                k, v = self._fetch_fn(self._k_cache, self._v_cache,
-                                      np.int32(req.blocks[i]))
-                kv_transfer.add_block(payload, hx, np.asarray(k),
-                                      np.asarray(v))
+            # K and V slices (and, quantized, their per-layer scale
+            # columns), in the pools' order
+            kv_transfer.add_block(payload, hx, *(
+                np.asarray(piece) for piece in self._fetch_fn(
+                    *self._pools, np.int32(req.blocks[i]))))
             shipped += 1
         nbytes = kv_transfer.payload_bytes(payload)
         dedup = int(payload["dedup_blocks"])
@@ -2541,9 +2244,8 @@ class DecodeEngine:
             info["skipped"] = (f"block size {payload['block_size']} != "
                                f"{self._block_size}")
             return info
-        cfg = self._model_cfg
         shape = tuple(int(d) for d in payload["shape"])
-        if shape != (cfg.n_layers, self._block_size, cfg.d_model):
+        if shape != self._xfer_block_shape():
             info["skipped"] = f"block shape {shape} mismatch"
             return info
         dtype = np.dtype(payload["dtype"])
@@ -2553,8 +2255,7 @@ class DecodeEngine:
         # check is the belt to that suspender (same-version payloads
         # from a differently-configured fleet must still degrade to a
         # local re-prefill, never splice mis-typed bytes)
-        expect = (np.dtype(np.int8) if self._kv_quant
-                  else np.dtype(cfg.dtype))
+        expect = np.dtype(self._pools[0].dtype)
         if dtype != expect:
             info["skipped"] = f"dtype {dtype} != {expect}"
             return info
@@ -2570,7 +2271,7 @@ class DecodeEngine:
                 break
             try:
                 k, v = kv_transfer.unpack_block(rec, shape, dtype)
-                scales = (kv_transfer.unpack_scales(rec, cfg.n_layers)
+                scales = (kv_transfer.unpack_scales(rec, shape[0])
                           if self._kv_quant else None)
             except ValueError:
                 break
@@ -2579,15 +2280,8 @@ class DecodeEngine:
                 # stop the walk (prefix semantics) and re-prefill
                 break
             blk = self._pool.alloc(1)[0]
-            if self._kv_quant:
-                (self._k_cache, self._v_cache, self._k_scales,
-                 self._v_scales) = self._splice_fn(
-                    self._k_cache, self._v_cache, self._k_scales,
-                    self._v_scales, np.int32(blk), k, v,
-                    scales[0], scales[1])
-            else:
-                self._k_cache, self._v_cache = self._splice_fn(
-                    self._k_cache, self._v_cache, np.int32(blk), k, v)
+            self._pools = tuple(self._splice_fn(
+                *self._pools, np.int32(blk), k, v, *(scales or ())))
             self._pool.register(blk, h)
             self._pool.decref([blk])
             info["xfer_blocks"] += 1
@@ -2657,22 +2351,13 @@ class DecodeEngine:
                     req.usage.prefill_tokens += len(req.prompt)
                     if req.resumed:
                         req.usage.recompute_tokens += len(req.prompt)
-            if self._paged and self._kv_quant:
-                (first, self._k_cache, self._v_cache, self._k_scales,
-                 self._v_scales) = self._admit_fn(
-                    self._pinned, self._k_cache, self._v_cache,
-                    self._k_scales, self._v_scales, jnp.asarray(bts),
-                    jnp.asarray(toks), jnp.asarray(lens))
-            elif self._paged:
-                first, self._k_cache, self._v_cache = self._admit_fn(
-                    self._pinned, self._k_cache, self._v_cache,
-                    jnp.asarray(bts), jnp.asarray(toks), jnp.asarray(lens))
-            else:
+            if not self._paged:
                 slots[len(group):] = slots[0]  # pads: overwritten by row 0
-                first, self._k_cache, self._v_cache = self._admit_fn(
-                    self._pinned, self._k_cache, self._v_cache,
-                    jnp.asarray(slots), jnp.asarray(toks),
-                    jnp.asarray(lens))
+            first, *pools = self._admit_fn(
+                self._pinned, *self._pools,
+                jnp.asarray(bts if self._paged else slots),
+                jnp.asarray(toks), jnp.asarray(lens))
+            self._pools = tuple(pools)
             staged.append((group, slots, first, pb, bb))
         # phase 2 — read the first tokens back (one sync per group, after
         # every group's dispatch is already in the device queue)
@@ -2925,31 +2610,14 @@ class DecodeEngine:
             # acceptance is decided below on the host from the argmax
             # chain (traced data in, plain ints out — never a shape)
             self.spec_steps += 1
-            if self._kv_quant:
-                (self._k_cache, self._v_cache, self._k_scales,
-                 self._v_scales, nxt) = self._verify_fn(
-                    self._pinned, self._k_cache, self._v_cache,
-                    self._k_scales, self._v_scales, self._block_tables,
-                    spec_toks, self._pos, self._active, n_valid)
-            else:
-                self._k_cache, self._v_cache, nxt = self._verify_fn(
-                    self._pinned, self._k_cache, self._v_cache,
-                    self._block_tables, spec_toks, self._pos,
-                    self._active, n_valid)
-        elif self._paged and self._kv_quant:
-            (self._k_cache, self._v_cache, self._k_scales,
-             self._v_scales, nxt, _) = self._step_fn(
-                self._pinned, self._k_cache, self._v_cache,
-                self._k_scales, self._v_scales, self._block_tables,
-                self._tok, self._pos, self._active)
-        elif self._paged:
-            self._k_cache, self._v_cache, nxt, _ = self._step_fn(
-                self._pinned, self._k_cache, self._v_cache,
-                self._block_tables, self._tok, self._pos, self._active)
+            *pools, nxt = self._verify_fn(
+                self._pinned, *self._pools, self._block_tables,
+                spec_toks, self._pos, self._active, n_valid)
         else:
-            self._k_cache, self._v_cache, nxt, _ = self._step_fn(
-                self._pinned, self._k_cache, self._v_cache,
+            *pools, nxt, _ = self._step_fn(
+                self._pinned, *self._pools, *self._tables_arg(),
                 self._tok, self._pos, self._active)
+        self._pools = tuple(pools)
         with trace.phase("engine.step.sync"):
             nxt = np.array(nxt)   # [S] or [S, K+1]; the host sync point
         with trace.phase("engine.step.book"):
@@ -3127,8 +2795,8 @@ class DecodeEngine:
             self._stop.set()
             pending = self._q.drain()
             # release splice waiters: the loop will never apply these
-            while self._splice_q:
-                _, done, info = self._splice_q.popleft()
+            while self._loop_work:
+                _, done, info = self._loop_work.popleft()
                 info["skipped"] = "engine failed"
                 done.set()
         live = [r for r in self._slot_req if r is not None]
@@ -3240,171 +2908,113 @@ class DecodeEngine:
     def warmup(self) -> None:
         """Compile every admission trace (the ONE chunk program when
         chunked, else every (batch bucket, prompt bucket) fused
-        prefill+insert) and the fused step before taking traffic,
-        against scratch caches — deadline-sensitive deployments call
-        this BEFORE submitting so no live request ever pays a compile.
-        Pins the snapshot through the serving path itself, so the warmup
-        params copy (and placement, hence the compiled traces) IS the
-        one the first admission serves.
+        prefill+insert), the copy-on-write and transfer programs and the
+        fused step before taking traffic — deadline-sensitive
+        deployments call this BEFORE submitting so no live request ever
+        pays a compile. Pins the snapshot through the serving path
+        itself, so the warmup params copy (and placement, hence the
+        compiled traces) IS the one the first admission serves.
+
+        A paged engine warms up against its LIVE pools, on the loop
+        thread (the only thread that may hand the donated pools to a
+        program): every table row names the scratch block and no lane
+        is active, so all writes park in scratch, which no live mask
+        reaches. No second copy of the pools ever exists (a model's
+        weights and pools may fill the chip). Contiguous strips have no
+        scratch row, so that engine warms up against scratch strips.
         """
+        if self._paged:
+            done = threading.Event()
+            info: Dict = {}
+
+            def warm() -> dict:
+                self._pools = self._warm(self._pools)
+                return {}
+
+            with self._cv:
+                if self._stop.is_set():
+                    return
+                self._loop_work.append((warm, done, info))
+                self._cv.notify()
+            while not done.wait(0.5):
+                if not self._thread.is_alive():
+                    raise RuntimeError(
+                        f"decode engine {self.name!r}: loop thread died "
+                        f"during warmup")
+            if "error" in info:
+                raise info["error"]
+            return
+        self._warm(tuple(
+            jax.device_put(jnp.zeros(shape, dtype), target)
+            for (shape, dtype), target in zip(self._progs.pools,
+                                              self._pool_targets)))
+
+    def _warm(self, pools: tuple) -> tuple:
+        """Dispatch every serving program once with the serving avals
+        (numpy host state, the pools' committed placement), threading
+        ``pools`` through the donations; returns the pools."""
         self._maybe_refresh()
         params = self._pinned
         S = self.config.slots
-        shape = self._k_cache.shape
-        dtype = self._k_cache.dtype
-
-        def scratch():
-            # the live caches' placement (devices()[0], or the decode
-            # mesh's pool sharding when tp > 1): warmup traces only ARE
-            # the serving traces if their operands carry the same
-            # committed sharding
-            return (jax.device_put(jnp.zeros(shape, dtype),
-                                   self._cache_target),
-                    jax.device_put(jnp.zeros(shape, dtype),
-                                   self._cache_target))
-
-        def scratch_scales():
-            # quant engines: scratch scale arrays on the scales' own
-            # placement (replicated on a sharded engine) — same
-            # committed-placement reasoning as scratch()
-            sshape = self._k_scales.shape
-            return (jax.device_put(jnp.zeros(sshape, jnp.float32),
-                                   self._scale_target),
-                    jax.device_put(jnp.zeros(sshape, jnp.float32),
-                                   self._scale_target))
-
-        if self._paged and self._kv_quant:
-            # quant warmup mirrors the fp paged warmup exactly, with
-            # the scale arrays threaded through every program — the
-            # traces built here ARE the quant serving traces
-            M = self._blocks_per_seq
-            bt = np.full((S, M), SCRATCH_BLOCK, np.int32)
-            if self._budget > 0:
-                kc, vc = scratch()
-                ks, vs = scratch_scales()
-                self._chunk_fn(params, kc, vc, ks, vs, bt, np.int32(0),
-                               np.ones(self._budget, np.int32),
-                               np.int32(0), np.int32(1))
-            else:
-                for pb in self._prompt_buckets:
-                    for bb in self._batch_buckets:
-                        kc, vc = scratch()
-                        ks, vs = scratch_scales()
-                        self._admit_fn(
-                            params, kc, vc, ks, vs,
-                            np.full((bb, M), SCRATCH_BLOCK, np.int32),
-                            np.ones((bb, pb), np.int32),
-                            np.ones(bb, np.int32))
-            if self._prefix:
-                kc, vc = scratch()
-                ks, vs = scratch_scales()
-                jax.block_until_ready(self._cow_fn(
-                    kc, vc, ks, vs, np.int32(0), np.int32(0)))
-                kc, vc = scratch()
-                ks, vs = scratch_scales()
-                k, v, bks, bvs = self._fetch_fn(kc, vc, ks, vs,
-                                                np.int32(0))
-                k, v = np.asarray(k), np.asarray(v)
-                bks, bvs = np.asarray(bks), np.asarray(bvs)
-                jax.block_until_ready(self._splice_fn(
-                    kc, vc, ks, vs, np.int32(0), k, v, bks, bvs)[0])
-            if self._spec:
-                kc, vc = scratch()
-                ks, vs = scratch_scales()
-                jax.block_until_ready(self._verify_fn(
-                    params, kc, vc, ks, vs, bt,
-                    np.zeros((S, self._spec + 1), np.int32),
-                    np.zeros(S, np.int32), np.zeros(S, bool),
-                    np.ones(S, np.int32)))
-            kc, vc = scratch()
-            ks, vs = scratch_scales()
-            jax.block_until_ready(self._step_fn(
-                params, kc, vc, ks, vs, bt, np.zeros(S, np.int32),
-                np.zeros(S, np.int32), np.zeros(S, bool)))
-            return
-        if self._paged:
-            # all-scratch block tables: warmup writes park in the
-            # sentinel block of the scratch pools — placement is data,
-            # so these ARE the serving traces for any block assignment
-            M = self._blocks_per_seq
-            bt = np.full((S, M), SCRATCH_BLOCK, np.int32)
-            if self._budget > 0:
-                kc, vc = scratch()
-                self._chunk_fn(params, kc, vc, bt, np.int32(0),
-                               np.ones(self._budget, np.int32),
-                               np.int32(0), np.int32(1))
-                if self._chunk_sp_fn is not None:
-                    # the seqpar chunk program compiles here too (its
-                    # budget * tp token shape is the only static), so no
-                    # long prompt ever pays the trace — and the
-                    # partitioner runs now, not on the loop thread
-                    kc, vc = scratch()
-                    self._chunk_sp_fn(params, kc, vc, bt, np.int32(0),
-                                      np.ones(self._sp_chunk, np.int32),
-                                      np.int32(0), np.int32(1))
-            else:
-                for pb in self._prompt_buckets:
-                    for bb in self._batch_buckets:
-                        kc, vc = scratch()
-                        self._admit_fn(
-                            params, kc, vc,
-                            np.full((bb, M), SCRATCH_BLOCK, np.int32),
-                            np.ones((bb, pb), np.int32),
-                            np.ones(bb, np.int32))
-            if self._prefix:
-                # the CoW block copy is part of the serving path (a
-                # full-prompt cache hit dispatches it at admission):
-                # compile it here so no live request pays the trace
-                kc, vc = scratch()
-                jax.block_until_ready(self._cow_fn(
-                    kc, vc, np.int32(0), np.int32(0)))
-                # the KV transfer plane's two programs likewise (a
-                # disaggregated fleet dispatches fetch at stage-1
-                # completion and splice at arrival): warm both so no
-                # transfer pays a compile. The host round-trip mirrors
-                # serving — fetch materializes before splice donates
-                # the pools away.
-                kc, vc = scratch()
-                k, v = self._fetch_fn(kc, vc, np.int32(0))
-                k, v = np.asarray(k), np.asarray(v)
-                jax.block_until_ready(self._splice_fn(
-                    kc, vc, np.int32(0), k, v)[0])
-            if self._spec:
-                # the verify step pins like the step programs: compiled
-                # here against the pinned params + scratch pools, so
-                # the trace warmup builds IS the serving trace (the
-                # [S, K + 1] window shape is the whole signature)
-                kc, vc = scratch()
-                jax.block_until_ready(self._verify_fn(
-                    params, kc, vc, bt,
-                    np.zeros((S, self._spec + 1), np.int32),
-                    np.zeros(S, np.int32), np.zeros(S, bool),
-                    np.ones(S, np.int32)))
-            kc, vc = scratch()
-            jax.block_until_ready(self._step_fn(
-                params, kc, vc, bt, np.zeros(S, np.int32),
-                np.zeros(S, np.int32), np.zeros(S, bool)))
-            return
+        M = self._blocks_per_seq
+        # all-scratch block tables: placement is data, so these ARE the
+        # serving traces for any block assignment
+        tables = ((np.full((S, M), SCRATCH_BLOCK, np.int32),)
+                  if self._paged else ())
+        zeros = np.zeros(S, np.int32)
         if self._budget > 0:
-            kc, vc = scratch()
-            self._chunk_fn(params, kc, vc, np.int32(0),
-                           np.ones(self._budget, np.int32), np.int32(0),
-                           np.int32(1))
+            *pools, _ = self._chunk_fn(
+                params, *pools, *tables, np.int32(0),
+                np.ones(self._budget, np.int32), np.int32(0), np.int32(1))
+            if self._chunk_sp_fn is not None:
+                # the seqpar chunk program compiles here too (its
+                # budget * tp token shape is the only static), so no
+                # long prompt ever pays the trace — and the partitioner
+                # runs now, not mid-traffic
+                *pools, _ = self._chunk_sp_fn(
+                    params, *pools, *tables, np.int32(0),
+                    np.ones(self._sp_chunk, np.int32), np.int32(0),
+                    np.int32(1))
         else:
             for pb in self._prompt_buckets:
                 for bb in self._batch_buckets:
-                    kc, vc = scratch()
-                    self._admit_fn(params, kc, vc,
-                                   np.arange(bb, dtype=np.int32) % S,
-                                   np.ones((bb, pb), np.int32),
-                                   np.ones(bb, np.int32))
-        kc, vc = scratch()
-        jax.block_until_ready(self._step_fn(
-            params, kc, vc, np.zeros(S, np.int32), np.zeros(S, np.int32),
-            np.zeros(S, bool)))
+                    where = (np.full((bb, M), SCRATCH_BLOCK, np.int32)
+                             if self._paged
+                             else np.arange(bb, dtype=np.int32) % S)
+                    _, *pools = self._admit_fn(
+                        params, *pools, where, np.ones((bb, pb), np.int32),
+                        np.ones(bb, np.int32))
+        if self._cow_fn is not None:
+            # the CoW block copy is part of the serving path (a
+            # full-prompt cache hit dispatches it at admission)
+            pools = self._cow_fn(*pools, np.int32(0), np.int32(0))
+        if self._fetch_fn is not None:
+            # the KV transfer plane's two programs likewise. The host
+            # round-trip mirrors serving: fetch materializes before
+            # splice donates the pools away
+            pieces = [np.asarray(x)
+                      for x in self._fetch_fn(*pools, np.int32(0))]
+            pools = self._splice_fn(*pools, np.int32(0), *pieces)
+        if self._verify_fn is not None:
+            # the [S, K + 1] window shape is the whole signature
+            *pools, _ = self._verify_fn(
+                params, *pools, *tables,
+                np.zeros((S, self._spec + 1), np.int32), zeros,
+                np.zeros(S, bool), np.ones(S, np.int32))
+        *pools, nxt, _ = self._step_fn(params, *pools, *tables, zeros,
+                                       zeros, np.zeros(S, bool))
+        jax.block_until_ready(nxt)
+        return tuple(pools)
+
+    def _model_counters(self):
+        """The newest value of the model's own counter pool (never
+        donated, so any thread may read it), or None."""
+        i = self._progs.counter_pool
+        return None if i is None else np.asarray(self._pools[i])
 
     def reset_stats(self) -> None:
         """Zero counters/histograms (benches: measure past jit warmup)."""
+        self._counters_base = self._model_counters()
         self.ttft_hist.reset()
         self.itl_hist.reset()
         self.completed = 0
@@ -3462,11 +3072,8 @@ class DecodeEngine:
                  # int8 K/V bytes PLUS the per-(layer, block) fp32
                  # scales — the footprint must not flatter quantization
                  "kv_bytes_per_device": (
-                     (self._pool.capacity + 1) * kv_bytes_per_block(
-                         self._model_cfg.n_layers, self._model_cfg.d_model,
-                         self._block_size, np.dtype(self._model_cfg.dtype),
-                         quant=self._kv_quant_mode)
-                     // self._tp),
+                     (self._pool.capacity + 1)
+                     * self._progs.bytes_per_block // self._tp),
                  "kv_blocks_free": self._pool.n_free,
                  "kv_blocks_live": self._pool.n_live,
                  "kv_blocks_cached": self._pool.n_cached,
@@ -3494,9 +3101,10 @@ class DecodeEngine:
             # the real device count (one sync, stats are not the hot
             # loop); the per-iteration recorder uses the pool proxy
             try:
-                nz = int((np.maximum(
-                    np.asarray(self._k_scales),
-                    np.asarray(self._v_scales)).max(axis=0) > 0).sum())
+                nz = int((np.maximum.reduce(
+                    [np.asarray(self._pools[i])
+                     for i in self._progs.scale_pools]).max(axis=0)
+                    > 0).sum())
             except RuntimeError:
                 # donated-away buffer (stats raced a dispatch): the
                 # count is a diagnostic, not an invariant — degrade
@@ -3570,6 +3178,15 @@ class DecodeEngine:
                 "verify_traces": self.verify_cache_size(),
             })
         health = self.health()
+        counts = self._model_counters()
+        if counts is not None:
+            # the model's own counters (LongCat: routing picks and the
+            # held experts' load), accumulated on the device by its
+            # programs and read here, never a sync a step; a window's
+            # delta since reset_stats()
+            if self._counters_base is not None:
+                counts = counts - self._counters_base
+            pool.update(self._progs.counters(counts))
         return {
             **pool,
             "decode_tp": self._tp,

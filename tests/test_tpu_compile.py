@@ -384,3 +384,69 @@ def test_sharded_decode_programs_compile_on_two_chips(topo, serving_shapes):
         mem = compiled.memory_analysis()      # per device
         assert mem.alias_size_in_bytes >= pool_bytes      # 2 pools / 2 chips
         assert mem.argument_size_in_bytes < 2 * pool_bytes
+
+
+# -- LongCat-Flash share: the latent-pool programs at the cell's shapes ----------
+@pytest.mark.parametrize("program,temp_mb", [("step", 700), ("chunk", 850)])
+def test_longcat_cell_programs_fit_the_chip(one_chip, program, temp_mb):
+    """``LongCatLM.serving_programs`` at the shapes of
+    ``serve_longcat_ep32_closed128`` (``benchmarks/configs/
+    longcat-flash-ep32.json`` under ``traffic/closed128_gen768.json``):
+    10.4 GB of weights and the 3.0 GB latent pool are arguments, the
+    pool is aliased (a 576-wide row made the compiler copy the pool
+    whole around every scatter: the row is padded to 640), no copy of
+    the pool and no float32 copy of the ``slots x T`` view appear, and
+    arguments plus temporaries stay inside the chip."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import longcat
+    from multiverso_tpu.serving.programs import EngineSpec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "longcat-flash-ep32.json")) as fh:
+        cfg = longcat.config_from_dict(json.load(fh), 1)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "closed128_gen768.json")) as fh:
+        t = json.load(fh)
+    S, Bs, C = t["slots"], 16, t["prefill_token_budget"]
+    T = t["max_prompt"] + t["max_new"]
+    M = -(-T // Bs)
+    lm = object.__new__(longcat.LongCatLM)      # no weights drawn
+    lm.config = cfg
+    progs = lm.serving_programs(EngineSpec(
+        name="cell", slots=S, max_prompt=t["max_prompt"],
+        max_new=t["max_new"], cache_len=T, block_size=Bs, blocks_per_seq=M,
+        pool_blocks=S * M, budget=C, prefix=True, tp=1, mesh=None,
+        kv_quant="none", param_quant="none", spec_k=0, prefill_sp="none",
+        donate=True))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place,
+                          jax.eval_shape(lambda: longcat.init_params(cfg)))
+    (pshape, pdtype), (cshape, cdtype) = progs.pools
+    assert pshape == (8, S * M + 1, Bs, 640)
+    pool = jax.ShapeDtypeStruct(pshape, pdtype, sharding=one_chip)
+    counters = jax.ShapeDtypeStruct(cshape, cdtype, sharding=one_chip)
+    bt = _ints(one_chip, S, M)
+    if program == "step":
+        compiled = progs.step.lower(
+            params, pool, counters, bt, _ints(one_chip, S),
+            _ints(one_chip, S), jax.ShapeDtypeStruct(
+                (S,), jnp.bool_, sharding=one_chip)).compile()
+    else:
+        compiled = progs.chunk.lower(
+            params, pool, counters, bt, _ints(one_chip), _ints(one_chip, C),
+            _ints(one_chip), _ints(one_chip)).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pshape)) * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < temp_mb * 10 ** 6
+    assert mem.argument_size_in_bytes > 13.3e9      # weights + pool
+    assert _fits_hbm(compiled, budget=15.75 * 2 ** 30)
+    text = compiled.as_text()
+    assert f"copy(bf16[{pshape[0]},{pshape[1]},{Bs},640]" not in text
+    assert f"f32[{S},{T},640]" not in text
