@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..log import Log
+from ..ops import paged_attention as paged_kernel
 from ..ops.moe import COUNT_SCALARS, held_expert_layer, route_topk, swiglu
 
 _NEG_INF = -1e30
@@ -287,6 +288,31 @@ def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask):
                    preferred_element_type=f32)
 
 
+def latent_query(cfg: LongCatConfig, w, q_nope, q_rope, width: int):
+    """The decode form's query ``[S, H, width]``: the key half of
+    ``W_kvb`` absorbed into ``q_nope``, then ``q_rope``, then zeros up to
+    the cache rows' ``width``, so ONE product runs over the rows as they
+    lie."""
+    dt = cfg.dtype
+    q_lat = jnp.einsum("shd,chd->shc", q_nope, _kvb(cfg, w)[0],
+                       preferred_element_type=jnp.float32).astype(dt)
+    pad = width - cfg.cache_width
+    return jnp.concatenate(
+        [q_lat, q_rope] + ([jnp.zeros(q_rope.shape[:2] + (pad,), dt)]
+                           if pad else []), axis=-1)
+
+
+def latent_output(cfg: LongCatConfig, w, o_lat):
+    """The decode form's tail: the attended latents ``o_lat`` [S, H,
+    rkv] through the value half of ``W_kvb`` and ``W_o``: [S, D]
+    float32."""
+    f32, dt = jnp.float32, cfg.dtype
+    o = jnp.einsum("shc,chd->shd", o_lat.astype(dt), _kvb(cfg, w)[1],
+                   preferred_element_type=f32).astype(dt)
+    return jnp.dot(o.reshape(o.shape[0], -1), w["w_o"],
+                   preferred_element_type=f32)
+
+
 def mla_latent(cfg: LongCatConfig, w, q_nope, q_rope, view, pos):
     """The decode form: one query a slot, ``q_nope``/``q_rope`` [S, H,
     .], against each slot's cache rows ``view`` [S, T, >= rkv + dr] as
@@ -294,26 +320,17 @@ def mla_latent(cfg: LongCatConfig, w, q_nope, q_rope, view, pos):
     ``<= pos`` [S] are live. The key half of ``W_kvb`` is absorbed into
     the query and the value half into the output, so both products run
     over the cache rows. Returns [S, D] float32."""
-    f32, dt = jnp.float32, cfg.dtype
-    rkv = cfg.kv_lora_rank
+    f32 = jnp.float32
     dq = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    wk, wv = _kvb(cfg, w)
-    q_lat = jnp.einsum("shd,chd->shc", q_nope, wk,
-                       preferred_element_type=f32).astype(dt)
-    pad = view.shape[-1] - cfg.cache_width
-    q_cat = jnp.concatenate(
-        [q_lat, q_rope] + ([jnp.zeros(q_rope.shape[:2] + (pad,), dt)]
-                           if pad else []), axis=-1)    # [S, H, row width]
+    q_cat = latent_query(cfg, w, q_nope, q_rope, view.shape[-1])
     s = jnp.einsum("shc,stc->sht", q_cat, view,
                    preferred_element_type=f32) / math.sqrt(dq)
     live = jnp.arange(view.shape[1])[None, :] <= pos[:, None]
     p = jax.nn.softmax(jnp.where(live[:, None, :], s, _NEG_INF), axis=-1)
-    o_lat = jnp.einsum("sht,stc->shc", p.astype(dt), view[..., :rkv],
-                       preferred_element_type=f32).astype(dt)
-    o = jnp.einsum("shc,chd->shd", o_lat, wv,
-                   preferred_element_type=f32).astype(dt)
-    return jnp.dot(o.reshape(o.shape[0], -1), w["w_o"],
-                   preferred_element_type=f32)
+    o_lat = jnp.einsum("sht,stc->shc", p.astype(cfg.dtype),
+                       view[..., :cfg.kv_lora_rank],
+                       preferred_element_type=f32)
+    return latent_output(cfg, w, o_lat)
 
 
 def expert_layer(cfg: LongCatConfig, blk, u, valid=None, identity=True):
@@ -380,25 +397,39 @@ def _view(pool, sub: int, tables, t: int):
 
 
 def decode_step_paged(cfg: LongCatConfig, params, pool, counters,
-                      block_tables, tok, pos, active, t_logical: int):
+                      block_tables, tok, pos, active, t_logical: int,
+                      paged_attention=None):
     """One fused token step over S slots against the paged latent pool
     ``[subs, N + 1, Bs, pool_width]`` (block 0 = scratch). The engine's
     contract (``models.transformer.decode_step_paged``): a live slot
     writes its row at ``(block_tables[s, pos // Bs], pos % Bs)``, dead
     lanes park theirs in scratch. ``counters`` accumulates the routing
-    counts of live slots. Returns ``(pool, counters, next_tok, pos)``."""
-    Bs = pool.shape[2]
+    counts of live slots. ``paged_attention``
+    (``ops.paged_attention.paged_mq_attention``, where
+    ``LongCatLM.serving_programs`` finds it applies) reads each slot's
+    live blocks out of the pool in place of the gathered view: the same
+    latent query, keys the whole rows, values their first ``rkv``
+    columns. Returns ``(pool, counters, next_tok, pos)``."""
+    n_sub, N, Bs, W = pool.shape
     blk_ix = jnp.take_along_axis(block_tables, (pos // Bs)[:, None],
                                  axis=1)[:, 0]
     write_blk = jnp.where(active, blk_ix, 0)
     write_off = jnp.where(active, pos % Bs, 0)
+    lengths = jnp.where(active, pos + 1, 0)
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
     h = jnp.take(params["embed"], tok, axis=0).astype(jnp.float32)
     for b, blk in enumerate(params["blocks"]):
         def attend(j, w, x, b=b):
             nonlocal pool
-            q_nope, q_rope, row = mla_project(cfg, w, x, pos,
-                                              pool.shape[-1])
+            q_nope, q_rope, row = mla_project(cfg, w, x, pos, W)
             pool = pool.at[2 * b + j, write_blk, write_off].set(row)
+            if paged_attention is not None:
+                o_lat = paged_attention(
+                    latent_query(cfg, w, q_nope, q_rope, W),
+                    pool.reshape(n_sub * N, Bs, W), None,
+                    (2 * b + j) * N + block_tables, lengths, scale=scale,
+                    wv=cfg.kv_lora_rank)
+                return latent_output(cfg, w, o_lat)
             # the barrier holds the view as ONE array between its two
             # readers (scores, values): with weights and pool filling
             # the chip the TPU compiler otherwise rematerializes the
@@ -520,12 +551,16 @@ class LongCatLM:
                       f"exceeds max_position_embeddings {cfg.max_seq}")
         T = spec.cache_len
         donate = (1,) if spec.donate else ()
+        # the one-token step reads the live blocks in place where a
+        # block is whole tiles on a TPU; the chunk keeps the view
+        attend = paged_kernel.step_attention(cfg.dtype, spec.block_size,
+                                             cfg.pool_width)
 
         # the functions' names are the programs' in a profile (jit_<name>)
         def longcat_decode_step(params, pool, counters, bt, tok, pos,
                                 active):
             return decode_step_paged(cfg, params, pool, counters, bt, tok,
-                                     pos, active, T)
+                                     pos, active, T, paged_attention=attend)
 
         def longcat_prefill_chunk(params, pool, counters, bt, slot, toks,
                                   off, n):

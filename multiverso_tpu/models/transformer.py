@@ -27,7 +27,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..log import Log
+from ..ops import paged_attention as paged_kernel
 from ..ops.ring_attention import ring_prefill_attention
 from ..ops.ulysses import ulysses_prefill_attention
 from ..topology import SERVER_AXIS, WORKER_AXIS
@@ -244,6 +245,25 @@ def loss_fn(cfg: TransformerConfig, params: Dict[str, Any],
 _NEG_INF = -1e30
 
 
+def _spread_heads(q, n_heads: int) -> Tuple[jax.Array, jax.Array]:
+    """``q`` [B, D] spread block-diagonally to ``[B, h, D]`` (head
+    ``h``'s ``dh`` columns, exact zeros elsewhere), and the ``[h, D]``
+    mask of each head's own columns: with it a one-token attention is
+    two MATRIX products over cache rows as they lie, ``[h, D] x [D, T]``
+    and ``[h, T] x [T, D]``, and head ``h`` keeps its own columns of its
+    row (:func:`_own_columns`). The added terms are exact zeros."""
+    D = q.shape[1]
+    own = (jnp.arange(n_heads)[:, None]
+           == jnp.arange(D)[None, :] // (D // n_heads))
+    return jnp.where(own[None], q[:, None, :], 0), own
+
+
+def _own_columns(full, own) -> jax.Array:
+    """Head ``h``'s own columns of row ``h`` of ``full`` [B, h, D]:
+    the heads' outputs side by side, [B, D]."""
+    return jnp.sum(jnp.where(own[None], full, 0.0), axis=1)
+
+
 def _cached_attention(q, k_cache, v_cache, n_heads: int, pos) -> jax.Array:
     """One-token attention: ``q`` [B, D] against cache [B, T, D].
 
@@ -253,29 +273,24 @@ def _cached_attention(q, k_cache, v_cache, n_heads: int, pos) -> jax.Array:
     :func:`ops.reference_attention` (1/sqrt(dh) scale, f32 accumulation,
     f32 softmax, probabilities rounded to the cache's dtype), but both
     products are written as MATRIX products over the cache as it lies,
-    ``[B, T, D]``: ``q`` is spread block-diagonally to ``[B, h, D]``
-    (head ``h``'s ``dh`` columns, exact zeros elsewhere), so the scores
-    are ``[h, D] x [D, T]`` and the values ``[h, T] x [T, D]`` per
-    example, and head ``h`` keeps its own columns of its row. A one-row
-    product per (example, head) is no matrix product to the TPU
-    compiler: it multiplies and reduces on the vector units, over a
-    float32 copy of the whole cache. The added terms are exact zeros, so
-    only the order of the f32 accumulation is the compiler's.
+    ``[B, T, D]``, with ``q`` spread block-diagonally
+    (:func:`_spread_heads`). A one-row product per (example, head) is no
+    matrix product to the TPU compiler: it multiplies and reduces on the
+    vector units, over a float32 copy of the whole cache. The added
+    terms are exact zeros, so only the order of the f32 accumulation is
+    the compiler's.
     """
-    D = q.shape[1]
     T = k_cache.shape[1]
-    dh = D // n_heads
-    own = jnp.arange(n_heads)[:, None] == jnp.arange(D)[None, :] // dh
-    q_heads = jnp.where(own[None], q[:, None, :], 0)
+    q_heads, own = _spread_heads(q, n_heads)
     scores = jnp.einsum("bhD,btD->bht", q_heads, k_cache,
-                        preferred_element_type=jnp.float32) / np.sqrt(dh)
+                        preferred_element_type=jnp.float32) / np.sqrt(
+                            q.shape[1] // n_heads)
     mask = (jnp.arange(T)[None, :] <= pos[:, None])[:, None, :]
     scores = jnp.where(mask, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     full = jnp.einsum("bht,btD->bhD", probs.astype(v_cache.dtype), v_cache,
                       preferred_element_type=jnp.float32)
-    out = jnp.sum(jnp.where(own[None], full, 0.0), axis=1)
-    return out.astype(q.dtype)
+    return _own_columns(full, own).astype(q.dtype)
 
 
 def prefill(cfg: TransformerConfig, params: Dict[str, Any],
@@ -499,7 +514,8 @@ def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
                       k_pool: jax.Array, v_pool: jax.Array,
                       block_tables: jax.Array, tok: jax.Array,
                       pos: jax.Array, active: jax.Array,
-                      t_logical: Optional[int] = None
+                      t_logical: Optional[int] = None,
+                      paged_attention: Optional[Callable] = None
                       ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One fused token step over S slots against the paged KV pool.
 
@@ -514,15 +530,23 @@ def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
     a live mask, so a mid-flight chunked prefill's prompt region cannot
     be clobbered).
 
+    ``paged_attention`` (:func:`ops.paged_attention.paged_mq_attention`,
+    chosen by :func:`make_serving_programs` through
+    ``ops.paged_attention.step_attention``) replaces "gather the view,
+    two products over it" by a kernel that reads each slot's LIVE blocks
+    out of the pools where they lie: the same block-diagonal query, the
+    same rounding points, no ``[S, T, D]`` view.
+
     Returns ``(k_pool, v_pool, next_tok [S], pos [S])``.
     """
-    Bs = k_pool.shape[2]
+    L, N, Bs, D = k_pool.shape
     M = block_tables.shape[1]
     T = M * Bs if t_logical is None else int(t_logical)
     blk = jnp.take_along_axis(block_tables, (pos // Bs)[:, None],
                               axis=1)[:, 0]
     write_blk = jnp.where(active, blk, 0)      # dead lanes -> scratch
     write_off = jnp.where(active, pos % Bs, 0)
+    lengths = jnp.where(active, pos + 1, 0)
     h = (jnp.take(params["embed"], tok, axis=0)
          + jnp.take(params["pos"], pos, axis=0))
     for i in range(cfg.n_layers):
@@ -531,13 +555,22 @@ def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
         q, k, v = x @ layer["w_q"], x @ layer["w_k"], x @ layer["w_v"]
         k_pool = k_pool.at[i, write_blk, write_off].set(k)
         v_pool = v_pool.at[i, write_blk, write_off].set(v)
-        # each slot's blocks as a contiguous [S, T, D] view — the operand
-        # shape of the contiguous cache, so both layouts run the same
-        # attention (same products, same rounding points)
-        kc = _paged_view(k_pool, i, block_tables)
-        vc = _paged_view(v_pool, i, block_tables)
-        h = h + _cached_attention(
-            q, kc[:, :T], vc[:, :T], cfg.n_heads, pos) @ layer["w_o"]
+        if paged_attention is not None:
+            q_heads, own = _spread_heads(q, cfg.n_heads)
+            full = paged_attention(
+                q_heads, k_pool.reshape(L * N, Bs, D),
+                v_pool.reshape(L * N, Bs, D), i * N + block_tables,
+                lengths, scale=1.0 / np.sqrt(D // cfg.n_heads), wv=D)
+            att = _own_columns(full, own).astype(q.dtype)
+        else:
+            # each slot's blocks as a contiguous [S, T, D] view — the
+            # operand shape of the contiguous cache, so both layouts run
+            # the same attention (same products, same rounding points)
+            kc = _paged_view(k_pool, i, block_tables)
+            vc = _paged_view(v_pool, i, block_tables)
+            att = _cached_attention(q, kc[:, :T], vc[:, :T], cfg.n_heads,
+                                    pos)
+        h = h + att @ layer["w_o"]
         x = _rmsnorm(h, layer["ln2_g"])
         h = h + jax.nn.gelu(x @ layer["w_ff1"]) @ layer["w_ff2"]
     h = _rmsnorm(h, params["ln_f_g"])
@@ -1604,10 +1637,16 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
                                            sp_backend, t_logical=T,
                                            tp_axis=DECODE_TP_AXIS),
                     donate_argnums=donate)
+            # the one-token step reads the live blocks in place where a
+            # block is whole tiles on a TPU; every other program attends
+            # the gathered view
+            attend = paged_kernel.step_attention(pool_dtype,
+                                                 spec.block_size, D)
             out.step = jax.jit(
                 lambda params, kc, vc, bt, tok, pos, active:
                 decode_step_paged(cfg, pf(params), kc, vc, bt, tok, pos,
-                                  active, t_logical=T),
+                                  active, t_logical=T,
+                                  paged_attention=attend),
                 donate_argnums=donate)
             if spec.spec_k:
                 # the fixed-K verify step: the [S, spec_k + 1] window is

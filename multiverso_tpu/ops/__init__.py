@@ -3,6 +3,7 @@
 from .embedding import embedding_lookup, scatter_add_rows, segment_mean_rows
 from .flash_attention import (flash_attention, flash_attention_partial,
                               merge_partials)
+from .paged_attention import paged_mq_attention
 from .moe import (EXPERT_AXIS, held_expert_layer, init_moe_params, mlp_expert,
                   moe_apply, route_topk, swiglu, top1_gating)
 from .ring_attention import (reference_attention, ring_attention,
@@ -16,6 +17,7 @@ __all__ = [
     "flash_attention",
     "flash_attention_partial",
     "merge_partials",
+    "paged_mq_attention",
     "EXPERT_AXIS",
     "held_expert_layer",
     "route_topk",

@@ -1064,6 +1064,7 @@ class DecodeEngine:
         self._it_spec_proposed = 0
         self._it_spec_accepted = 0
         self._it_sp_chunks = 0
+        self._it_live_blocks = -1
         self.completed = 0
         self.shed = 0
         self.tokens = 0
@@ -1118,6 +1119,10 @@ class DecodeEngine:
         self.t_first: Optional[float] = None
         self._occ_sum = 0.0          # mean occupancy over iterations
         self._occ_n = 0
+        # KV blocks the steps' attention had to read (paged engines):
+        # what the view path's gather over all slots x M is wasted on,
+        # and what the paged kernel's copies scale with
+        self._live_blocks_sum = 0
         self._thread = threading.Thread(
             target=self._loop, name=f"serve-decode-{name}", daemon=True)
         self._thread.start()
@@ -1598,6 +1603,7 @@ class DecodeEngine:
             self._it_prefill = self._it_decode = 0
             self._it_spec_proposed = self._it_spec_accepted = 0
             self._it_sp_chunks = 0
+            self._it_live_blocks = -1
             step_ms = 0.0
             worked = False
             try:
@@ -1742,7 +1748,13 @@ class DecodeEngine:
             # seqpar tail (FIELDS append at the END; -1 = prefill_sp
             # off): chunks this iteration dispatched through the
             # sequence-parallel program
-            self._it_sp_chunks if self._sp else -1))
+            self._it_sp_chunks if self._sp else -1,
+            # live-block tail (FIELDS append at the END; -1 = contiguous
+            # cache, or a pass that ran no step): the share of the
+            # slots x M table entries this pass's step had to read
+            (self._it_live_blocks
+             / (self.config.slots * self._blocks_per_seq))
+            if self._it_live_blocks >= 0 else -1.0))
 
     def _tables_arg(self) -> tuple:
         """The block tables as the programs take them: one traced
@@ -2602,6 +2614,12 @@ class DecodeEngine:
                                 else None)
             if not self._active.any():
                 return
+        if self._paged:
+            # blocks this step's attention reads: every live slot's
+            # positions <= pos (host state the loop already holds)
+            self._it_live_blocks = int(np.sum(
+                self._pos[self._active] // self._block_size + 1))
+            self._live_blocks_sum += self._it_live_blocks
         # host state (tok/pos/active — and, paged, the block tables)
         # feeds the jit as plain numpy: the same aval signature warmup()
         # uses, so the two share one trace
@@ -3044,6 +3062,7 @@ class DecodeEngine:
         self.t_first = None
         self._occ_sum = 0.0
         self._occ_n = 0
+        self._live_blocks_sum = 0
 
     def record_argmax_match(self, rate: float) -> None:
         """Attach an externally measured argmax-match rate (quant output
@@ -3223,6 +3242,10 @@ class DecodeEngine:
             "itl_p99_ms": itl[99],
             "slot_occupancy": (self._occ_sum / self._occ_n
                                if self._occ_n else 0.0),
+            **({"kv_live_block_share": (
+                self._live_blocks_sum
+                / (self._occ_n * self.config.slots * self._blocks_per_seq)
+                if self._occ_n else 0.0)} if self._paged else {}),
             "active_slots": int(self._active.sum()),
             "queue_depth": self.queue_depth(),
             "snapshot_publishes": self._manager.publishes,

@@ -57,6 +57,10 @@ Record schema (:data:`FIELDS`, positional):
 ``sp_chunks``           prefill chunks dispatched through the sequence-
                         parallel program THIS pass (-1 when
                         ``-prefill_sp`` is off)
+``kv_live_block_share`` KV blocks this pass's step had to read (live
+                        slots' ``ceil((pos + 1) / Bs)``) over ``slots x
+                        M`` (-1 when the cache is contiguous or the pass
+                        ran no step)
 ======================  =====================================================
 
 Timestamps are monotonic; the recorder captures a wall/mono anchor at
@@ -96,7 +100,8 @@ FIELDS = ("it", "ts", "busy_ms", "step_ms", "live", "reserved", "queue",
           "queue_age_ms", "prefill_toks", "decode_toks", "pool_free",
           "pool_live", "pool_shared", "version", "admitted", "completed",
           "spec_proposed", "spec_accepted", "kv_quant",
-          "quant_scale_blocks", "kv_block_s", "tenants_live", "sp_chunks")
+          "quant_scale_blocks", "kv_block_s", "tenants_live", "sp_chunks",
+          "kv_live_block_share")
 
 
 def window_digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:

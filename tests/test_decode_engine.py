@@ -875,3 +875,49 @@ def test_gauge_registry():
     assert Dashboard.get_or_create_gauge("t_gauge2") is got
     assert Dashboard.stats("t_gauge2") == {"value": 3.0}
     assert "t_gauge2" in Dashboard.display(emit=lambda *a: None)
+
+
+def test_kv_live_block_share_counts_the_steps_live_blocks(mv_session):
+    """``stats()["kv_live_block_share"]``: blocks the steps' attention
+    had to read, ``sum over live slots of ceil((pos + 1) / Bs)``, over
+    ``slots x M`` a step. Two requests of known lengths, one after the
+    other: a request of prompt P and n tokens takes its first token from
+    the prefill and n - 1 steps at positions P .. P + n - 2. The flight
+    recorder carries the same share an iteration (-1 where no step
+    ran); a contiguous engine has neither."""
+    from multiverso_tpu.models.transformer import TransformerLM
+    from multiverso_tpu.serving import InferenceServer
+
+    cfg = _small_cfg()
+    lm = TransformerLM(cfg)
+    srv = InferenceServer("t")
+    Bs, slots = 4, 2
+    engine = srv.register_decoder("lm", lm, slots=slots, max_prompt=8,
+                                  max_new=6, kv_block_size=Bs)
+    engine.warmup()
+    M = -(-(8 + 6) // Bs)
+    engine.reset_stats()
+    rng = np.random.default_rng(5)
+    want = []
+    for plen, n in ((3, 4), (7, 6)):
+        prompt = rng.integers(1, cfg.vocab_size, plen).astype(np.int32)
+        out = srv.submit("lm", {"prompt": prompt,
+                                "max_new": n}).result(timeout=120)["result"]
+        assert len(out) == n
+        want += [(plen + k) // Bs + 1 for k in range(n - 1)]
+    stats = engine.stats()
+    assert stats["kv_live_block_share"] == pytest.approx(
+        sum(want) / (len(want) * slots * M))
+    shares = [r["kv_live_block_share"] for r in engine.recorder.records()
+              if r.get("kv_live_block_share", -1) >= 0]
+    assert shares[-len(want):] == pytest.approx(
+        [b / (slots * M) for b in want])
+
+    flat = srv.register_decoder("flat", lm, slots=2, max_prompt=8, max_new=6,
+                                kv_block_size=0, prefill_token_budget=0)
+    flat.warmup()
+    srv.submit("flat", {"prompt": np.asarray([3, 4], np.int32),
+                        "max_new": 3}).result(timeout=120)
+    assert "kv_live_block_share" not in flat.stats()
+    assert all(r["kv_live_block_share"] == -1
+               for r in flat.recorder.records())

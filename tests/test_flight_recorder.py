@@ -22,11 +22,12 @@ def _rec(it, ts, busy=1.0, step=0.5, live=1, reserved=0, queue=0,
          pool_shared=-1, version=0, admitted=(), completed=(),
          spec_proposed=-1, spec_accepted=-1, kv_quant=-1,
          quant_scale_blocks=-1, kv_block_s=-1.0, tenants_live=-1,
-         sp_chunks=-1):
+         sp_chunks=-1, kv_live_block_share=-1.0):
     return (it, ts, busy, step, live, reserved, queue, queue_age,
             prefill, decode, pool_free, pool_live, pool_shared, version,
             admitted, completed, spec_proposed, spec_accepted, kv_quant,
-            quant_scale_blocks, kv_block_s, tenants_live, sp_chunks)
+            quant_scale_blocks, kv_block_s, tenants_live, sp_chunks,
+            kv_live_block_share)
 
 
 # -- ring ---------------------------------------------------------------------
@@ -218,6 +219,25 @@ def test_sp_chunks_column_and_pre_seqpar_tuple_tolerance():
     assert "sp_chunks" not in recs[0] and recs[0]["tenants_live"] == 3
     assert legacy.summary()["iterations"] == 1
     legacy.chrome_counter_events()                 # no positional IndexError
+
+
+def test_kv_live_block_share_column_and_older_tuple_tolerance():
+    """The live-block share rides the END of FIELDS: a paged engine
+    records the share of its ``slots x M`` table entries the pass's step
+    had to read, -1 where no step ran or the cache is contiguous, and a
+    23-field tuple from before the column still reads cleanly."""
+    assert FIELDS[-1] == "kv_live_block_share"
+    fr = FlightRecorder(capacity=8, name="eng")
+    fr.record(_rec(1, time.monotonic(), kv_live_block_share=0.15))
+    assert fr.records()[0]["kv_live_block_share"] == 0.15
+    assert fr.summary()["iterations"] == 1
+
+    older = FlightRecorder(capacity=8, name="old")
+    older.record(_rec(1, time.monotonic(), sp_chunks=2)[:23])
+    recs = older.records()
+    assert "kv_live_block_share" not in recs[0] and recs[0]["sp_chunks"] == 2
+    assert older.summary()["iterations"] == 1
+    older.chrome_counter_events()
 
 
 # -- engine integration -------------------------------------------------------

@@ -240,19 +240,26 @@ def _fits_hbm(compiled, budget=16 * 2 ** 30) -> bool:
     return held < budget
 
 
-def _compile_step(one_chip, shapes, t_logical):
-    """``decode_step_paged`` as the engine jits it: pools donated."""
+def _compile_step(one_chip, shapes, t_logical, kernel=True):
+    """``decode_step_paged`` as the engine jits it: pools donated, and
+    (``kernel``) attention by ``paged_mq_attention`` COMPILED, the
+    choice ``make_serving_programs`` makes on the chip for these
+    bfloat16 pools of 16-row blocks (here the backend is the CPU, so the
+    test makes it)."""
     import jax
     import jax.numpy as jnp
 
     from multiverso_tpu.models.transformer import decode_step_paged
+    from multiverso_tpu.ops.paged_attention import paged_mq_attention
 
     cfg, params, kc, vc, bt = shapes
     slots = bt.shape[0]
     active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    attend = partial(paged_mq_attention, interpret=False) if kernel else None
     return _compile(
         lambda p, kc, vc, bt, tok, pos, act: decode_step_paged(
-            cfg, p, kc, vc, bt, tok, pos, act, t_logical=t_logical),
+            cfg, p, kc, vc, bt, tok, pos, act, t_logical=t_logical,
+            paged_attention=attend),
         params, kc, vc, bt, _ints(one_chip, slots), _ints(one_chip, slots),
         active, donate_argnums=(1, 2))
 
@@ -276,9 +283,13 @@ def _pools_aliased(compiled, pool) -> bool:
         pool.shape) * 2
 
 
-def test_decode_step_paged_compiles(one_chip, serving_shapes):
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "view"])
+def test_decode_step_paged_compiles(one_chip, serving_shapes, kernel):
+    """Both forms of the step: the kernel (bfloat16 pools on the chip)
+    and the gathered view (every other dtype, block shape and backend)."""
     compiled = _compile_step(one_chip, serving_shapes,
-                             _MAX_PROMPT + _MAX_NEW)
+                             _MAX_PROMPT + _MAX_NEW, kernel)
+    assert (_kernel_calls(compiled) > 0) == kernel
     assert _fits_hbm(compiled)
     assert _pools_aliased(compiled, serving_shapes[2])
 
@@ -300,20 +311,28 @@ _CELL_T, _CELL_CHUNK = 1024, 512
 # of one layer's whole pool (``pool[i]`` of a pool just scattered into)
 _CELL_FORBIDDEN = ("= f32[128,64,16,768]", "= f32[128,1024,768]",
                    "= f32[128,1024,12,64]", "= bf16[8193,16,768]")
+# nor, in the step, the view itself: the kernel reads the live blocks out
+# of the pool (24 gathers of 201 MB a step before)
+_CELL_VIEW = ("= bf16[8192,16,768]", "= bf16[128,1024,768]")
 
 
-@pytest.mark.parametrize("program,temp_mb", [("step", 650), ("chunk", 50)])
+@pytest.mark.parametrize("program,temp_mb", [("step", 100), ("chunk", 50)])
 def test_serving_cell_programs_move_kv_once(one_chip, program, temp_mb):
     """``decode_step_paged`` and ``prefill_chunk_paged`` at the CELL's
-    shapes: K and V go from the pool to the attention products once, in
-    bfloat16 (1,230 MB and 222 MB of temporaries before that)."""
+    shapes. The step gathers NO view: one ``paged_mq_attention`` call a
+    layer over the pools as they lie (650 MB of temporaries with the
+    view, 1,230 MB before PR 26). The chunk moves one slot's K and V
+    from the pool to its products once, in bfloat16."""
     shapes = _paged_shapes(one_chip, **_CELL)
     if program == "step":
         compiled = _compile_step(one_chip, shapes, _CELL_T)
+        forbidden = _CELL_FORBIDDEN + _CELL_VIEW
+        assert _kernel_calls(compiled) == shapes[0].n_layers
     else:
         compiled = _compile_chunk(one_chip, shapes, _CELL_T, _CELL_CHUNK)
+        forbidden = _CELL_FORBIDDEN
     text = compiled.as_text()
-    for array in _CELL_FORBIDDEN:
+    for array in forbidden:
         assert array not in text, array
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 10 ** 6
     assert _pools_aliased(compiled, shapes[2])
@@ -387,8 +406,9 @@ def test_sharded_decode_programs_compile_on_two_chips(topo, serving_shapes):
 
 
 # -- LongCat-Flash share: the latent-pool programs at the cell's shapes ----------
-@pytest.mark.parametrize("program,temp_mb", [("step", 700), ("chunk", 850)])
-def test_longcat_cell_programs_fit_the_chip(one_chip, program, temp_mb):
+@pytest.mark.parametrize("program,temp_mb", [("step", 150), ("chunk", 850)])
+def test_longcat_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
+                                            temp_mb):
     """``LongCatLM.serving_programs`` at the shapes of
     ``serve_longcat_ep32_closed128`` (``benchmarks/configs/
     longcat-flash-ep32.json`` under ``traffic/closed128_gen768.json``):
@@ -396,7 +416,10 @@ def test_longcat_cell_programs_fit_the_chip(one_chip, program, temp_mb):
     pool is aliased (a 576-wide row made the compiler copy the pool
     whole around every scatter: the row is padded to 640), no copy of
     the pool and no float32 copy of the ``slots x T`` view appear, and
-    arguments plus temporaries stay inside the chip."""
+    arguments plus temporaries stay inside the chip. The step takes the
+    kernel, as on the chip (``serving_programs`` asks the backend, which
+    is the CPU here: the test answers for it), so it gathers no
+    ``[128, 2304, 640]`` view: 8 gathers of 377 MB a step before."""
     import json
 
     import jax
@@ -415,6 +438,10 @@ def test_longcat_cell_programs_fit_the_chip(one_chip, program, temp_mb):
     S, Bs, C = t["slots"], 16, t["prefill_token_budget"]
     T = t["max_prompt"] + t["max_new"]
     M = -(-T // Bs)
+    import multiverso_tpu.ops  # noqa: F401  (registers the submodule)
+
+    monkeypatch.setattr(sys.modules["multiverso_tpu.ops.paged_attention"],
+                        "_on_tpu", lambda: True)
     lm = object.__new__(longcat.LongCatLM)      # no weights drawn
     lm.config = cfg
     progs = lm.serving_programs(EngineSpec(
@@ -450,3 +477,7 @@ def test_longcat_cell_programs_fit_the_chip(one_chip, program, temp_mb):
     text = compiled.as_text()
     assert f"copy(bf16[{pshape[0]},{pshape[1]},{Bs},640]" not in text
     assert f"f32[{S},{T},640]" not in text
+    if program == "step":
+        assert _kernel_calls(compiled) == cfg.n_sublayers
+        for view in (f"= bf16[{S * M},{Bs},640]", f"= bf16[{S},{T},640]"):
+            assert view not in text, view
