@@ -235,15 +235,16 @@ define_int("prefill_token_budget", 32,
            "decode engine: per-iteration chunked-prefill token budget "
            "(Sarathi-style stall-free admission — inter-token latency is "
            "bounded by one budget-sized chunk regardless of arriving "
-           "prompt length); 0 = monolithic whole-prompt admission")
+           "prompt length); must be > 0, and a budget of max_prompt or "
+           "more prefills every prompt in one chunk")
 define_int("kv_block_size", 16,
            "decode engine: paged KV cache block size in token positions "
            "(vLLM-style block pool — per-slot block tables ride the jitted "
            "step as traced data, so capacity, not slot geometry, bounds "
-           "concurrency); 0 = contiguous per-slot strips")
+           "concurrency); must be > 0")
 define_int("kv_pool_blocks", 0,
            "decode engine: usable KV pool blocks (+1 scratch block is "
-           "added); 0 = auto-size to the contiguous-equivalent capacity "
+           "added); 0 = auto-size to every slot's worst case, "
            "slots * ceil((max_prompt + max_new) / kv_block_size). "
            "serving.block_pool.blocks_for_bytes converts a device-bytes "
            "budget into this count")
@@ -256,8 +257,8 @@ define_int("decode_tp", 1,
            "every per-token program compiles once against matched "
            "in/out_shardings (no spmd repartition in the hot loop). "
            "1 = single-device replicated decode (replicate_for_decode, "
-           "the pre-PR 9 path). Needs kv_block_size > 0, "
-           "decode_tp | n_heads and decode_tp | d_ff")
+           "the pre-PR 9 path). Needs decode_tp | n_heads and "
+           "decode_tp | d_ff")
 define_string("kv_quant", "none",
               "decode engine: paged KV cache storage precision — 'int8' "
               "stores both pools as int8 with a per-(layer, block) fp32 "
@@ -267,7 +268,7 @@ define_string("kv_quant", "none",
               "pool-byte budget holds ~4x the blocks "
               "(block_pool.kv_bytes_per_block reports the real quantized "
               "+ scales footprint). 'none' = fp32 pools, bit-identical "
-              "to the pre-quantization engine. Needs kv_block_size > 0; "
+              "to the pre-quantization engine; "
               "quality face: argmax-match rate vs the fp32 oracle "
               "(docs/SERVING.md 'Quantized KV & params')")
 define_string("decode_param_quant", "none",
@@ -299,8 +300,7 @@ define_bool("prefix_cache", True,
             "paged pool — full blocks get a hash-chained identity, "
             "admission splices the longest cached prefix into the new "
             "sequence's block table (refcounted, copy-on-write) and "
-            "prefills only the remainder; needs kv_block_size > 0 and "
-            "prefill_token_budget > 0. false = every prompt prefills "
+            "prefills only the remainder. false = every prompt prefills "
             "from token zero (the A/B baseline)")
 define_bool("prefill_sp", False,
             "decode engine: sequence-parallel long-prompt prefill over "
@@ -310,8 +310,7 @@ define_bool("prefill_sp", False,
             "budget's worth of rows per device per iteration, so a long "
             "document admits in decode_tp x fewer iterations while the "
             "per-iteration ITL bound holds); shorter prompts keep the "
-            "single-lane chunk program bit-for-bit. Needs kv_block_size "
-            "> 0 and prefill_token_budget > 0; incompatible with "
+            "single-lane chunk program bit-for-bit. Incompatible with "
             "kv_quant=int8 (docs/SERVING.md 'Long-context prefill')")
 define_string("prefill_sp_backend", "ring",
               "decode engine: seqpar prefill collective schedule — "
@@ -335,7 +334,7 @@ define_int("spec_k", 0,
            "[slots, spec_k + 1]; accepted length handled as traced data), "
            "emitting up to spec_k + 1 tokens per iteration with outputs "
            "token-identical to plain greedy decode. 0 = off (today's "
-           "one-token path, bit-for-bit). Needs kv_block_size > 0")
+           "one-token path, bit-for-bit)")
 define_bool("preempt", True,
             "decode engine: overload-graceful serving — OPTIMISTIC "
             "paged-KV admission (reserve prompt blocks only; the "
@@ -347,10 +346,8 @@ define_bool("preempt", True,
             "output, host-side scheduling only (block tables stay "
             "traced data). Anti-livelock: -preempt_budget per request "
             "and a guaranteed-progress floor (the OLDEST live sequence "
-            "is never preempted). Needs kv_block_size > 0 and "
-            "prefill_token_budget > 0 (silently inert otherwise). "
-            "false = the pre-PR worst-case prompt+max_new up-front "
-            "reservation (the A/B baseline)")
+            "is never preempted). false = the worst-case "
+            "prompt+max_new up-front reservation (the A/B baseline)")
 define_int("preempt_budget", 3,
            "decode engine: max times one request may be preempted; a "
            "request whose budget is spent re-admits PESSIMISTICALLY "

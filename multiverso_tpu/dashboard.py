@@ -567,8 +567,8 @@ class Dashboard:
 
         ``{name: {"type": kind, ...stats}}`` — JSON-serializable floats
         and ints only, so the same object feeds the JSON-lines reporter,
-        the Prometheus renderer, and bench archives
-        (``tools/serving_bench.py``) without per-sink formats.
+        the Prometheus renderer, and bench archives without per-sink
+        formats.
         """
         with cls._lock:
             monitors = list(cls._monitors.values())

@@ -540,9 +540,7 @@ class LongCatLM:
 
         cfg = self.config
         who = f"DecodeEngine {spec.name!r} (LongCat)"
-        refuse(who, spec, contiguous="a paged latent pool only "
-               "(kv_block_size > 0)", monolithic="chunked prefill only "
-               "(prefill_token_budget > 0)", kv_quant="no int8 latent pool",
+        refuse(who, spec, kv_quant="no int8 latent pool",
                param_quant="no int8 parameter pin", decode_tp="no "
                "tensor-parallel decode programs", spec_k="no verify step",
                prefill_sp="no sequence-parallel prefill")

@@ -320,60 +320,6 @@ def prefill(cfg: TransformerConfig, params: Dict[str, Any],
     return logits, jnp.stack(ks), jnp.stack(vs)
 
 
-def decode_step(cfg: TransformerConfig, params: Dict[str, Any],
-                k_cache: jax.Array, v_cache: jax.Array, tok: jax.Array,
-                pos: jax.Array, active: jax.Array
-                ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One fused token step over S persistent slots.
-
-    ``k_cache``/``v_cache`` [L, S, T, D], ``tok``/``pos`` [S] int32,
-    ``active`` [S] bool. Each slot is an independent sequence: writes its
-    token's K/V at ``pos``, attends the cache through ``pos``
-    (:func:`_cached_attention` — the math of :func:`greedy_decode`'s scan
-    body, with the batch dim reinterpreted as the slot dim), and emits its
-    greedy next token. Dead slots still flow through the fused program
-    (one compiled trace regardless of which slots live) but emit pad and
-    keep a frozen ``pos``; their cache writes are parked at position
-    ``T - 1`` — never at the frozen ``pos``, which could sit inside a
-    prompt region a chunked admission is prefilling between iterations —
-    and a later admission/live decode overwrites anything they left
-    before attending it.
-
-    Returns ``(k_cache, v_cache, next_tok [S], pos [S])`` — jit with
-    ``donate_argnums`` on the caches so XLA updates them in place.
-    """
-    S = tok.shape[0]
-    T = k_cache.shape[2]
-    slot_ix = jnp.arange(S)
-    # dead lanes still flow through the fused program but must NOT write
-    # at their frozen ``pos``: a chunked prefill may be mid-flight in
-    # that slot (serving/decode_engine.py), and a stale-pos write
-    # between two chunks would clobber prompt K/V already inserted.
-    # Park dead writes at T-1 — a position strictly past any prompt
-    # (T = max_prompt + max_new, max_new >= 1) that a live generation
-    # overwrites before its attention mask ever reaches it.
-    write_pos = jnp.where(active, pos, T - 1)
-    h = (jnp.take(params["embed"], tok, axis=0)
-         + jnp.take(params["pos"], pos, axis=0))
-    for i in range(cfg.n_layers):
-        layer = jax.tree.map(lambda a: a[i], params["layers"])
-        x = _rmsnorm(h, layer["ln1_g"])
-        q, k, v = x @ layer["w_q"], x @ layer["w_k"], x @ layer["w_v"]
-        k_cache = k_cache.at[i, slot_ix, write_pos].set(k)
-        v_cache = v_cache.at[i, slot_ix, write_pos].set(v)
-        h = h + _cached_attention(
-            q, k_cache[i], v_cache[i], cfg.n_heads, pos) @ layer["w_o"]
-        x = _rmsnorm(h, layer["ln2_g"])
-        h = h + jax.nn.gelu(x @ layer["w_ff1"]) @ layer["w_ff2"]
-    h = _rmsnorm(h, params["ln_f_g"])
-    out = jnp.einsum("sd,vd->sv", h, params["embed"],
-                     preferred_element_type=jnp.float32)
-    nxt = jnp.argmax(out, axis=-1).astype(tok.dtype)
-    nxt = jnp.where(active, nxt, jnp.zeros_like(nxt))
-    pos = jnp.where(active, pos + 1, pos)
-    return k_cache, v_cache, nxt, pos
-
-
 def _chunk_attention(q, k_cache, v_cache, n_heads: int, offset) -> jax.Array:
     """Chunk attention: ``q`` [C, D] against one slot's cache [T, D].
 
@@ -401,81 +347,19 @@ def _chunk_attention(q, k_cache, v_cache, n_heads: int, offset) -> jax.Array:
     return out.reshape(C, D).astype(q.dtype)
 
 
-def prefill_chunk(cfg: TransformerConfig, params: Dict[str, Any],
-                  k_cache: jax.Array, v_cache: jax.Array, slot: jax.Array,
-                  tokens: jax.Array, offset: jax.Array, length: jax.Array
-                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Incremental prefill: one fixed-size chunk of one slot's prompt.
-
-    ``k_cache``/``v_cache`` [L, S, T, D] (the decode engine's slot
-    caches), ``tokens`` [C] right-padded chunk ids, ``slot`` the target
-    slot, ``offset`` the cache position of ``tokens[0]``, ``length`` the
-    real token count in this chunk (``1 <= length <= C``). All of slot/
-    offset/length are traced scalars: ONE compiled trace per chunk size
-    serves every (slot, offset, partial-fill) combination — the
-    Sarathi-style budget knob adds exactly one trace to the engine's
-    accounting, next to the single fused :func:`decode_step`.
-
-    Each chunk position's K/V is written in place at
-    ``[l, slot, offset + i]`` (a per-position scatter) BEFORE attention,
-    so causal attention for position ``offset + i`` covers the already-
-    inserted prefix ``[0, offset)`` from earlier chunks plus the chunk's
-    own positions ``<= i`` via :func:`_chunk_attention`'s mask. The
-    write is a scatter, NOT a C-wide dynamic-update-slice: a final
-    chunk's pad tail can extend past ``T`` (``ceil(P/C)*C`` need not fit
-    ``max_prompt + max_new``), and a DUS would CLAMP its start index
-    back over real prompt positions — silent K/V corruption. Scatter
-    pad writes past ``T - 1`` simply drop (the ``add_rows`` XLA
-    out-of-bounds contract); in-bounds real positions are distinct, so
-    the write stays deterministic. In-bounds pad garbage lands at cache
-    positions the decode mask only reaches AFTER :func:`decode_step`
-    overwrites them (the :func:`prefill` pad contract), and pad
-    position-embedding reads clamp (``jnp.take``'s OOB mode), so the
-    garbage is never observable.
-
-    Returns ``(k_cache, v_cache, last_logits [V])`` — the logits of
-    position ``offset + length - 1``. Callers use them only on the
-    FINAL chunk of a prompt, where they are the prompt's last real
-    position: the first generated token still falls out of the last
-    chunk, exactly as it falls out of a whole-prompt prefill.
-    """
-    C = tokens.shape[0]
-    pos_ix = offset + jnp.arange(C)
-    h = (jnp.take(params["embed"], tokens, axis=0)
-         + jnp.take(params["pos"], pos_ix, axis=0))
-    for i in range(cfg.n_layers):
-        layer = jax.tree.map(lambda a: a[i], params["layers"])
-        x = _rmsnorm(h, layer["ln1_g"])
-        q, k, v = x @ layer["w_q"], x @ layer["w_k"], x @ layer["w_v"]
-        k_cache = k_cache.at[i, slot, pos_ix].set(k)
-        v_cache = v_cache.at[i, slot, pos_ix].set(v)
-        kc = jax.lax.dynamic_index_in_dim(k_cache[i], slot, 0, keepdims=False)
-        vc = jax.lax.dynamic_index_in_dim(v_cache[i], slot, 0, keepdims=False)
-        h = h + _chunk_attention(
-            q, kc, vc, cfg.n_heads, offset) @ layer["w_o"]
-        x = _rmsnorm(h, layer["ln2_g"])
-        h = h + jax.nn.gelu(x @ layer["w_ff1"]) @ layer["w_ff2"]
-    h = _rmsnorm(h, params["ln_f_g"])
-    last = jnp.take(h, length - 1, axis=0)
-    logits = jnp.einsum("d,vd->v", last, params["embed"],
-                        preferred_element_type=jnp.float32)
-    return k_cache, v_cache, logits
-
-
 # -- serving: paged KV cache --------------------------------------------------
 #
-# The slotted [L, S, T, D] cache above gives every slot a contiguous strip
-# sized for the worst case T = max_prompt + max_new; a short sequence wastes
-# almost its whole strip. The paged layout (vLLM/PagedAttention) replaces the
-# strips with ONE block pool [L, n_blocks, block_size, D] plus a per-slot
-# BLOCK TABLE [S, max_blocks_per_seq] of int32 block ids: logical cache
-# position p of slot s lives at physical (block_tables[s, p // Bs], p % Bs).
-# Block tables are TRACED DATA (fixed [S, M] shape), so the one-compiled-
-# trace-per-engine-config invariant survives paging: reads become gathers
-# through the table, writes become (block, offset) scatters, and which blocks
-# a slot owns never touches a shape.
+# The decode engine's cache (vLLM/PagedAttention) is ONE block pool
+# [L, n_blocks, block_size, D] plus a per-slot BLOCK TABLE
+# [S, max_blocks_per_seq] of int32 block ids: logical cache position p of
+# slot s lives at physical (block_tables[s, p // Bs], p % Bs), so a sequence
+# holds only the blocks it needs, not a strip sized for the worst case
+# T = max_prompt + max_new. Block tables are TRACED DATA (fixed [S, M]
+# shape), so every program compiles once per engine config: reads are
+# gathers through the table, writes are (block, offset) scatters, and which
+# blocks a slot owns never touches a shape.
 #
-# Conventions shared by the three paged entry points below (and by
+# Conventions shared by the paged entry points below (and by
 # serving/block_pool.py, which owns the host-side allocator):
 #
 # * block id 0 is the SCRATCH block: the block-table pad sentinel, the
@@ -485,11 +369,11 @@ def prefill_chunk(cfg: TransformerConfig, params: Dict[str, Any],
 #   every position <= pos resolves to a real allocated block.
 # * per-slot views are built by ONE helper, :func:`_paged_view`, and SLICED
 #   to the engine's logical cache length ``t_logical`` (= max_prompt +
-#   max_new) before attention, so the paged attention operand has the exact
-#   shape of the contiguous cache it replaces and both layouts run the same
-#   attention on it (hence bit-exact outputs across layouts) — the gather's
-#   tail positions past a slot's allocation hold scratch garbage, masked
-#   off exactly like the contiguous strips' dead writes.
+#   max_new) before attention, so the attention operand is ``[.., T, D]``
+#   whatever the block size: the same products and rounding points as
+#   :func:`greedy_decode`'s cache, hence the same tokens. The gather's tail
+#   positions past a slot's allocation hold scratch garbage, which the
+#   position mask never reaches.
 
 
 def _paged_view(pool: jax.Array, layer: int, tables: jax.Array) -> jax.Array:
@@ -521,14 +405,18 @@ def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
 
     ``k_pool``/``v_pool`` [L, N, Bs, D] (block 0 = scratch),
     ``block_tables`` [S, M] int32 (traced — one compiled trace per
-    engine config regardless of block assignment), ``tok``/``pos``/
-    ``active`` as in :func:`decode_step`. Each live slot writes its
-    token's K/V at ``(block_tables[s, pos // Bs], pos % Bs)`` and
-    attends its gathered view sliced to ``t_logical``; dead lanes park
-    their writes in the scratch block (the paged analogue of the
-    contiguous path's ``T - 1`` parking — scratch is never reachable by
-    a live mask, so a mid-flight chunked prefill's prompt region cannot
-    be clobbered).
+    engine config regardless of block assignment), ``tok``/``pos`` [S]
+    int32, ``active`` [S] bool. Each slot is an independent sequence
+    (the math of :func:`greedy_decode`'s scan body, with the batch dim
+    as the slot dim): a live slot writes its token's K/V at
+    ``(block_tables[s, pos // Bs], pos % Bs)``, attends its gathered
+    view sliced to ``t_logical`` through ``pos``, and emits its greedy
+    next token. Dead slots still flow through the fused program (one
+    compiled trace whichever slots live) but emit pad, keep a frozen
+    ``pos`` and park their writes in the scratch block: never at the
+    frozen ``pos``, which could sit inside a prompt region a chunked
+    admission is prefilling between iterations, and scratch is never
+    reachable by a live mask.
 
     ``paged_attention`` (:func:`ops.paged_attention.paged_mq_attention`,
     chosen by :func:`make_serving_programs` through
@@ -563,9 +451,9 @@ def decode_step_paged(cfg: TransformerConfig, params: Dict[str, Any],
                 lengths, scale=1.0 / np.sqrt(D // cfg.n_heads), wv=D)
             att = _own_columns(full, own).astype(q.dtype)
         else:
-            # each slot's blocks as a contiguous [S, T, D] view — the
-            # operand shape of the contiguous cache, so both layouts run
-            # the same attention (same products, same rounding points)
+            # each slot's blocks as a contiguous [S, T, D] view: the
+            # operand shape, products and rounding points of
+            # greedy_decode's cache
             kc = _paged_view(k_pool, i, block_tables)
             vc = _paged_view(v_pool, i, block_tables)
             att = _cached_attention(q, kc[:, :T], vc[:, :T], cfg.n_heads,
@@ -590,20 +478,30 @@ def prefill_chunk_paged(cfg: TransformerConfig, params: Dict[str, Any],
                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Incremental prefill of one fixed-size chunk into the paged pool.
 
-    The paged :func:`prefill_chunk`: same contract (``slot``/``offset``/
-    ``length`` all traced, ONE compiled trace per chunk size), but K/V
-    writes scatter to ``(block_tables[slot, p // Bs], p % Bs)`` and the
-    chunk attends the slot's gathered view. Pad positions (``i >=
-    length``) route to the scratch block explicitly — the paged
-    analogue of the contiguous scatter's drop-past-``T`` contract: a
-    final chunk's pad tail must not clamp onto real prompt blocks, and
-    with the table gather it would (the table row gather clamps), so
-    the pad lanes are masked to scratch before the scatter instead.
-    In-bounds pad garbage (real positions past ``length`` inside the
-    reservation) lands in allocated blocks that decode overwrites
-    before its mask reaches them, exactly as in the contiguous layout.
+    ``tokens`` [C] right-padded chunk ids, ``slot`` the target slot,
+    ``offset`` the cache position of ``tokens[0]``, ``length`` the real
+    token count in this chunk (``1 <= length <= C``). All of slot/
+    offset/length are traced scalars: ONE compiled trace per chunk size
+    serves every (slot, offset, partial-fill) combination, next to the
+    single fused :func:`decode_step_paged`.
 
-    Returns ``(k_pool, v_pool, last_logits [V])``.
+    Each chunk position's K/V scatters to ``(block_tables[slot, p //
+    Bs], p % Bs)`` BEFORE attention, so causal attention for position
+    ``offset + i`` covers the already-inserted prefix ``[0, offset)``
+    from earlier chunks plus the chunk's own positions ``<= i`` via
+    :func:`_chunk_attention`'s mask over the slot's gathered view. Pad
+    positions (``i >= length``) route to the scratch block explicitly:
+    a final chunk's pad tail can extend past ``T`` (``ceil(P/C)*C``
+    need not fit ``max_prompt + max_new``), and the table row gather
+    clamps, so unmasked it would land on real prompt blocks, silent
+    K/V corruption. Pad position-embedding reads clamp (``jnp.take``'s
+    OOB mode) and their rows are never read.
+
+    Returns ``(k_pool, v_pool, last_logits [V])``: the logits of
+    position ``offset + length - 1``. Callers use them only on the
+    FINAL chunk of a prompt, where they are the prompt's last real
+    position: the first generated token falls out of the last chunk,
+    exactly as it falls out of a whole-prompt :func:`prefill`.
     """
     C = tokens.shape[0]
     Bs = k_pool.shape[2]
@@ -702,38 +600,6 @@ def prefill_chunk_paged_sp(cfg: TransformerConfig, params: Dict[str, Any],
     logits = jnp.einsum("d,vd->v", last, params["embed"],
                         preferred_element_type=jnp.float32)
     return k_pool, v_pool, logits
-
-
-def cache_insert_paged(k_pool: jax.Array, v_pool: jax.Array,
-                       block_tables: jax.Array, ks: jax.Array, vs: jax.Array
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Write b prefilled sequences' K/V [L, b, P, D] through block tables.
-
-    The paged :func:`cache_insert`: ``block_tables`` [b, M] carries one
-    PER-ROW table (traced), so placement is encoded in data, not in a
-    DUS chain — row ``r``'s position ``p`` scatters to
-    ``(block_tables[r, p // Bs], p % Bs)``. A caller padding a partial
-    batch points the pad rows' tables entirely at the scratch sentinel
-    (block 0): their writes land in scratch, where the order-undefined
-    duplicate-index scatter is harmless because nothing reads it (the
-    contiguous path needed the row-0-last DUS ordering for exactly this;
-    the paged path needs only the sentinel). Positions past a row's
-    true prompt length write garbage into its reservation (overwritten
-    by decode before the mask reaches them — the :func:`prefill`
-    contract) or, past the reservation, into scratch via the table's
-    sentinel padding.
-    """
-    L, b, P, _ = ks.shape
-    Bs = k_pool.shape[2]
-    M = block_tables.shape[1]
-    p_ix = jnp.arange(P)
-    blk = jnp.take(block_tables, jnp.clip(p_ix // Bs, 0, M - 1),
-                   axis=1)                                       # [b, P]
-    off = jnp.broadcast_to(p_ix % Bs, (b, P))
-    for i in range(L):
-        k_pool = k_pool.at[i, blk, off].set(ks[i])
-        v_pool = v_pool.at[i, blk, off].set(vs[i])
-    return k_pool, v_pool
 
 
 # -- serving: speculative decoding (fixed-K verify step) ----------------------
@@ -931,25 +797,6 @@ def kv_pool_sharding(mesh, tp_axis: str = DECODE_TP_AXIS) -> NamedSharding:
     head slice of ``D`` — each device holds its heads' cache for every
     block, so table gathers/scatters stay device-local."""
     return NamedSharding(mesh, P(None, None, None, tp_axis))
-
-
-def admit_insert_paged(cfg: TransformerConfig, params: Dict[str, Any],
-                       k_pool: jax.Array, v_pool: jax.Array,
-                       block_tables: jax.Array, tokens: jax.Array,
-                       lengths: jax.Array
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused monolithic admission against the paged pool: whole-prompt
-    :func:`prefill`, last-REAL-position first tokens, and the
-    :func:`cache_insert_paged` table scatter — one dispatch. The body
-    the engine jits; shared by the replicated and sharded variants so
-    the two paths cannot drift."""
-    logits, ks, vs = prefill(cfg, params, tokens)
-    last = jnp.take_along_axis(
-        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    first = jnp.argmax(last, axis=-1).astype(tokens.dtype)
-    k_pool, v_pool = cache_insert_paged(k_pool, v_pool, block_tables,
-                                        ks, vs)
-    return first, k_pool, v_pool
 
 
 def cow_block_copy(k_pool: jax.Array, v_pool: jax.Array, src: jax.Array,
@@ -1229,72 +1076,6 @@ def verify_step_paged_q(cfg: TransformerConfig, params: Dict[str, Any],
             jnp.where(valid, nxt, jnp.zeros_like(nxt)))
 
 
-def cache_insert_paged_q(k_pool: jax.Array, v_pool: jax.Array,
-                         k_scales: jax.Array, v_scales: jax.Array,
-                         block_tables: jax.Array, ks: jax.Array,
-                         vs: jax.Array
-                         ) -> Tuple[jax.Array, jax.Array, jax.Array,
-                                    jax.Array]:
-    """Quantized :func:`cache_insert_paged`: b whole prompts' fp32 K/V
-    [L, b, P, D] quantize through per-row block tables. Positions write
-    from 0, so every written block's offset 0 is covered — its scale
-    resets from the fresh data (the reallocation contract). Pad rows
-    point at scratch, where order-undefined duplicates stay unobservable.
-    """
-    L, b, P, _ = ks.shape
-    Bs = k_pool.shape[2]
-    M = block_tables.shape[1]
-    p_ix = jnp.arange(P)
-    loc = jnp.clip(p_ix // Bs, 0, M - 1)
-    flat_ix = jnp.broadcast_to(loc * Bs + p_ix % Bs, (b, P))
-    fresh = jnp.broadcast_to((p_ix % Bs == 0).astype(jnp.float32), (b, P))
-    rows_ix = jnp.arange(b)[:, None]
-    loc_b = jnp.broadcast_to(loc, (b, P))
-
-    def write(pool, scales, rows):
-        rows_s = jnp.take(scales, block_tables, axis=0)        # [b, M]
-        flat = _kv_q_dequant(jnp.take(pool, block_tables, axis=0),
-                             rows_s).reshape(b, M * Bs, -1)
-        rows32 = rows.astype(jnp.float32)
-        flat = flat.at[rows_ix, flat_ix].set(rows32)
-        reset = jnp.zeros((b, M), jnp.float32).at[rows_ix, loc_b].max(
-            fresh) > 0
-        contrib = jnp.zeros((b, M), jnp.float32).at[rows_ix, loc_b].max(
-            jnp.max(jnp.abs(rows32), axis=-1))
-        new_s = jnp.maximum(jnp.where(reset, 0.0, rows_s),
-                            contrib / _KV_QMAX)
-        new_q = _kv_q_requant(flat.reshape(b, M, Bs, -1), new_s)
-        return (pool.at[block_tables].set(new_q),
-                scales.at[block_tables].set(new_s))
-
-    for i in range(L):
-        kp, ksc = write(k_pool[i], k_scales[i], ks[i])
-        vp, vsc = write(v_pool[i], v_scales[i], vs[i])
-        k_pool, k_scales = k_pool.at[i].set(kp), k_scales.at[i].set(ksc)
-        v_pool, v_scales = v_pool.at[i].set(vp), v_scales.at[i].set(vsc)
-    return k_pool, v_pool, k_scales, v_scales
-
-
-def admit_insert_paged_q(cfg: TransformerConfig, params: Dict[str, Any],
-                         k_pool: jax.Array, v_pool: jax.Array,
-                         k_scales: jax.Array, v_scales: jax.Array,
-                         block_tables: jax.Array, tokens: jax.Array,
-                         lengths: jax.Array
-                         ) -> Tuple[jax.Array, jax.Array, jax.Array,
-                                    jax.Array, jax.Array]:
-    """Quantized :func:`admit_insert_paged`: the fp32 whole-prompt
-    prefill and first-token argmax are unchanged (the first token is
-    computed BEFORE quantization, like the chunked path's final-chunk
-    logits); only the cache insert quantizes."""
-    logits, ks, vs = prefill(cfg, params, tokens)
-    last = jnp.take_along_axis(
-        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    first = jnp.argmax(last, axis=-1).astype(tokens.dtype)
-    k_pool, v_pool, k_scales, v_scales = cache_insert_paged_q(
-        k_pool, v_pool, k_scales, v_scales, block_tables, ks, vs)
-    return first, k_pool, v_pool, k_scales, v_scales
-
-
 def cow_block_copy_q(k_pool: jax.Array, v_pool: jax.Array,
                      k_scales: jax.Array, v_scales: jax.Array,
                      src: jax.Array, dst: jax.Array
@@ -1351,8 +1132,8 @@ def make_sharded_decode_programs(cfg: TransformerConfig, mesh,
                                  ) -> Dict[str, Any]:
     """Pre-partitioned decode-mesh variants of the paged serving programs.
 
-    Returns ``{"step", "chunk", "admit", "cow", "verify",
-    "param_shardings", "pool_sharding"}`` — each program jitted exactly
+    Returns ``{"step", "chunk", "cow", "verify", "param_shardings",
+    "pool_sharding"}`` — each program jitted exactly
     once with matched
     ``in_shardings``/``out_shardings``: params carry
     :func:`decode_param_shardings`, both pools carry
@@ -1416,13 +1197,6 @@ def make_sharded_decode_programs(cfg: TransformerConfig, mesh,
                           rep),
             out_shardings=(pool, pool, rep, rep, rep),
             donate_argnums=kv_donate)
-        admit = jax.jit(
-            lambda params, kc, vc, ksc, vsc, bts, toks, lens:
-            admit_insert_paged_q(cfg, pf(params), kc, vc, ksc, vsc, bts,
-                                 toks, lens),
-            in_shardings=(ps, pool, pool, rep, rep, rep, rep, rep),
-            out_shardings=(rep, pool, pool, rep, rep),
-            donate_argnums=kv_donate)
         cow = jax.jit(
             lambda kc, vc, ksc, vsc, src, dst: cow_block_copy_q(
                 kc, vc, ksc, vsc, src, dst),
@@ -1437,8 +1211,8 @@ def make_sharded_decode_programs(cfg: TransformerConfig, mesh,
                           rep),
             out_shardings=(pool, pool, rep, rep, rep),
             donate_argnums=kv_donate)
-        return {"step": step, "chunk": chunk, "admit": admit,
-                "cow": cow, "verify": verify, "param_shardings": ps,
+        return {"step": step, "chunk": chunk, "cow": cow,
+                "verify": verify, "param_shardings": ps,
                 "pool_sharding": pool}
     kv_donate = (1, 2) if donate else ()
     step = jax.jit(
@@ -1452,12 +1226,6 @@ def make_sharded_decode_programs(cfg: TransformerConfig, mesh,
             cfg, pf(params), kc, vc, bt, slot, toks, off, n, t_logical=T),
         in_shardings=(ps, pool, pool, rep, rep, rep, rep, rep),
         out_shardings=(pool, pool, rep),
-        donate_argnums=kv_donate)
-    admit = jax.jit(
-        lambda params, kc, vc, bts, toks, lens: admit_insert_paged(
-            cfg, pf(params), kc, vc, bts, toks, lens),
-        in_shardings=(ps, pool, pool, rep, rep, rep),
-        out_shardings=(rep, pool, pool),
         donate_argnums=kv_donate)
     # every program wraps in a FRESH lambda (cow included): jit caches
     # key on the function object, so jitting a shared module-level
@@ -1481,9 +1249,8 @@ def make_sharded_decode_programs(cfg: TransformerConfig, mesh,
         in_shardings=(ps, pool, pool, rep, rep, rep, rep, rep),
         out_shardings=(pool, pool, rep),
         donate_argnums=kv_donate)
-    progs = {"step": step, "chunk": chunk, "admit": admit, "cow": cow,
-             "verify": verify, "param_shardings": ps,
-             "pool_sharding": pool}
+    progs = {"step": step, "chunk": chunk, "cow": cow, "verify": verify,
+             "param_shardings": ps, "pool_sharding": pool}
     if prefill_sp != "none":
         progs["chunk_sp"] = jax.jit(
             lambda params, kc, vc, bt, slot, toks, off, n:
@@ -1499,8 +1266,8 @@ def make_sharded_decode_programs(cfg: TransformerConfig, mesh,
 def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
     """``TransformerLM``'s side of the engine's seam
     (``serving/programs.py``): two ``[L, N + 1, Bs, d_model]`` K/V
-    pools (contiguous: ``[L, S, T, d_model]`` strips; ``kv_quant="int8"``:
-    int8 pools and two ``[L, N + 1]`` float32 scale arrays) and the
+    pools (``kv_quant="int8"``: int8 pools and two ``[L, N + 1]``
+    float32 scale arrays) and the
     programs of this module over them, each jitted ONCE here, at engine
     construction (RT106). ``spec`` is the engine's resolved
     :class:`serving.programs.EngineSpec`."""
@@ -1514,11 +1281,9 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
     if T > cfg.max_seq:
         Log.fatal(f"{who}: max_prompt {spec.max_prompt} + "
                   f"max_new {spec.max_new} exceeds max_seq {cfg.max_seq}")
-    L, D, S = cfg.n_layers, cfg.d_model, spec.slots
-    paged = spec.block_size > 0
+    L, D = cfg.n_layers, cfg.d_model
     quant = spec.kv_quant == "int8"
-    pool_shape = ((L, spec.pool_blocks + 1, spec.block_size, D) if paged
-                  else (L, S, T, D))
+    pool_shape = (L, spec.pool_blocks + 1, spec.block_size, D)
     pool_dtype = jnp.dtype(jnp.int8 if quant else cfg.dtype)
     pools = [(pool_shape, pool_dtype)] * 2
     if quant:
@@ -1528,9 +1293,8 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
     n = len(pools)
     out = ServingPrograms(
         pools=tuple(pools), step=None,
-        bytes_per_block=(kv_bytes_per_block(
-            L, D, spec.block_size, np.dtype(cfg.dtype), quant=spec.kv_quant)
-            if paged else 0),
+        bytes_per_block=kv_bytes_per_block(
+            L, D, spec.block_size, np.dtype(cfg.dtype), quant=spec.kv_quant),
         scale_pools=(2, 3) if quant else ())
     prequant = (quantize_decode_params if spec.param_quant == "int8"
                 else (lambda value: value))
@@ -1550,8 +1314,7 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
         # slice to shard, and every shard needs every block's scale
         out.pool_targets = (progs["pool_sharding"],) * 2 + (
             (NamedSharding(spec.mesh, P()),) * 2 if quant else ())
-        out.admit, out.chunk, out.step = (progs["admit"], progs["chunk"],
-                                          progs["step"])
+        out.chunk, out.step = progs["chunk"], progs["step"]
         out.chunk_sp = progs.get("chunk_sp")
         out.cow = progs["cow"] if spec.prefix else None
         out.verify = progs["verify"] if spec.spec_k else None
@@ -1574,12 +1337,7 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
         # function object, so jitting a shared module-level function
         # directly would pool every engine's compiled traces on one
         # handle and break per-engine one-trace accounting
-        if paged and quant:
-            out.admit = jax.jit(
-                lambda params, kc, vc, ksc, vsc, bts, toks, lengths:
-                admit_insert_paged_q(cfg, pf(params), kc, vc, ksc, vsc,
-                                     bts, toks, lengths),
-                donate_argnums=donate)
+        if quant:
             out.chunk = jax.jit(
                 lambda params, kc, vc, ksc, vsc, bt, slot, toks, off, n:
                 prefill_chunk_paged_q(cfg, pf(params), kc, vc, ksc, vsc,
@@ -1605,15 +1363,10 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
                     lambda kc, vc, ksc, vsc, src, dst:
                     cow_block_copy_q(kc, vc, ksc, vsc, src, dst),
                     donate_argnums=cow_donate)
-        elif paged:
+        else:
             # block tables ride every call as DATA ([S, M] int32, fixed
             # shape): which blocks a slot owns never touches an aval, so
-            # the one-trace-per-config invariant survives paging
-            out.admit = jax.jit(
-                lambda params, kc, vc, bts, toks, lengths:
-                admit_insert_paged(cfg, pf(params), kc, vc, bts, toks,
-                                   lengths),
-                donate_argnums=donate)
+            # each program compiles once per engine config
             out.chunk = jax.jit(
                 lambda params, kc, vc, bt, slot, toks, off, n:
                 prefill_chunk_paged(cfg, pf(params), kc, vc, bt, slot,
@@ -1664,26 +1417,6 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
                     lambda kc, vc, src, dst: cow_block_copy(kc, vc, src,
                                                             dst),
                     donate_argnums=cow_donate)
-        else:
-            def _admit_insert(params, kc, vc, slots, toks, lengths):
-                logits, ks, vs = prefill(cfg, pf(params), toks)
-                last = jnp.take_along_axis(
-                    logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-                first = jnp.argmax(last, axis=-1).astype(toks.dtype)
-                kc, vc = cache_insert(kc, vc, slots, ks, vs)
-                return first, kc, vc
-
-            out.admit = jax.jit(_admit_insert, donate_argnums=donate)
-            out.chunk = jax.jit(
-                lambda params, kc, vc, slot, toks, off, n: prefill_chunk(
-                    cfg, pf(params), kc, vc, slot, toks, off, n),
-                donate_argnums=donate)
-            # THE fused step: all shapes fixed by the engine config ->
-            # exactly one compiled trace no matter which slots are live
-            out.step = jax.jit(
-                lambda params, kc, vc, tok, pos, active: decode_step(
-                    cfg, pf(params), kc, vc, tok, pos, active),
-                donate_argnums=donate)
 
     # -- KV transfer plane (disaggregated prefill/decode) --------------------
     # two programs, prefix-cache engines only: FETCH pulls one block's
@@ -1705,32 +1438,6 @@ def make_serving_programs(cfg: TransformerConfig, spec) -> Any:
                 for pool, piece in zip(a[:n], a[n + 1:])),
             donate_argnums=tuple(range(n)) if spec.donate else ())
     return out
-
-
-def cache_insert(k_cache: jax.Array, v_cache: jax.Array, slots: jax.Array,
-                 ks: jax.Array, vs: jax.Array
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """Write b prefilled sequences' K/V [L, b, P, D] into slots ``slots``.
-
-    ``slots`` [b] are traced slot indices (one compiled insert per
-    (batch bucket b, prompt bucket P), reused for every slot choice).
-    The rows land as a CHAIN of dynamic-update-slices, iterated so row 0
-    writes LAST: a caller padding a partial batch up to bucket b points
-    the pad rows at ``slots[0]`` and the real row deterministically
-    overwrites them (an XLA scatter with duplicate indices would be
-    order-undefined). Positions past a prompt's true length hold prefill
-    garbage — decode overwrites position ``pos`` before the attention
-    mask ever reaches it, so the garbage is never observable (the
-    :func:`prefill` contract).
-    """
-    zero = jnp.zeros((), slots.dtype)
-    for i in reversed(range(ks.shape[1])):
-        start = (zero, slots[i], zero, zero)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, ks[:, i][:, None], start)
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, vs[:, i][:, None], start)
-    return k_cache, v_cache
 
 
 def greedy_decode(cfg: TransformerConfig, params: Dict[str, Any],

@@ -151,7 +151,7 @@ class CostLedger:
                  slo_lat_ms: Optional[float] = None) -> None:
         from .. import config
         self.engine = engine
-        # per-block K/V bytes (paged engines): what turns kv_block_s
+        # per-block K/V bytes: what turns kv_block_s
         # into byte-seconds under the -cost_block_byte_s weight
         self.block_bytes = int(block_bytes)
         self.default_tenant = str(

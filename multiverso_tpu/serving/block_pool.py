@@ -1,10 +1,10 @@
 """Block-pool allocator for the paged KV cache — refcounted + content-addressed.
 
-The decode engine's original cache gave every slot a contiguous
-``[T, D]`` strip sized for the worst case ``max_prompt + max_new`` — a
-short sequence wasted almost its whole strip, so concurrency was capped
-by slot geometry rather than by actual KV bytes. The paged layout
-(vLLM/PagedAttention) carves the same memory into fixed-size **blocks**
+A cache that gives every slot a contiguous ``[T, D]`` strip sized for
+the worst case ``max_prompt + max_new`` lets a short sequence waste
+almost its whole strip, so concurrency is capped by slot geometry rather
+than by actual KV bytes. The paged layout (vLLM/PagedAttention) carves
+the same memory into fixed-size **blocks**
 of ``block_size`` token positions each; a sequence owns
 ``ceil((prompt_len + max_new) / block_size)`` blocks, recorded in a
 per-slot **block table** the jitted programs consume as traced data.
@@ -95,8 +95,8 @@ def blocks_for_bytes(budget_bytes: int, n_layers: int, d_model: int,
 
     Raises for a budget too small for scratch + one usable block: the
     result feeds ``kv_pool_blocks``, where ``0`` means AUTO-size — a
-    silent 0 here would turn "tiny budget" into "contiguous-equivalent
-    pool", a many-fold device-memory overshoot."""
+    silent 0 here would turn "tiny budget" into "every slot's worst
+    case", a many-fold device-memory overshoot."""
     per = kv_bytes_per_block(n_layers, d_model, block_size, dtype, quant)
     n = budget_bytes // per - 1
     if n < 1:

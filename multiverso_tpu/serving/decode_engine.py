@@ -1,30 +1,30 @@
-"""Continuous-batching decode engine: slot KV cache + iteration scheduling.
+"""Continuous-batching decode engine: paged KV cache + iteration scheduling.
 
 The micro-batcher's ``lm_decode`` workload locks B requests together
 through a full ``greedy_decode`` to ``max_new``: one long generation
 holds every short one hostage, and an arriving request waits for the
 whole batch to drain before it can even prefill (head-of-line blocking
 at completion AND admission). This engine removes both stalls with the
-Orca design — iteration-level scheduling over a persistent slotted KV
-cache (the fixed-slot precursor to vLLM's PagedAttention):
+Orca design — iteration-level scheduling over a persistent KV cache
+paged as in vLLM's PagedAttention. There is ONE cache layout (the block
+pool) and ONE admission path (chunked prefill):
 
-* **slots** — a slot is one in-flight sequence; the set of live slots
-  is an ``active`` lanes vector. With the default **paged KV cache**
-  the engine owns one block pool ``[L, n_blocks + 1, block_size, D]``
+* **slots and the paged KV cache** — a slot is one in-flight sequence;
+  the set of live slots is an ``active`` lanes vector. The engine owns
+  one block pool ``[L, n_blocks + 1, block_size, D]``
   plus a host-side allocator (``serving/block_pool.py``) and per-slot
   block tables ``[S, max_blocks_per_seq]`` handed to the jitted
   programs as traced data — a sequence reserves
   ``ceil((prompt + max_new) / block_size)`` blocks at admission and
   frees them at eos/completion, so CAPACITY (KV bytes), not slot
-  geometry, bounds concurrency: slots can outnumber what contiguous
+  geometry, bounds concurrency: slots can outnumber what worst-case
   strips would fit, short sequences hold only the blocks they need,
   and a submit whose ``prompt + max_new`` can never fit the pool sheds
-  with :class:`OverloadedError` (``kv_block_size=0`` restores the
-  contiguous ``[L, S, T, D]`` strips — the A/B baseline). Caches are
-  jit-donated so XLA updates them in place off-CPU.
-* **content-addressed prefix caching** (``-prefix_cache``, default on;
-  paged + chunked only) — every FULL block a prefill writes is
-  registered under a hash-chained identity (``block_pool.chain_hashes``
+  with :class:`OverloadedError`. Pools are jit-donated so XLA updates
+  them in place off-CPU.
+* **content-addressed prefix caching** (``-prefix_cache``, default on)
+  — every FULL block a prefill writes is registered under a
+  hash-chained identity (``block_pool.chain_hashes``
   seeded by the pinned snapshot version); admission looks up the
   longest cached prefix of an arriving prompt, splices the matched
   blocks into the new slot's table with a refcount bump, and starts
@@ -39,8 +39,8 @@ cache (the fixed-slot precursor to vLLM's PagedAttention):
   RadixAttention). All placement still rides the block tables as traced
   data — one compiled trace per program, cache hits or not.
 * **one fused step per iteration** — every iteration runs ONE jitted
-  :func:`models.transformer.decode_step` over all S slots, live or
-  dead. Shapes never depend on the request mix, so the step compiles
+  :func:`models.transformer.decode_step_paged` over all S slots, live
+  or dead. Shapes never depend on the request mix, so the step compiles
   exactly once per engine config.
 * **tensor-parallel decode mesh** (``-decode_tp``, default 1) — with
   ``decode_tp > 1`` the engine owns a decode-SPECIFIC mesh over the
@@ -53,28 +53,22 @@ cache (the fixed-slot precursor to vLLM's PagedAttention):
   pins reshard the params onto the mesh
   (:func:`snapshot.shard_for_decode`) instead of replicating them onto
   one device — models whose params + KV pool exceed a single device's
-  memory serve by splitting over the mesh, which removes the PR 2
-  single-device gate (now just the ``tp=1`` default, not a hard
-  limit). Block tables / tokens / positions stay replicated
-  traced-as-data, so the one-trace invariant holds per mesh, and
-  outputs are token-identical to the replicated path.
+  memory serve by splitting over the mesh. Block tables / tokens /
+  positions stay replicated traced-as-data, so the one-trace invariant
+  holds per mesh, and outputs are token-identical to the replicated
+  path.
 * **chunked, budget-bounded admission** — an arriving prompt prefills
-  in fixed-size chunks (:func:`models.transformer.prefill_chunk`, K/V
-  written straight into its reserved slot), AT MOST ONE chunk per
+  in fixed-size chunks (:func:`models.transformer.prefill_chunk_paged`,
+  K/V written straight into its reserved blocks), AT MOST ONE chunk per
   iteration interleaved with the fused decode step. Inter-token latency
   for in-flight generations is therefore bounded by one budget-sized
   chunk of work regardless of the arriving prompt's length (the
   Sarathi-Serve stall-free schedule), and a long prompt's TTFT
   amortizes across iterations instead of blocking the world. The chunk
   size is the ``prefill_token_budget`` config knob; its fixed shape
-  adds exactly ONE compiled trace per engine config. Setting the
-  budget to 0 restores **monolithic admission**: arrivals batched per
-  iteration through the bucketed :func:`models.transformer.prefill` +
-  fused :func:`models.transformer.cache_insert` (one synchronous
-  whole-prompt prefill between decode iterations — cheapest for
-  uniformly short prompts, and the A/B baseline the chunked path is
-  benched against in ``tools/serving_bench.py``). Either way the first
-  token falls out of the (last chunk of the) prefill, so TTFT is one
+  adds exactly ONE compiled trace per engine config; a budget of
+  ``max_prompt`` or more prefills every prompt in one chunk. The first
+  token falls out of the last chunk of the prefill, so TTFT is one
   prefill — not one full batch drain.
 * **speculative decoding** (``-spec_k``, default 0 = off) — the engine
   emits up to ``spec_k + 1`` tokens per iteration: a host-side n-gram
@@ -100,13 +94,13 @@ cache (the fixed-slot precursor to vLLM's PagedAttention):
   sequence emits ``eos_id`` or reaches its per-request ``max_new``;
   the finished tokens resolve the caller's Future immediately and the
   slot is reusable on the next iteration.
-* **overload-graceful scheduling** (``-preempt``, default on; paged +
-  chunked only) — requests carry a tenant ``priority`` class and an
+* **overload-graceful scheduling** (``-preempt``, default on) —
+  requests carry a tenant ``priority`` class and an
   optional ``deadline_s``. The queue is a set of per-priority FIFO
   lanes under a stride (weighted-fair) scheduler with bounded
   lookahead past a block-starved head, and expired-deadline requests
   are dropped at POP time (:class:`DeadlineExceededError`) before any
-  prefill is burned on them. Paged admission turns OPTIMISTIC: a
+  prefill is burned on them. Admission is OPTIMISTIC: a
   sequence reserves its PROMPT's blocks only and grows the reservation
   block-by-block at decode time; on pool exhaustion the lowest-
   priority/youngest victim is **preempted** — its blocks decref
@@ -146,7 +140,7 @@ from ..analysis import lockwatch
 import time
 from concurrent.futures import Future
 from dataclasses import dataclass
-from typing import Deque, Dict, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -155,8 +149,7 @@ import numpy as np
 from .. import trace
 from ..dashboard import Dashboard
 from ..log import Log
-from .batcher import (DeadlineExceededError, OverloadedError, bucket_for,
-                      shape_buckets)
+from .batcher import DeadlineExceededError, OverloadedError
 from . import accounting
 from . import kv_transfer
 from .block_pool import SCRATCH_BLOCK, BlockPool, chain_hashes
@@ -175,17 +168,14 @@ class DecodeEngineConfig:
     eos_id: Optional[int] = None
     max_queue: int = 256        # admission queue depth before shedding
     max_staleness_s: float = 0.05
-    # prompt pad buckets (powers of two up to max_prompt by default):
-    # one compiled prefill/insert per bucket, step compiles ONCE regardless
-    # (monolithic admission only; chunked admission needs no buckets)
-    prompt_buckets: Optional[Tuple[int, ...]] = None
-    # per-iteration chunked-prefill token budget; None = the
-    # -prefill_token_budget flag, 0 = monolithic whole-prompt admission
+    # per-iteration chunked-prefill token budget, > 0 (None = the
+    # -prefill_token_budget flag); a budget past max_prompt means every
+    # prompt prefills in one chunk
     prefill_token_budget: Optional[int] = None
-    # paged KV cache: block size in token positions (None = the
-    # -kv_block_size flag, 0 = contiguous per-slot strips) and usable
-    # pool blocks (None = the -kv_pool_blocks flag, <= 0 = auto-size to
-    # the contiguous-equivalent capacity slots * ceil(T / block_size))
+    # paged KV cache: block size in token positions, > 0 (None = the
+    # -kv_block_size flag) and usable pool blocks (None = the
+    # -kv_pool_blocks flag, <= 0 = auto-size to every slot's worst case,
+    # slots * ceil(T / block_size))
     kv_block_size: Optional[int] = None
     kv_pool_blocks: Optional[int] = None
     # tensor-parallel decode mesh width (None = the -decode_tp flag).
@@ -193,11 +183,10 @@ class DecodeEngineConfig:
     # a decode-specific mesh over the first decode_tp devices, shards
     # attention heads / the MLP hidden dim / the head slice of the paged
     # K/V pools over a "tp" axis, and compiles every serving program
-    # once against matched in/out_shardings (needs the paged KV cache)
+    # once against matched in/out_shardings
     decode_tp: Optional[int] = None
     # content-addressed prefix caching over the paged pool (None = the
-    # -prefix_cache flag; needs paged KV AND chunked prefill, silently
-    # inert otherwise). False is the A/B baseline: same pool bytes,
+    # -prefix_cache flag). False is the A/B baseline: same pool bytes,
     # every prompt prefills from token zero.
     prefix_cache: Optional[bool] = None
     # sequence-parallel long-prompt prefill over the decode mesh (None =
@@ -205,22 +194,21 @@ class DecodeEngineConfig:
     # prefill in budget * tp token chunks with the chunk's rows sharded
     # over the decode mesh's tp axis ("ring" ppermute rotations or
     # "ulysses" all_to_all head resharding); shorter prompts keep the
-    # single-lane chunk program bit-for-bit. Paged + chunked only;
-    # incompatible with kv_quant=int8.
+    # single-lane chunk program bit-for-bit. Incompatible with
+    # kv_quant=int8.
     prefill_sp: Optional[bool] = None
     prefill_sp_backend: Optional[str] = None
     prefill_sp_threshold: Optional[int] = None
     # speculative decoding draft length (None = the -spec_k flag).
     # 0 = off (today's one-token path, bit-for-bit); > 0 drafts up to
     # spec_k tokens per live slot via n-gram prompt lookup and verifies
-    # them in one fused fixed-K step (needs the paged KV cache)
+    # them in one fused fixed-K step
     spec_k: Optional[int] = None
     # int8 per-block-scaled paged KV pools (None = the -kv_quant flag).
     # "none" is today's fp pools bit-for-bit; "int8" stores the pools
     # as int8 with per-(layer, block) fp32 scales riding every program
     # as traced data — ~4x KV capacity at equal bytes, lossy (the bench
-    # archives the argmax-match rate against the fp32 oracle). Needs
-    # the paged KV cache.
+    # archives the argmax-match rate against the fp32 oracle).
     kv_quant: Optional[str] = None
     # int8 decode param snapshot pins (None = the -decode_param_quant
     # flag): pins quantize host-side once per version (~4x smaller
@@ -228,8 +216,8 @@ class DecodeEngineConfig:
     decode_param_quant: Optional[str] = None
     # overload-graceful serving (None = the matching flags): optimistic
     # prompt-only reservation + grow-at-decode + preemption-with-
-    # recompute (paged + chunked only; False = worst-case up-front
-    # reservation, the A/B baseline), the per-request preemption
+    # recompute (False = worst-case up-front reservation, the A/B
+    # baseline), the per-request preemption
     # budget, and the bounded admission lookahead past a block-starved
     # queue head (0 = strict FIFO within a priority class)
     preempt: Optional[bool] = None
@@ -261,11 +249,6 @@ class DecodeEngineConfig:
             value = get_flag(flag or field)
         return value
 
-    def resolved_prompt_buckets(self) -> Tuple[int, ...]:
-        if self.prompt_buckets:
-            return tuple(self.prompt_buckets)
-        return shape_buckets(self.max_prompt)
-
     def resolved_prefill_budget(self) -> int:
         if self.prefill_token_budget is not None:
             return int(self.prefill_token_budget)
@@ -286,7 +269,7 @@ class DecodeEngineConfig:
             from ..config import get_flag
 
             n = int(get_flag("kv_pool_blocks"))
-        if n <= 0:                   # auto: contiguous-equivalent capacity
+        if n <= 0:                   # auto: every slot's worst case
             n = self.slots * blocks_per_seq
         return int(n)
 
@@ -663,41 +646,27 @@ class DecodeEngine:
         self.name = name
         self.config = config or DecodeEngineConfig()
         ec = self.config
-        self._prompt_buckets = ec.resolved_prompt_buckets()
-        if self._prompt_buckets[-1] < ec.max_prompt:
-            Log.fatal(f"DecodeEngine {name!r}: largest prompt bucket "
-                      f"{self._prompt_buckets[-1]} < max_prompt "
-                      f"{ec.max_prompt}")
-        # admission-group batch buckets (an admission wave is <= slots)
-        self._batch_buckets = shape_buckets(ec.slots)
         S = ec.slots
         self._cache_len = ec.max_prompt + ec.max_new
         T = self._cache_len
 
         # -- paged KV cache geometry ----------------------------------------
-        # block size 0 = contiguous [L, S, T, D] strips (the pre-paging
-        # layout, kept as the A/B baseline); > 0 = one block pool
-        # [L, n_blocks + 1, block_size, D] (physical block 0 is the
-        # scratch/sentinel block) + per-slot block tables [S, M]
+        # one block pool [L, n_blocks + 1, block_size, D] (physical
+        # block 0 is the scratch/sentinel block) + per-slot block tables
+        # [S, M]
         self._block_size = ec.resolved_kv_block_size()
-        if self._block_size < 0:
-            Log.fatal(f"DecodeEngine {name!r}: negative kv_block_size "
+        if self._block_size <= 0:
+            Log.fatal(f"DecodeEngine {name!r}: kv_block_size must be "
+                      f"> 0 (token positions a block), got "
                       f"{self._block_size}")
-        self._paged = self._block_size > 0
-        if self._paged:
-            Bs = self._block_size
-            self._blocks_per_seq = -(-T // Bs)          # M = ceil(T / Bs)
-            n_blocks = ec.resolved_kv_pool_blocks(self._blocks_per_seq)
-            self._pool: Optional[BlockPool] = BlockPool(
-                n_blocks, Bs, name=name)
-            # all-sentinel rows: every position maps to scratch until an
-            # admission installs its reservation
-            self._block_tables = np.full(
-                (S, self._blocks_per_seq), SCRATCH_BLOCK, np.int32)
-        else:
-            self._blocks_per_seq = 0
-            self._pool = None
-            self._block_tables = None
+        self._blocks_per_seq = -(-T // self._block_size)  # M = ceil(T / Bs)
+        self._pool = BlockPool(
+            ec.resolved_kv_pool_blocks(self._blocks_per_seq),
+            self._block_size, name=name)
+        # all-sentinel rows: every position maps to scratch until an
+        # admission installs its reservation
+        self._block_tables = np.full(
+            (S, self._blocks_per_seq), SCRATCH_BLOCK, np.int32)
 
         # -- quantized serving knobs ----------------------------------------
         # int8 per-(layer, block)-scaled KV pools: the pools store int8
@@ -710,11 +679,6 @@ class DecodeEngine:
             Log.fatal(f"DecodeEngine {name!r}: kv_quant must be 'none' or "
                       f"'int8', got {self._kv_quant_mode!r}")
         self._kv_quant = self._kv_quant_mode == "int8"
-        if self._kv_quant and not self._paged:
-            Log.fatal(f"DecodeEngine {name!r}: kv_quant=int8 needs the "
-                      f"paged KV cache (kv_block_size > 0) — the scales "
-                      f"are per (layer, block), and a contiguous strip "
-                      f"has no blocks to scale")
         # int8 decode param pins: the pin quantizes host-side ONCE per
         # snapshot version (snapshot.quantize_decode_params) and the
         # compiled programs fold the dequant in — pin device_put bytes
@@ -739,11 +703,6 @@ class DecodeEngine:
             from ..models.transformer import DECODE_TP_AXIS
             from ..topology import make_mesh
 
-            if not self._paged:
-                Log.fatal(f"DecodeEngine {name!r}: decode_tp={self._tp} "
-                          f"needs the paged KV cache (kv_block_size > 0) "
-                          f"— the sharded programs partition the block "
-                          f"pools over the head slice of D")
             ndev = len(jax.devices())
             if self._tp > ndev:
                 Log.fatal(f"DecodeEngine {name!r}: decode_tp {self._tp} "
@@ -775,23 +734,20 @@ class DecodeEngine:
 
         # -- jitted programs ------------------------------------------------
         # chunked admission budget: a fixed-size chunk prefilled straight
-        # into the slot cache at a traced (slot, offset, length) — the
+        # into the slot's blocks at a traced (slot, offset, length) — the
         # chunk shape is the ONLY static, so it is exactly one extra
         # compiled trace per engine config (asserted in the tests)
         self._budget = ec.resolved_prefill_budget()
-        if self._budget < 0:
-            Log.fatal(f"DecodeEngine {name!r}: negative "
-                      f"prefill_token_budget {self._budget}")
+        if self._budget <= 0:
+            Log.fatal(f"DecodeEngine {name!r}: prefill_token_budget must "
+                      f"be > 0 (tokens a prefill chunk), got "
+                      f"{self._budget}")
         # a chunk never needs more tokens than the longest admissible
         # prompt (and must fit the [.., T, ..] cache): clamp the chunk
         # shape — budgets past max_prompt just mean one-chunk admission
         self._budget = min(self._budget, ec.max_prompt)
-        # content-addressed prefix caching: paged blocks + chunked
-        # prefill only (monolithic admission writes the WHOLE prompt
-        # through the table in one fused insert — it cannot start at the
-        # first uncached token, so the cache gates itself off)
-        self._prefix = (self._paged and self._budget > 0
-                        and bool(ec._resolved("prefix_cache")))
+        # content-addressed prefix caching over the block pool
+        self._prefix = bool(ec._resolved("prefix_cache"))
         self._hash_seed = b""        # pinned-version scope for the chain
         # sequence-parallel prefill: prompts at/above the threshold chunk
         # at budget * tp tokens with the rows sharded over the decode
@@ -804,12 +760,6 @@ class DecodeEngine:
         self._sp_threshold = int(ec._resolved("prefill_sp_threshold"))
         self._chunk_sp_fn = None
         if self._sp:
-            if not (self._paged and self._budget > 0):
-                Log.fatal(f"DecodeEngine {name!r}: prefill_sp needs the "
-                          f"paged KV cache (kv_block_size > 0) AND "
-                          f"chunked prefill (prefill_token_budget > 0) "
-                          f"— the seqpar chunk scatters through block "
-                          f"tables at a traced offset")
             if self._kv_quant:
                 Log.fatal(f"DecodeEngine {name!r}: prefill_sp is "
                           f"incompatible with kv_quant=int8 — the "
@@ -833,29 +783,18 @@ class DecodeEngine:
         # the seqpar chunk's global size: one budget of rows per DEVICE
         self._sp_chunk = self._budget * self._tp if self._sp else 0
         # speculative decoding: up to spec_k prompt-lookup drafts per
-        # live slot, verified by one fused fixed-K step per iteration.
-        # Paged-only: the verify window's scatter/rollback contract is
-        # written against block tables (dead/pad writes park in scratch;
-        # the contiguous strips have no per-position sentinel for a
-        # multi-position window), so spec_k > 0 fail-fasts on contiguous
+        # live slot, verified by one fused fixed-K step per iteration
+        # (the verify window parks rejected/pad writes in the scratch
+        # block)
         self._spec = int(ec._resolved("spec_k"))
         if self._spec < 0:
             Log.fatal(f"DecodeEngine {name!r}: negative spec_k "
                       f"{self._spec}")
-        if self._spec and not self._paged:
-            Log.fatal(f"DecodeEngine {name!r}: spec_k={self._spec} needs "
-                      f"the paged KV cache (kv_block_size > 0) — the "
-                      f"verify window parks rejected/pad writes in the "
-                      f"scratch block")
         # overload-graceful serving: optimistic prompt-only reservation
-        # + grow-at-decode + preemption-with-recompute. Paged + chunked
-        # only (a contiguous strip has no blocks to release, and
-        # monolithic admission can neither grow nor restart mid-prompt)
-        # — the knob gates itself off otherwise, the prefix_cache
-        # precedent. preempt=False keeps the pre-PR worst-case
-        # prompt+max_new up-front reservation (the A/B baseline).
-        self._preempt_on = (self._paged and self._budget > 0
-                            and bool(ec._resolved("preempt")))
+        # + grow-at-decode + preemption-with-recompute. preempt=False
+        # keeps the worst-case prompt+max_new up-front reservation (the
+        # A/B baseline).
+        self._preempt_on = bool(ec._resolved("preempt"))
         self._preempt_budget = int(ec._resolved("preempt_budget"))
         if self._preempt_budget < 0:
             Log.fatal(f"DecodeEngine {name!r}: negative preempt_budget "
@@ -878,14 +817,13 @@ class DecodeEngine:
             name=name, slots=S, max_prompt=ec.max_prompt,
             max_new=ec.max_new, cache_len=T, block_size=self._block_size,
             blocks_per_seq=self._blocks_per_seq,
-            pool_blocks=self._pool.capacity if self._paged else 0,
+            pool_blocks=self._pool.capacity,
             budget=self._budget, prefix=self._prefix, tp=self._tp,
             mesh=self._decode_mesh, kv_quant=self._kv_quant_mode,
             param_quant=self._param_quant, spec_k=self._spec,
             prefill_sp=self._sp_backend if self._sp else "none",
             donate=jax.default_backend() != "cpu"))
         self._progs = progs
-        self._admit_fn = progs.admit
         self._chunk_fn = progs.chunk
         self._chunk_sp_fn = progs.chunk_sp
         self._step_fn = progs.step
@@ -895,12 +833,9 @@ class DecodeEngine:
         # a model that has them): one compiled trace each
         self._fetch_fn = progs.fetch
         self._splice_fn = progs.splice
-        if self._budget > 0 and self._chunk_fn is None:
+        if self._chunk_fn is None:
             Log.fatal(f"DecodeEngine {name!r}: the model has no prefill "
-                      f"chunk program (prefill_token_budget > 0)")
-        if self._budget == 0 and self._admit_fn is None:
-            Log.fatal(f"DecodeEngine {name!r}: the model has no monolithic "
-                      f"admission program (prefill_token_budget = 0)")
+                      f"chunk program")
 
         # -- device state (owned by the loop thread after start) -------------
         # committed placement from birth: the programs' traces are
@@ -927,12 +862,6 @@ class DecodeEngine:
         # the one admission currently prefilling in chunks (its slot is
         # reserved — excluded from the free pool — but not yet live)
         self._pf: Optional[_Request] = None
-        # monolithic admission in progress: blocks are reserved at
-        # _admit entry but slots go active only after the fused prefill
-        # returns (a cold bucket compiles for SECONDS in between) — the
-        # watchdog's leaked-reservation heuristic must not read that
-        # window as a leak
-        self._admitting = False
         # per-priority weighted-fair admission lanes (a plain FIFO when
         # every submit uses the default class)
         self._q = _PrioQueue(name, self._lookahead)
@@ -1069,9 +998,7 @@ class DecodeEngine:
         self.shed = 0
         self.tokens = 0
         # peak concurrent sequences (live slots + the mid-prefill
-        # admission): the capacity headline the paged A/B compares —
-        # at a fixed KV-bytes budget, paging should hold several times
-        # more of these than contiguous strips
+        # admission): what the pool's KV bytes, not the slot count, bound
         self.peak_live = 0
         # engine-local prefill-token count: the PREFILL_TOKENS Counter is
         # monotonic by contract (MetricsExporter rates), so stats() and
@@ -1119,7 +1046,7 @@ class DecodeEngine:
         self.t_first: Optional[float] = None
         self._occ_sum = 0.0          # mean occupancy over iterations
         self._occ_n = 0
-        # KV blocks the steps' attention had to read (paged engines):
+        # KV blocks the steps' attention had to read:
         # what the view path's gather over all slots x M is wasted on,
         # and what the paged kernel's copies scale with
         self._live_blocks_sum = 0
@@ -1158,7 +1085,7 @@ class DecodeEngine:
                xfer_info: Optional[Dict[str, int]] = None,
                tenant: Optional[str] = None) -> Future:
         """Enqueue one prompt; fast-rejects at the admission-queue cap,
-        and (paged KV) when ``prompt + max_new`` needs more blocks than
+        and when ``prompt + max_new`` needs more blocks than
         the whole pool holds — such a request could NEVER be admitted
         (``retriable=False``: no amount of retrying changes that), so
         queueing it would deadlock the admission head. ``ctx`` is the
@@ -1197,18 +1124,17 @@ class DecodeEngine:
         with self._cv:
             if self._stop.is_set():
                 raise RuntimeError(f"decode engine {self.name!r} is stopped")
-            if self._paged:
-                need = self._pool.blocks_needed(p.shape[0] + req.max_new)
-                if need > self._pool.capacity:
-                    self.shed += 1
-                    self.shed_counter.inc()
-                    self._shed_class(prio)
-                    if req.usage is not None:
-                        self.ledger.finalize(req.usage, "shed")
-                    raise OverloadedError(self.name, need,
-                                          self._pool.capacity,
-                                          what="kv block pool",
-                                          retriable=False)
+            need = self._pool.blocks_needed(p.shape[0] + req.max_new)
+            if need > self._pool.capacity:
+                self.shed += 1
+                self.shed_counter.inc()
+                self._shed_class(prio)
+                if req.usage is not None:
+                    self.ledger.finalize(req.usage, "shed")
+                raise OverloadedError(self.name, need,
+                                      self._pool.capacity,
+                                      what="kv block pool",
+                                      retriable=False)
             if len(self._q) >= self.config.max_queue:
                 self.shed += 1
                 self.shed_counter.inc()
@@ -1228,9 +1154,8 @@ class DecodeEngine:
     def supports_transfer(self) -> bool:
         """Whether this engine can be a disaggregation endpoint. The
         transfer plane moves chain-addressed FULL blocks, so it rides
-        exactly the prefix-cache gate (paged + chunked + prefix_cache):
-        without the content index there is nothing to splice INTO, and
-        without chunked prefill nothing block-granular to fetch FROM."""
+        exactly the prefix-cache gate: without the content index there
+        is nothing to splice INTO."""
         return self._prefix and self._fetch_fn is not None
 
     def submit_prefill(self, prompt: np.ndarray,
@@ -1252,9 +1177,8 @@ class DecodeEngine:
         if not self.supports_transfer:
             raise RuntimeError(
                 f"decode engine {self.name!r} cannot serve prefill-only "
-                f"admissions (needs paged KV + chunked prefill + "
-                f"prefix_cache, and a model with KV transfer programs "
-                f"— the transfer plane's gate)")
+                f"admissions (needs prefix_cache and a model with KV "
+                f"transfer programs — the transfer plane's gate)")
         self.validate(prompt, None)
         p = np.asarray(prompt, np.int32).ravel()
         # max_new=1 keeps the reservation arithmetic in-range; the
@@ -1355,13 +1279,8 @@ class DecodeEngine:
             "params_age_s": round(params_age, 4),
             "params_stale": self._manager.params_stale(
                 stale_after, age_s=params_age),
-            # a monolithic admission in flight counts as live: its
-            # requests are already popped from the queue (queue_age_s
-            # reads 0) and no slot is active yet, so without it a
-            # wedged fused prefill would be invisible to the stall check
             "live_seqs": int(self._active.sum())
-            + (1 if self._pf is not None else 0)
-            + (1 if self._admitting else 0),
+            + (1 if self._pf is not None else 0),
             "active_slots": int(self._active.sum()),
             "queue_depth": depth,
             "queue_age_s": age,
@@ -1390,17 +1309,14 @@ class DecodeEngine:
     def pool_drift(self) -> Optional[str]:
         """Paged-KV accounting sanity: allocator invariant violations,
         or live blocks held while NOTHING is alive to hold them (no
-        active slot, no admission mid-flight — chunked ``_pf`` or
-        monolithic ``_admitting``, whose cold-bucket compile can hold
-        reservations for seconds — nothing queued). Refcounted sharing
+        active slot, no admission mid-prefill, nothing queued).
+        Refcounted sharing
         is NOT a leak: ``n_live`` counts blocks with holders exactly
         once however many sequences share them, and prefix-cached
         blocks whose refcount hit zero sit in the pool's CACHED tier,
         outside ``n_live`` entirely. Sampled racily — the watchdog
         requires the verdict to persist across two polls before
         tripping."""
-        if not self._paged:
-            return None
         msg = self._pool.drift()
         if msg is not None:
             return msg
@@ -1409,8 +1325,7 @@ class DecodeEngine:
         # lost reservation
         live_blocks = self._pool.n_live - len(self._squeezed)
         if (live_blocks > 0 and not self._active.any()
-                and self._pf is None and not self._admitting
-                and not self._q):
+                and self._pf is None and not self._q):
             return (f"{live_blocks} live block(s) with zero live "
                     f"sequences (leaked reservation)")
         return None
@@ -1469,11 +1384,10 @@ class DecodeEngine:
         return self._pool.blocks_needed(
             len(req.prompt) + req.max_new - len(req.out))
 
-    def _blocks_cover(self, req: _Request, reserved: int) -> bool:
-        """Paged-KV admission gate: a request admits only when its
+    def _blocks_cover(self, req: _Request) -> bool:
+        """The admission gate: a request admits only when its
         reservation (:meth:`_reservation_blocks` — worst-case by
-        default, prompt-only under ``-preempt``, less what earlier
-        arrivals of the same wave will take — and, with prefix
+        default, prompt-only under ``-preempt`` — and, with prefix
         caching, less the cached blocks it will share instead of
         allocate) fits the reclaimable pool (free list + evictable
         cached blocks). A false verdict leaves it QUEUED — completions
@@ -1481,12 +1395,10 @@ class DecodeEngine:
         enough return; only a request larger than the entire pool could
         wait forever, and ``submit`` shed that case up front (no
         admission deadlock, tested)."""
-        if not self._paged:
-            return True
         need = self._reservation_blocks(req)
         if self._prefix:
             need -= self._prefix_usable_hits(req)
-        return need + reserved <= self._pool.n_free + self._pool.n_cached
+        return need <= self._pool.n_free + self._pool.n_cached
 
     def _drop_expired(self, dropped: List[_Request]) -> None:
         """Deadline enforcement lands at queue-POP time: the scheduler
@@ -1513,7 +1425,6 @@ class DecodeEngine:
                     f"(engine {self.name!r})"))
 
     def _loop(self) -> None:
-        chunked = self._budget > 0
         while True:
             splices: List[tuple] = []
             with self._cv:
@@ -1557,31 +1468,16 @@ class DecodeEngine:
                 # admission pops through the weighted-fair lane
                 # scheduler (expired deadlines dropped at pop,
                 # bounded lookahead past a block-starved head) onto
-                # the explicit free-slot set and, when paged, gates on
-                # the block pool covering each arrival's reservation
-                now = time.monotonic()
+                # the explicit free-slot set, gated on the block pool
+                # covering the arrival's reservation. One admission
+                # prefills at a time; the NEXT request is only picked
+                # up once the current one goes live
                 arrivals: List[_Request] = []
                 expired: List[_Request] = []
-                if chunked:
-                    # one admission prefills at a time; the NEXT request
-                    # is only picked up once the current one goes live
-                    if self._pf is None and self._free_q and self._q:
-                        req, expired = self._q.pop_admissible(
-                            now, lambda r: self._blocks_cover(r, 0))
-                        if req is not None:
-                            arrivals.append(req)
-                else:
-                    reserved = 0
-                    while len(arrivals) < len(self._free_q) and self._q:
-                        req, exp = self._q.pop_admissible(
-                            now,
-                            lambda r, res=reserved:
-                            self._blocks_cover(r, res))
-                        expired.extend(exp)
-                        if req is None:
-                            break
-                        if self._paged:
-                            reserved += self._reservation_blocks(req)
+                if self._pf is None and self._free_q and self._q:
+                    req, expired = self._q.pop_admissible(
+                        time.monotonic(), self._blocks_cover)
+                    if req is not None:
                         arrivals.append(req)
                 if not sure:
                     it_phase = trace.phase(
@@ -1620,53 +1516,42 @@ class DecodeEngine:
                     finally:
                         done.set()
                     worked = True
-                if chunked:
-                    if arrivals:
-                        self._begin_prefill(arrivals[0],
-                                            self._free_q.popleft())
-                    # zero-cost admissions (a full prefix hit goes live
-                    # without a single prefill chunk) must not consume
-                    # the iteration's one admission slot: keep admitting
-                    # until a chunk is actually pending or nothing is
-                    # admissible, so a full-hit-heavy trace admits at
-                    # slot rate instead of one request per iteration
-                    # (the per-iteration chunk budget below is what
-                    # bounds ITL, and these admissions cost no chunk)
-                    while self._pf is None and self._free_q:
-                        with self._cv:
-                            if not self._q:
-                                break
-                            req, exp = self._q.pop_admissible(
-                                time.monotonic(),
-                                lambda r: self._blocks_cover(r, 0))
-                        if exp:
-                            self._drop_expired(exp)
-                        if req is None:
+                if arrivals:
+                    self._begin_prefill(arrivals[0],
+                                        self._free_q.popleft())
+                # zero-cost admissions (a full prefix hit goes live
+                # without a single prefill chunk) must not consume
+                # the iteration's one admission slot: keep admitting
+                # until a chunk is actually pending or nothing is
+                # admissible, so a full-hit-heavy trace admits at
+                # slot rate instead of one request per iteration
+                # (the per-iteration chunk budget below is what
+                # bounds ITL, and these admissions cost no chunk)
+                while self._pf is None and self._free_q:
+                    with self._cv:
+                        if not self._q:
                             break
-                        arrivals.append(req)
-                        self._begin_prefill(req, self._free_q.popleft())
-                        if req.slot == -1:
-                            # the reservation raced a pool claimant and
-                            # the request was requeued — retry next
-                            # iteration rather than spinning here
-                            break
-                    admit_phase.__exit__(None, None, None)
-                    if self._pf is not None:
-                        # AT MOST one budget-sized chunk per iteration:
-                        # the stall an admission can add to every live
-                        # generation's next token is one chunk of work
-                        with trace.phase("engine.prefill_chunk"):
-                            self._prefill_one_chunk()
-                        worked = True
-                else:
-                    if arrivals:
-                        self._admitting = True
-                        try:
-                            self._admit(arrivals)
-                        finally:
-                            self._admitting = False
-                        worked = True
-                    admit_phase.__exit__(None, None, None)
+                        req, exp = self._q.pop_admissible(
+                            time.monotonic(), self._blocks_cover)
+                    if exp:
+                        self._drop_expired(exp)
+                    if req is None:
+                        break
+                    arrivals.append(req)
+                    self._begin_prefill(req, self._free_q.popleft())
+                    if req.slot == -1:
+                        # the reservation raced a pool claimant and
+                        # the request was requeued — retry next
+                        # iteration rather than spinning here
+                        break
+                admit_phase.__exit__(None, None, None)
+                if self._pf is not None:
+                    # AT MOST one budget-sized chunk per iteration:
+                    # the stall an admission can add to every live
+                    # generation's next token is one chunk of work
+                    with trace.phase("engine.prefill_chunk"):
+                        self._prefill_one_chunk()
+                    worked = True
                 live = int(self._active.sum()) + (self._pf is not None)
                 if live > self.peak_live:
                     self.peak_live = live
@@ -1726,14 +1611,12 @@ class DecodeEngine:
             len(self._q),
             0.0 if oldest is None else (now - oldest) * 1e3,
             self._it_prefill, self._it_decode,
-            self._pool.n_free if self._paged else -1,
-            self._pool.n_live if self._paged else -1,
-            self._pool.n_shared if self._paged else -1,
+            self._pool.n_free, self._pool.n_live, self._pool.n_shared,
             self._snap.version if self._snap is not None else -1,
             tuple(self._it_admitted), tuple(self._it_completed),
             self._it_spec_proposed if self._spec else -1,
             self._it_spec_accepted if self._spec else -1,
-            (1 if self._kv_quant else 0) if self._paged else -1,
+            1 if self._kv_quant else 0,
             # written-block occupancy PROXY (live + cached pool blocks)
             # — the real nonzero-scale count lives on the device, and
             # the recorder's cost posture forbids a per-iteration sync
@@ -1749,17 +1632,12 @@ class DecodeEngine:
             # off): chunks this iteration dispatched through the
             # sequence-parallel program
             self._it_sp_chunks if self._sp else -1,
-            # live-block tail (FIELDS append at the END; -1 = contiguous
-            # cache, or a pass that ran no step): the share of the
-            # slots x M table entries this pass's step had to read
+            # live-block tail (FIELDS append at the END; -1 = a pass
+            # that ran no step): the share of the slots x M table
+            # entries this pass's step had to read
             (self._it_live_blocks
              / (self.config.slots * self._blocks_per_seq))
             if self._it_live_blocks >= 0 else -1.0))
-
-    def _tables_arg(self) -> tuple:
-        """The block tables as the programs take them: one traced
-        ``[S, M]`` argument on a paged engine, none on strips."""
-        return (self._block_tables,) if self._paged else ()
 
     def _xfer_block_shape(self) -> tuple:
         """One block of the first pool as the transfer plane ships it:
@@ -1835,7 +1713,7 @@ class DecodeEngine:
                     self._pool.flush_cache()
 
     def _reserve_blocks(self, req: _Request, slot: int) -> None:
-        """Paged KV: build the admission's reservation
+        """Build the admission's reservation
         (:meth:`_reservation_blocks` — ``prompt + max_new`` positions
         worst-case, the prompt's positions only under optimistic
         ``-preempt`` admission) and install it in the slot's block
@@ -1852,8 +1730,6 @@ class DecodeEngine:
         there, and a write must never land in a shared block — the copy
         happens here, host-dispatched, before the table is ever handed
         to the jitted step."""
-        if not self._paged:
-            return
         total = self._reservation_blocks(req)
         matched: List[int] = []
         hashes: List[bytes] = []
@@ -1904,7 +1780,7 @@ class DecodeEngine:
 
     def _release_seq(self, req: _Request) -> None:
         """Completion (eos / max_new / eos-at-first-token): the slot
-        returns to the free set and, paged, the reservation's blocks
+        returns to the free set and the reservation's blocks
         drop this holder — at iteration granularity, so a same-
         iteration queued admission can reuse them on the very next
         loop pass (tested). ``decref``, not ``free``: a block shared
@@ -1917,7 +1793,7 @@ class DecodeEngine:
         its END — a head-first release would have pressure evict the
         chain's first block and strand every cached suffix block as
         unreachable dead weight (the vLLM eviction convention)."""
-        if self._paged and req.blocks:
+        if req.blocks:
             self._pool.decref(reversed(req.blocks))
             req.blocks = []
             self._block_tables[req.slot][:] = SCRATCH_BLOCK
@@ -2038,7 +1914,7 @@ class DecodeEngine:
         t0 = time.monotonic() if tracing else 0.0
         chunk_fn = self._chunk_sp_fn if sp else self._chunk_fn
         *pools, logits = chunk_fn(
-            self._pinned, *self._pools, *self._tables_arg(),
+            self._pinned, *self._pools, self._block_tables,
             np.int32(req.slot), toks, np.int32(off), np.int32(n))
         self._pools = tuple(pools)
         # block per chunk: letting chunk dispatches run ahead
@@ -2101,7 +1977,7 @@ class DecodeEngine:
             self._finish_prefill_only(req, chunks=req.pf_chunks)
             return
         # final chunk: the prompt's last real position's logits are the
-        # first generated token (exactly the monolithic prefill's gather)
+        # first generated token (exactly a whole-prompt prefill's gather)
         with trace.phase("engine.prefill_chunk.sync"):
             logits = np.asarray(logits)
         tok0 = int(np.argmax(logits))
@@ -2126,9 +2002,8 @@ class DecodeEngine:
         if tracing and req.ctx is not None:
             trace.record_span("queue.wait", req.ctx, req.t_enq,
                               req.t_admit, cause="admission")
-            extra = ({"blocks": len(req.blocks),
-                      "pool_free": self._pool.n_free}
-                     if self._paged else {})
+            extra = {"blocks": len(req.blocks),
+                     "pool_free": self._pool.n_free}
             if self._prefix:
                 extra["prefix_hit_blocks"] = req.n_hit
                 extra["prefill_tokens_saved"] = req.saved
@@ -2315,108 +2190,6 @@ class DecodeEngine:
             self.ledger.charge(payload.get("tenant"),
                                xfer_bytes=info["xfer_bytes"])
         return info
-
-    def _admit(self, arrivals: List[_Request]) -> None:
-        t_admit = time.monotonic()     # queue.wait ends / admission begins
-        self._maybe_refresh()
-        version = self._snap.version
-        # phase 1 — dispatch every admission without blocking: arrivals
-        # group by PROMPT bucket, each group pads to a power-of-two batch
-        # bucket and runs ONE fused prefill+insert. Placement: contiguous
-        # pads point their slot at slots[0] (the cache_insert DUS chain
-        # overwrites them); paged pads carry all-scratch block-table rows
-        # (their scatter lands in the sentinel block nothing reads)
-        by_bucket: dict = {}
-        for req in arrivals:
-            pb = bucket_for(len(req.prompt), self._prompt_buckets)
-            by_bucket.setdefault(pb, []).append(req)
-        staged = []
-        for pb, group in by_bucket.items():
-            bb = bucket_for(len(group), self._batch_buckets)
-            toks = np.zeros((bb, pb), np.int32)
-            lens = np.ones(bb, np.int32)
-            slots = np.empty(bb, np.int32)
-            bts = (np.full((bb, self._blocks_per_seq), SCRATCH_BLOCK,
-                           np.int32) if self._paged else None)
-            for i, req in enumerate(group):
-                toks[i, : len(req.prompt)] = req.prompt
-                lens[i] = len(req.prompt)
-                # popleft off the persistent free-slot deque (kept
-                # current at admit/complete; list.pop(0) here was
-                # O(slots) per admission, O(slots^2) across a wave)
-                slot = self._free_q.popleft()
-                slots[i] = slot
-                req.slot = slot
-                self._reserve_blocks(req, slot)
-                if self._spec:
-                    req.drafter = _PromptLookup()
-                    req.drafter.extend(req.prompt)
-                if self._paged:
-                    bts[i] = self._block_tables[slot]
-                self.prefill_tokens += len(req.prompt)
-                self.prefill_tok_counter.inc(len(req.prompt))
-                self._it_prefill += len(req.prompt)
-                self._it_admitted.append(req.rid)
-                if req.usage is not None:
-                    req.usage.queue_wait_ms += (
-                        t_admit - req.usage.t_wait0) * 1e3
-                    req.usage.prefill_tokens += len(req.prompt)
-                    if req.resumed:
-                        req.usage.recompute_tokens += len(req.prompt)
-            if not self._paged:
-                slots[len(group):] = slots[0]  # pads: overwritten by row 0
-            first, *pools = self._admit_fn(
-                self._pinned, *self._pools,
-                jnp.asarray(bts if self._paged else slots),
-                jnp.asarray(toks), jnp.asarray(lens))
-            self._pools = tuple(pools)
-            staged.append((group, slots, first, pb, bb))
-        # phase 2 — read the first tokens back (one sync per group, after
-        # every group's dispatch is already in the device queue)
-        for group, slots, first, pb, bb in staged:
-            first = np.asarray(first)
-            now = time.monotonic()
-            tracing = trace.enabled()
-            for i, req in enumerate(group):
-                tok0 = int(first[i])
-                slot = int(slots[i])
-                req.version = version
-                req.t_last = now
-                self.ttft_hist.record((now - req.t_enq) * 1e3)
-                self.tokens += 1
-                self.decode_tok_counter.inc()
-                self._it_decode += 1
-                if req.usage is not None:
-                    req.usage.decode_tokens += 1
-                req.out.append(tok0)
-                if req.drafter is not None:
-                    req.drafter.extend((tok0,))
-                if tracing and req.ctx is not None:
-                    # the two child spans that explain a slow TTFT: how
-                    # long the prompt queued for a free slot, then the
-                    # fused prefill+insert with its bucket choice and
-                    # the pinned snapshot it was admitted under
-                    trace.record_span("queue.wait", req.ctx, req.t_enq,
-                                      t_admit, cause="admission")
-                    extra = ({"blocks": len(req.blocks),
-                              "pool_free": self._pool.n_free}
-                             if self._paged else {})
-                    extra.update(self._mesh_attrs)
-                    trace.record_span(
-                        "decode.admit", req.ctx, t_admit, now, slot=slot,
-                        prompt_len=len(req.prompt), prompt_bucket=pb,
-                        batch_bucket=bb, snapshot_version=version, **extra)
-                if self._finished(req, tok0):
-                    # slot never goes live; the inserted K/V is dead
-                    # weight a later admission overwrites — slot and
-                    # blocks return to the free sets immediately
-                    self._release_seq(req)
-                    self._resolve(req)
-                    continue
-                self._slot_req[slot] = req
-                self._tok[slot] = tok0
-                self._pos[slot] = len(req.prompt)
-                self._active[slot] = True
 
     def _propose_drafts(self):
         """Gather this iteration's verification window: up to ``spec_k``
@@ -2614,13 +2387,12 @@ class DecodeEngine:
                                 else None)
             if not self._active.any():
                 return
-        if self._paged:
-            # blocks this step's attention reads: every live slot's
-            # positions <= pos (host state the loop already holds)
-            self._it_live_blocks = int(np.sum(
-                self._pos[self._active] // self._block_size + 1))
-            self._live_blocks_sum += self._it_live_blocks
-        # host state (tok/pos/active — and, paged, the block tables)
+        # blocks this step's attention reads: every live slot's
+        # positions <= pos (host state the loop already holds)
+        self._it_live_blocks = int(np.sum(
+            self._pos[self._active] // self._block_size + 1))
+        self._live_blocks_sum += self._it_live_blocks
+        # host state (tok/pos/active and the block tables)
         # feeds the jit as plain numpy: the same aval signature warmup()
         # uses, so the two share one trace
         if spec_toks is not None:
@@ -2633,7 +2405,7 @@ class DecodeEngine:
                 spec_toks, self._pos, self._active, n_valid)
         else:
             *pools, nxt, _ = self._step_fn(
-                self._pinned, *self._pools, *self._tables_arg(),
+                self._pinned, *self._pools, self._block_tables,
                 self._tok, self._pos, self._active)
         self._pools = tuple(pools)
         with trace.phase("engine.step.sync"):
@@ -2821,21 +2593,20 @@ class DecodeEngine:
         if self._pf is not None:      # mid-prefill admission dies too
             live.append(self._pf)
             self._pf = None
-        if self._paged:
-            # the dying requests' reservations go back too — including
-            # arrivals reserved mid-_admit but not yet slotted. The
-            # engine is stopped, but stats()/gauges must not report
-            # phantom live blocks (the pool's leak invariant must hold).
-            # decref, not free: prefix-shared blocks carry one holder
-            # per dying request, and each drops exactly its own
-            for req in live + (in_flight or []):
-                if req.blocks:
-                    self._pool.decref(req.blocks)
-                    req.blocks = []
-            if self._squeezed:       # staged chaos squeeze dies too
-                self._pool.decref(self._squeezed)
-                self._squeezed = []
-            self._block_tables[:] = SCRATCH_BLOCK
+        # the dying requests' reservations go back too — including
+        # arrivals popped but not yet slotted. The engine is stopped,
+        # but stats()/gauges must not report phantom live blocks (the
+        # pool's leak invariant must hold). decref, not free:
+        # prefix-shared blocks carry one holder per dying request, and
+        # each drops exactly its own
+        for req in live + (in_flight or []):
+            if req.blocks:
+                self._pool.decref(req.blocks)
+                req.blocks = []
+        if self._squeezed:       # staged chaos squeeze dies too
+            self._pool.decref(self._squeezed)
+            self._squeezed = []
+        self._block_tables[:] = SCRATCH_BLOCK
         self._active[:] = False
         self._slot_req = [None] * self.config.slots
         self._free_q = collections.deque(range(self.config.slots))
@@ -2862,8 +2633,6 @@ class DecodeEngine:
         leaked-reservation heuristic excludes squeezed blocks; release
         with :meth:`unsqueeze_pool` (``stop()``/the failure path
         release automatically)."""
-        if not self._paged:
-            return 0
         want = int(self._pool.capacity * float(frac))
         take = min(want, self._pool.n_free + self._pool.n_cached)
         if take <= 0:
@@ -2890,11 +2659,8 @@ class DecodeEngine:
 
     def prefill_cache_size(self) -> int:
         """Compiled-trace count of the admission path: the single
-        fixed-shape chunk program when chunked, or the (batch bucket x
-        prompt bucket) fused prefill+insert set when monolithic."""
-        if self._budget > 0:
-            return _jit_cache_size(self._chunk_fn)
-        return _jit_cache_size(self._admit_fn)
+        fixed-shape chunk program."""
+        return _jit_cache_size(self._chunk_fn)
 
     def verify_cache_size(self) -> int:
         """Compiled-trace count of the speculative verify step (1 after
@@ -2924,48 +2690,40 @@ class DecodeEngine:
                 + _jit_cache_size(self._splice_fn))
 
     def warmup(self) -> None:
-        """Compile every admission trace (the ONE chunk program when
-        chunked, else every (batch bucket, prompt bucket) fused
-        prefill+insert), the copy-on-write and transfer programs and the
-        fused step before taking traffic — deadline-sensitive
-        deployments call this BEFORE submitting so no live request ever
-        pays a compile. Pins the snapshot through the serving path
-        itself, so the warmup params copy (and placement, hence the
-        compiled traces) IS the one the first admission serves.
+        """Compile the ONE chunk program, the copy-on-write and transfer
+        programs and the fused step before taking traffic —
+        deadline-sensitive deployments call this BEFORE submitting so no
+        live request ever pays a compile. Pins the snapshot through the
+        serving path itself, so the warmup params copy (and placement,
+        hence the compiled traces) IS the one the first admission
+        serves.
 
-        A paged engine warms up against its LIVE pools, on the loop
-        thread (the only thread that may hand the donated pools to a
-        program): every table row names the scratch block and no lane
-        is active, so all writes park in scratch, which no live mask
-        reaches. No second copy of the pools ever exists (a model's
-        weights and pools may fill the chip). Contiguous strips have no
-        scratch row, so that engine warms up against scratch strips.
+        The engine warms up against its LIVE pools, on the loop thread
+        (the only thread that may hand the donated pools to a program):
+        every table row names the scratch block and no lane is active,
+        so all writes park in scratch, which no live mask reaches. No
+        second copy of the pools ever exists (a model's weights and
+        pools may fill the chip).
         """
-        if self._paged:
-            done = threading.Event()
-            info: Dict = {}
+        done = threading.Event()
+        info: Dict = {}
 
-            def warm() -> dict:
-                self._pools = self._warm(self._pools)
-                return {}
+        def warm() -> dict:
+            self._pools = self._warm(self._pools)
+            return {}
 
-            with self._cv:
-                if self._stop.is_set():
-                    return
-                self._loop_work.append((warm, done, info))
-                self._cv.notify()
-            while not done.wait(0.5):
-                if not self._thread.is_alive():
-                    raise RuntimeError(
-                        f"decode engine {self.name!r}: loop thread died "
-                        f"during warmup")
-            if "error" in info:
-                raise info["error"]
-            return
-        self._warm(tuple(
-            jax.device_put(jnp.zeros(shape, dtype), target)
-            for (shape, dtype), target in zip(self._progs.pools,
-                                              self._pool_targets)))
+        with self._cv:
+            if self._stop.is_set():
+                return
+            self._loop_work.append((warm, done, info))
+            self._cv.notify()
+        while not done.wait(0.5):
+            if not self._thread.is_alive():
+                raise RuntimeError(
+                    f"decode engine {self.name!r}: loop thread died "
+                    f"during warmup")
+        if "error" in info:
+            raise info["error"]
 
     def _warm(self, pools: tuple) -> tuple:
         """Dispatch every serving program once with the serving avals
@@ -2974,34 +2732,22 @@ class DecodeEngine:
         self._maybe_refresh()
         params = self._pinned
         S = self.config.slots
-        M = self._blocks_per_seq
         # all-scratch block tables: placement is data, so these ARE the
         # serving traces for any block assignment
-        tables = ((np.full((S, M), SCRATCH_BLOCK, np.int32),)
-                  if self._paged else ())
+        tables = np.full((S, self._blocks_per_seq), SCRATCH_BLOCK, np.int32)
         zeros = np.zeros(S, np.int32)
-        if self._budget > 0:
-            *pools, _ = self._chunk_fn(
-                params, *pools, *tables, np.int32(0),
-                np.ones(self._budget, np.int32), np.int32(0), np.int32(1))
-            if self._chunk_sp_fn is not None:
-                # the seqpar chunk program compiles here too (its
-                # budget * tp token shape is the only static), so no
-                # long prompt ever pays the trace — and the partitioner
-                # runs now, not mid-traffic
-                *pools, _ = self._chunk_sp_fn(
-                    params, *pools, *tables, np.int32(0),
-                    np.ones(self._sp_chunk, np.int32), np.int32(0),
-                    np.int32(1))
-        else:
-            for pb in self._prompt_buckets:
-                for bb in self._batch_buckets:
-                    where = (np.full((bb, M), SCRATCH_BLOCK, np.int32)
-                             if self._paged
-                             else np.arange(bb, dtype=np.int32) % S)
-                    _, *pools = self._admit_fn(
-                        params, *pools, where, np.ones((bb, pb), np.int32),
-                        np.ones(bb, np.int32))
+        *pools, _ = self._chunk_fn(
+            params, *pools, tables, np.int32(0),
+            np.ones(self._budget, np.int32), np.int32(0), np.int32(1))
+        if self._chunk_sp_fn is not None:
+            # the seqpar chunk program compiles here too (its
+            # budget * tp token shape is the only static), so no
+            # long prompt ever pays the trace — and the partitioner
+            # runs now, not mid-traffic
+            *pools, _ = self._chunk_sp_fn(
+                params, *pools, tables, np.int32(0),
+                np.ones(self._sp_chunk, np.int32), np.int32(0),
+                np.int32(1))
         if self._cow_fn is not None:
             # the CoW block copy is part of the serving path (a
             # full-prompt cache hit dispatches it at admission)
@@ -3016,10 +2762,10 @@ class DecodeEngine:
         if self._verify_fn is not None:
             # the [S, K + 1] window shape is the whole signature
             *pools, _ = self._verify_fn(
-                params, *pools, *tables,
+                params, *pools, tables,
                 np.zeros((S, self._spec + 1), np.int32), zeros,
                 np.zeros(S, bool), np.ones(S, np.int32))
-        *pools, nxt, _ = self._step_fn(params, *pools, *tables, zeros,
+        *pools, nxt, _ = self._step_fn(params, *pools, tables, zeros,
                                        zeros, np.zeros(S, bool))
         jax.block_until_ready(nxt)
         return tuple(pools)
@@ -3055,8 +2801,7 @@ class DecodeEngine:
         self.preempted = 0
         self.deadline_drops = 0
         self._argmax_match = -1.0
-        if self._paged:
-            self._evictions_base = self._pool.evictions
+        self._evictions_base = self._pool.evictions
         if self.ledger is not None:
             self.ledger.reset()
         self.t_first = None
@@ -3078,31 +2823,28 @@ class DecodeEngine:
         ttft = self.ttft_hist.percentiles((50, 99))
         itl = self.itl_hist.percentiles((50, 99))
         issued = self.completed + self.shed
-        # paged-KV pool occupancy: capacity is what bounds concurrency
-        # now, so the pool's free/live split (and the peak sequence
-        # count it allowed) belongs next to slot occupancy
-        pool = ({"kv_block_size": self._block_size,
-                 "kv_pool_blocks": self._pool.capacity,
-                 # mesh-aware capacity: the pools (scratch included)
-                 # shard over the head slice of D, so each device holds
-                 # 1/tp of the KV bytes — the number that decides
-                 # whether a model + pool fits the hardware
-                 # quant-aware: an int8 pool's per-block cost counts its
-                 # int8 K/V bytes PLUS the per-(layer, block) fp32
-                 # scales — the footprint must not flatter quantization
-                 "kv_bytes_per_device": (
-                     (self._pool.capacity + 1)
-                     * self._progs.bytes_per_block // self._tp),
-                 "kv_blocks_free": self._pool.n_free,
-                 "kv_blocks_live": self._pool.n_live,
-                 "kv_blocks_cached": self._pool.n_cached,
-                 "blocks_shared": self._pool.n_shared,
-                 "block_allocs": self._pool.allocs,
-                 "block_frees": self._pool.frees}
-                if self._paged else {"kv_block_size": 0})
-        if self._paged:
-            lookups = self.prefix_hits + self.prefix_misses
-            pool.update({
+        # KV pool occupancy: capacity is what bounds concurrency, so
+        # the pool's free/live split (and the peak sequence count it
+        # allowed) belongs next to slot occupancy
+        lookups = self.prefix_hits + self.prefix_misses
+        pool = {"kv_block_size": self._block_size,
+                "kv_pool_blocks": self._pool.capacity,
+                # mesh-aware capacity: the pools (scratch included)
+                # shard over the head slice of D, so each device holds
+                # 1/tp of the KV bytes — the number that decides
+                # whether a model + pool fits the hardware
+                # quant-aware: an int8 pool's per-block cost counts its
+                # int8 K/V bytes PLUS the per-(layer, block) fp32
+                # scales — the footprint must not flatter quantization
+                "kv_bytes_per_device": (
+                    (self._pool.capacity + 1)
+                    * self._progs.bytes_per_block // self._tp),
+                "kv_blocks_free": self._pool.n_free,
+                "kv_blocks_live": self._pool.n_live,
+                "kv_blocks_cached": self._pool.n_cached,
+                "blocks_shared": self._pool.n_shared,
+                "block_allocs": self._pool.allocs,
+                "block_frees": self._pool.frees,
                 "prefix_cache": int(self._prefix),
                 "prefix_hits": self.prefix_hits,
                 "prefix_misses": self.prefix_misses,
@@ -3111,8 +2853,7 @@ class DecodeEngine:
                 "prefill_tokens_saved": self.prefill_tokens_saved,
                 "prefix_evictions": self._pool.evictions
                 - self._evictions_base,
-                "cow_copies": self.cow_copies,
-            })
+                "cow_copies": self.cow_copies}
         if self._kv_quant:
             # quant surface, present only on kv_quant=int8 engines (an
             # off-quant engine's stats dict stays byte-for-byte — the
@@ -3242,10 +2983,10 @@ class DecodeEngine:
             "itl_p99_ms": itl[99],
             "slot_occupancy": (self._occ_sum / self._occ_n
                                if self._occ_n else 0.0),
-            **({"kv_live_block_share": (
+            "kv_live_block_share": (
                 self._live_blocks_sum
                 / (self._occ_n * self.config.slots * self._blocks_per_seq)
-                if self._occ_n else 0.0)} if self._paged else {}),
+                if self._occ_n else 0.0),
             "active_slots": int(self._active.sum()),
             "queue_depth": self.queue_depth(),
             "snapshot_publishes": self._manager.publishes,
@@ -3264,9 +3005,8 @@ class DecodeEngine:
             self._stop.set()
             self._cv.notify_all()
         self._thread.join(timeout=60)
-        if self._paged:
-            # a staged chaos squeeze must not outlive the engine (the
-            # pool's books would report phantom live blocks forever)
-            self.unsqueeze_pool()
+        # a staged chaos squeeze must not outlive the engine (the
+        # pool's books would report phantom live blocks forever)
+        self.unsqueeze_pool()
         if self.watchdog is not None:
             self.watchdog.stop()

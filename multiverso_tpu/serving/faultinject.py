@@ -4,10 +4,9 @@ A fault-tolerance claim that was never exercised is a comment, not a
 property. This module is the exercise plane: a :class:`FaultPlan` is a
 parsed, *seeded* schedule of named failure points that the replica and
 its wire publisher consult at well-defined places — the same plan drives
-the chaos unit tests, the 3-process acceptance test, and the
-``serving_bench`` ``lm_fleet_chaos`` A/B, so "recovery works" is a
-number (``requests_lost == 0``, ``recovery_time_s``) the perf gate
-watches, not a belief.
+the chaos unit tests and the 3-process acceptance test, so "recovery
+works" is a number (``requests_lost == 0``, ``recovery_time_s``) the
+tests assert, not a belief.
 
 Named failure points (the ``-chaos`` spec grammar; directives are
 comma-separated, all optional)::

@@ -31,10 +31,10 @@ Record schema (:data:`FIELDS`, positional):
 ``queue_age_ms``        age of the OLDEST queued request (0 if empty)
 ``prefill_toks``        prompt tokens prefilled THIS pass
 ``decode_toks``         tokens emitted THIS pass (first tokens included)
-``pool_free``           paged-KV pool free blocks (-1 when contiguous)
-``pool_live``           paged-KV pool live blocks (-1 when contiguous)
+``pool_free``           KV pool free blocks
+``pool_live``           KV pool live blocks
 ``pool_shared``         prefix-cache shared blocks — live blocks held by
-                        >= 2 sequences (-1 when contiguous)
+                        >= 2 sequences
 ``version``             pinned snapshot version (-1 before the first pin)
 ``admitted``            request ids admitted this pass (tuple, usually empty)
 ``completed``           request ids completed this pass (tuple)
@@ -44,8 +44,8 @@ Record schema (:data:`FIELDS`, positional):
                         ``spec_k=0``); accepted/proposed per time bucket
                         is the acceptance-rate strip
                         ``tools/engine_timeline.py`` renders
-``kv_quant``            1 when the paged pools are int8-quantized, 0 for
-                        fp paged pools, -1 for contiguous caches
+``kv_quant``            1 when the KV pools are int8-quantized, 0 for
+                        fp pools
 ``quant_scale_blocks``  pool blocks carrying a nonzero quant scale (a
                         written-block occupancy proxy; -1 when
                         ``kv_quant`` != 1)
@@ -59,8 +59,7 @@ Record schema (:data:`FIELDS`, positional):
                         ``-prefill_sp`` is off)
 ``kv_live_block_share`` KV blocks this pass's step had to read (live
                         slots' ``ceil((pos + 1) / Bs)``) over ``slots x
-                        M`` (-1 when the cache is contiguous or the pass
-                        ran no step)
+                        M`` (-1 when the pass ran no step)
 ======================  =====================================================
 
 Timestamps are monotonic; the recorder captures a wall/mono anchor at

@@ -19,19 +19,18 @@ are traced data of fixed shape, so each program compiles once):
 ===========  =====================================================  =========================
 program      arguments                                              returns
 ===========  =====================================================  =========================
-``step``     ``params, *pools, [tables,] tok, pos, active``         ``*pools, next_tok, _``
-``chunk``    ``params, *pools, [tables,] slot, toks, off, n``       ``*pools, last_logits``
+``step``     ``params, *pools, tables, tok, pos, active``           ``*pools, next_tok, _``
+``chunk``    ``params, *pools, tables, slot, toks, off, n``         ``*pools, last_logits``
 ``chunk_sp``  as ``chunk`` (sequence-parallel, ``budget * tp`` toks)  as ``chunk``
-``admit``    ``params, *pools, tables | slots, toks, lengths``      ``first_tok, *pools``
 ``verify``   ``params, *pools, tables, toks, pos, active, n_valid`` ``*pools, next_tok``
 ``cow``      ``*pools, src, dst``                                   ``*pools``
 ``fetch``    ``*pools, block``                                      one block's slice a pool
 ``splice``   ``*pools, block, *slices``                             ``*pools``
 ===========  =====================================================  =========================
 
-(``[tables,]``: paged engines only.) A model leaves a program it lacks
-``None`` and REFUSES the engine feature that needs it at construction
-(:func:`refuse`), by name: a feature is never run wrong.
+A model leaves a program it lacks ``None`` and REFUSES the engine
+feature that needs it at construction (:func:`refuse`), by name: a
+feature is never run wrong.
 """
 
 from __future__ import annotations
@@ -52,10 +51,10 @@ class EngineSpec:
     max_prompt: int
     max_new: int
     cache_len: int            # T = max_prompt + max_new
-    block_size: int           # 0 = contiguous per-slot strips
-    blocks_per_seq: int       # M = ceil(T / block_size); 0 unpaged
+    block_size: int           # token positions a block
+    blocks_per_seq: int       # M = ceil(T / block_size)
     pool_blocks: int          # usable blocks N (the pool holds N + 1)
-    budget: int               # prefill chunk tokens; 0 = monolithic
+    budget: int               # prefill chunk tokens
     prefix: bool              # prefix cache on: needs cow (+ fetch/splice)
     tp: int                   # decode mesh width
     mesh: Any                 # the decode mesh (tp > 1) or None
@@ -74,7 +73,6 @@ class ServingPrograms:
     bytes_per_block: int                     # device bytes a block, all pools
     step: Callable
     chunk: Optional[Callable] = None
-    admit: Optional[Callable] = None
     chunk_sp: Optional[Callable] = None
     verify: Optional[Callable] = None
     cow: Optional[Callable] = None
@@ -99,9 +97,7 @@ class ServingPrograms:
 def refuse(who: str, spec: EngineSpec, **lacking: str) -> None:
     """Fail construction when ``spec`` asks for a feature named in
     ``lacking`` (feature -> why the model lacks it)."""
-    asked = {"contiguous": spec.block_size == 0,
-             "monolithic": spec.budget == 0,
-             "kv_quant": spec.kv_quant != "none",
+    asked = {"kv_quant": spec.kv_quant != "none",
              "param_quant": spec.param_quant != "none",
              "decode_tp": spec.tp > 1,
              "spec_k": spec.spec_k > 0,

@@ -128,7 +128,6 @@ class InferenceServer:
                          max_prompt: int = 64, max_new: int = 32,
                          eos_id: Optional[int] = None, max_queue: int = 256,
                          max_staleness_s: float = 0.05,
-                         prompt_buckets: Optional[tuple] = None,
                          prefill_token_budget: Optional[int] = None,
                          kv_block_size: Optional[int] = None,
                          kv_pool_blocks: Optional[int] = None,
@@ -159,10 +158,10 @@ class InferenceServer:
         "max_new": n}`` for a per-request generation cap.
         ``prefill_token_budget`` bounds the prefill work any single
         iteration interleaves with decode (chunked admission; None =
-        the ``-prefill_token_budget`` flag, 0 = monolithic).
+        the ``-prefill_token_budget`` flag; must be > 0).
         ``kv_block_size``/``kv_pool_blocks`` size the paged KV cache
         (None = the ``-kv_block_size``/``-kv_pool_blocks`` flags;
-        block size 0 = contiguous per-slot strips) — with paging, pool
+        the block size must be > 0): pool
         capacity rather than slot geometry bounds concurrency, and a
         submit whose ``prompt + max_new`` can never fit the pool sheds
         with :class:`OverloadedError` (docs/SERVING.md "Paged KV
@@ -178,7 +177,7 @@ class InferenceServer:
         pool: prompts sharing a prefix prefill it once and splice the
         cached blocks refcounted/copy-on-write (docs/SERVING.md
         "Prefix caching"). ``prefill_sp`` (None = the ``-prefill_sp``
-        flag, default off; paged + chunked, sharded or single-device)
+        flag, default off; sharded or single-device)
         turns on sequence-parallel long-prompt prefill: prompts of at
         least ``prefill_sp_threshold`` tokens prefill in
         ``prefill_token_budget * decode_tp`` token chunks whose rows
@@ -193,8 +192,7 @@ class InferenceServer:
         ``spec_k`` n-gram prompt-lookup drafts per live slot, verified
         by one fused fixed-K step per iteration — up to ``spec_k + 1``
         tokens per iteration, outputs token-identical to plain greedy
-        decode (docs/SERVING.md "Speculative decoding"; needs the
-        paged KV cache). ``kv_quant`` (None = the ``-kv_quant`` flag,
+        decode (docs/SERVING.md "Speculative decoding"). ``kv_quant`` (None = the ``-kv_quant`` flag,
         default "none") stores the paged K/V pools as int8 with
         per-(layer, block) fp32 scales — ~4x the KV capacity at equal
         pool bytes, lossy (the bench archives the argmax-match rate);
@@ -204,7 +202,7 @@ class InferenceServer:
         snapshots and folds the dequant into the compiled programs —
         ~4x smaller pin copies (docs/SERVING.md "Quantized KV &
         params"). ``preempt`` (None = the ``-preempt`` flag,
-        default on; paged + chunked only) switches paged admission to
+        default on) switches admission to
         OPTIMISTIC prompt-only reservation with grow-at-decode and
         preemption-with-recompute under pool pressure —
         ``preempt_budget`` bounds how often one request may be
@@ -232,7 +230,7 @@ class InferenceServer:
         cfg = DecodeEngineConfig(
             slots=slots, max_prompt=max_prompt, max_new=max_new,
             eos_id=eos_id, max_queue=max_queue,
-            max_staleness_s=max_staleness_s, prompt_buckets=prompt_buckets,
+            max_staleness_s=max_staleness_s,
             prefill_token_budget=prefill_token_budget,
             kv_block_size=kv_block_size, kv_pool_blocks=kv_pool_blocks,
             decode_tp=decode_tp, prefix_cache=prefix_cache,

@@ -15,8 +15,6 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import inspect
-import re
 import threading
 
 import pytest
@@ -26,44 +24,6 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: long benches excluded from the tier-1 run (-m 'not slow')")
-
-
-# serving_bench INVOCATION (import or attribute use), not a mere
-# docstring mention — fast oracle tests legitimately cite "the
-# serving_bench A/B" in prose
-_BENCH_INVOKE = re.compile(
-    r"serving_bench\s+import|import\s+tools\.serving_bench"
-    r"|serving_bench\.\w")
-
-
-def _needs_slow_marker(name: str, src: str) -> bool:
-    """Perf A/B tests must carry ``@pytest.mark.slow``: PR 7 found one
-    that had silently LOST its marker and was re-absorbed into tier-1.
-    The shape of a perf A/B here is stable — the name says ``_ab_`` or
-    the body drives ``tools/serving_bench`` — so the collection hook
-    below enforces it structurally instead of relying on review."""
-    return "_ab_" in name or bool(_BENCH_INVOKE.search(src))
-
-
-def pytest_collection_modifyitems(config, items):
-    bad = []
-    for item in items:
-        fn = getattr(item, "function", None)
-        if fn is None:
-            continue
-        if any(m.name == "slow" for m in item.iter_markers()):
-            continue
-        try:
-            src = inspect.getsource(fn)
-        except (OSError, TypeError):
-            src = ""
-        if _needs_slow_marker(item.name, src):
-            bad.append(item.nodeid)
-    if bad:
-        raise pytest.UsageError(
-            "perf A/B test(s) missing the @slow marker — tier-1 must "
-            "never re-absorb a bench (add @pytest.mark.slow): "
-            + ", ".join(bad))
 
 
 @pytest.fixture(autouse=True)

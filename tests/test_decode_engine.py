@@ -6,8 +6,8 @@ The acceptance contract of the decode-engine PR (docs/SERVING.md,
 * **oracle exactness** — for a randomized admission trace (mixed prompt
   lengths, per-request max_new, arrivals in waves), every request's
   engine output equals a per-request ``greedy_decode`` run: slot reuse,
-  active-lane masking, and bucketed admission are invisible in the
-  tokens;
+  active-lane masking, chunked admission and the block layout are
+  invisible in the tokens;
 * **one compiled step** — the fused step's jit cache holds exactly ONE
   trace after warmup, no matter how the request mix churns (the engine's
   whole point: shapes never depend on scheduling state);
@@ -266,17 +266,52 @@ def test_engine_validates_payloads(mv_session):
         srv.submit("lm", {"max_new": 2})                # no prompt key
 
 
-@pytest.mark.parametrize("kv_bs", [4, 0])
+@pytest.mark.parametrize("model", ["transformer", "longcat"])
+@pytest.mark.parametrize("knob,value", [("kv_block_size", 0),
+                                        ("kv_block_size", -4),
+                                        ("prefill_token_budget", 0)])
+def test_zero_block_size_and_budget_refused(mv_session, model, knob, value):
+    """The cache is a block pool and admission is chunked prefill: a
+    block size or a budget that is not positive is out-of-range input,
+    refused at construction by the knob's name before any model is
+    asked for programs (so for every model alike)."""
+    from multiverso_tpu.log import FatalError
+    from multiverso_tpu.serving import InferenceServer
+
+    if model == "longcat":
+        from multiverso_tpu.models import from_config
+        from test_longcat import TOY
+
+        lm = from_config(TOY, 7)
+    else:
+        from multiverso_tpu.models.transformer import TransformerLM
+
+        lm = TransformerLM(_small_cfg())
+    srv = InferenceServer("t")
+    kwargs = dict(slots=2, max_prompt=8, max_new=4, kv_block_size=4,
+                  prefill_token_budget=4)
+    kwargs[knob] = value
+    with pytest.raises(FatalError, match=f"{knob} must be > 0"):
+        srv.register_decoder("lm", lm, **kwargs)
+    # int8 KV scales are per (layer, block): the same refusal, not one
+    # of kv_quant's own
+    if knob == "kv_block_size" and model == "transformer":
+        with pytest.raises(FatalError, match="kv_block_size must be > 0"):
+            srv.register_decoder("q", lm, kv_quant="int8", **kwargs)
+
+
+@pytest.mark.parametrize("kv_bs", [4, 2, 16])
 def test_chunked_admission_matches_oracle_across_boundaries(mv_session,
                                                             kv_bs):
     """Chunked-prefill oracle: randomized prompts whose lengths straddle
     every chunk boundary (B-1, B, B+1, 2B, 2B+1, max_prompt) produce
     output tokens identical to the whole-prompt ``greedy_decode`` oracle
-    — the admission schedule is invisible in the results — with exactly
-    ONE compiled chunk trace and ONE fused-step trace. Runs against the
-    paged KV layout (block size 4: chunk boundaries and BLOCK boundaries
-    interleave, every scatter/gather path crosses both) and the
-    contiguous baseline (kv_block_size=0)."""
+    — neither the admission schedule nor the block layout is visible in
+    the results — with exactly ONE compiled chunk trace and ONE
+    fused-step trace. Block size 4: chunk boundaries and BLOCK
+    boundaries interleave, every scatter/gather path crosses both; 2:
+    blocks smaller than a chunk; 16: a block that does not divide
+    ``T`` = 19, so the gathered view (32 rows) is sliced to ``T``."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
@@ -310,6 +345,8 @@ def test_chunked_admission_matches_oracle_across_boundaries(mv_session,
         "chunk program retraced (slot/offset/length must all be traced)"
     stats = engine.stats()
     assert stats["prefill_token_budget"] == B
+    assert stats["kv_block_size"] == kv_bs
+    assert stats["kv_blocks_live"] == 0
     assert stats["prefill_tokens"] == sum(len(p) for p, _ in reqs)
     assert stats["tokens"] == sum(n for _, n in reqs)
 
@@ -343,11 +380,12 @@ def test_chunk_pad_tail_past_cache_end_is_dropped(mv_session):
                     "prompt K/V")
 
 
-def test_chunked_vs_monolithic_identical_outputs(mv_session):
-    """Fast A/B smoke (the tier-1 face of the slow serving_bench A/B):
-    the SAME request set through a chunked engine and a monolithic
-    (budget=0) engine on one model returns identical tokens, and each
-    side's admission-trace accounting holds."""
+def test_chunk_budget_is_invisible_in_the_tokens(mv_session):
+    """The SAME request set under budgets 3 and 8 on one model returns
+    identical tokens: 8 = ``max_prompt``, so one chunk holds the whole
+    prompt (whole-prompt admission is that value of the budget, not
+    another path), 3 splits a prompt into up to three. One chunk trace
+    and one step trace each."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
@@ -356,9 +394,8 @@ def test_chunked_vs_monolithic_identical_outputs(mv_session):
     srv = InferenceServer("t")
     engines = {
         b: srv.register_decoder(f"lm{b}", lm, slots=2, max_prompt=8,
-                                max_new=6, prompt_buckets=(8,),
-                                prefill_token_budget=b)
-        for b in (3, 0)
+                                max_new=6, prefill_token_budget=b)
+        for b in (3, 8)
     }
     for e in engines.values():
         e.warmup()
@@ -370,16 +407,18 @@ def test_chunked_vs_monolithic_identical_outputs(mv_session):
     for b in engines:
         futs = [srv.submit(f"lm{b}", p) for p in prompts]
         outs[b] = [f.result(timeout=120)["result"] for f in futs]
-    for chunked, mono in zip(outs[3], outs[0]):
-        np.testing.assert_array_equal(chunked, mono)
-    assert engines[3].prefill_cache_size() == 1
-    assert engines[3].step_cache_size() == 1
-    # one budget=3 chunk program serves 1..8-token prompts: 1-3 chunks
-    assert engines[3].stats()["prefill_tokens"] == sum(map(len, prompts))
-    assert engines[0].stats()["prefill_token_budget"] == 0
+    for chunked, whole in zip(outs[3], outs[8]):
+        np.testing.assert_array_equal(chunked, whole)
+    for b, e in engines.items():
+        assert e.prefill_cache_size() == 1
+        assert e.step_cache_size() == 1
+        # one chunk program serves 1..8-token prompts: 1-3 chunks of 3,
+        # one of 8
+        assert e.stats()["prefill_tokens"] == sum(map(len, prompts))
+        assert e.stats()["prefill_token_budget"] == b
 
 
-@pytest.mark.parametrize("budget", [3, 0])
+@pytest.mark.parametrize("budget", [3, 8])
 def test_eos_at_first_token_slot_never_goes_live(mv_session, budget):
     """A prompt whose FIRST generated token is eos resolves straight out
     of admission: the reserved slot never goes live, and the dead K/V it
@@ -544,44 +583,6 @@ def test_paged_engine_failure_path_returns_blocks(mv_session):
     engine._pool.check()
 
 
-def test_paged_matches_contiguous_outputs(mv_session):
-    """The paged layout is invisible in the tokens: the SAME request set
-    through a paged engine and a contiguous engine on one model returns
-    identical outputs (gathered views are sliced to the contiguous
-    operand shape, so even the reduction order matches), each with ONE
-    compiled chunk trace and ONE fused-step trace."""
-    from multiverso_tpu.models.transformer import TransformerLM
-    from multiverso_tpu.serving import InferenceServer
-
-    cfg = _small_cfg()
-    lm = TransformerLM(cfg)
-    srv = InferenceServer("t")
-    engines = {
-        kv: srv.register_decoder(f"lm{kv}", lm, slots=3, max_prompt=8,
-                                 max_new=6, kv_block_size=kv)
-        for kv in (4, 0)
-    }
-    for e in engines.values():
-        e.warmup()
-    rng = np.random.default_rng(12)
-    prompts = [rng.integers(1, cfg.vocab_size,
-                            int(rng.integers(1, 9))).astype(np.int32)
-               for _ in range(10)]
-    outs = {}
-    for kv in engines:
-        futs = [srv.submit(f"lm{kv}", p) for p in prompts]
-        outs[kv] = [f.result(timeout=120)["result"] for f in futs]
-    for paged, contig in zip(outs[4], outs[0]):
-        np.testing.assert_array_equal(paged, contig)
-    for e in engines.values():
-        assert e.step_cache_size() == 1
-        assert e.prefill_cache_size() == 1
-    paged_stats = engines[4].stats()
-    assert paged_stats["kv_block_size"] == 4
-    assert paged_stats["kv_blocks_live"] == 0
-    assert engines[0].stats()["kv_block_size"] == 0
-
-
 # -- prefix caching: content-addressed, refcounted, copy-on-write blocks -----
 
 def test_prefix_cache_shared_prefix_bit_exact_vs_cache_off(mv_session):
@@ -635,6 +636,10 @@ def test_prefix_cache_shared_prefix_bit_exact_vs_cache_off(mv_session):
     # the cached side did strictly less prefill work for the same tokens
     assert on["prefill_tokens"] < off["prefill_tokens"]
     assert on["tokens"] == off["tokens"]
+    # ... and took strictly fewer blocks off the free list for the same
+    # sequences (a shared block gains a holder, not an allocation): the
+    # capacity a shared prefix buys at equal pool bytes
+    assert on["block_allocs"] < off["block_allocs"]
     # one-trace-under-cache-hits: hits/misses/CoW never add a compile
     for e in engines.values():
         assert e.step_cache_size() == 1
@@ -884,7 +889,7 @@ def test_kv_live_block_share_counts_the_steps_live_blocks(mv_session):
     other: a request of prompt P and n tokens takes its first token from
     the prefill and n - 1 steps at positions P .. P + n - 2. The flight
     recorder carries the same share an iteration (-1 where no step
-    ran); a contiguous engine has neither."""
+    ran)."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
@@ -912,12 +917,3 @@ def test_kv_live_block_share_counts_the_steps_live_blocks(mv_session):
               if r.get("kv_live_block_share", -1) >= 0]
     assert shares[-len(want):] == pytest.approx(
         [b / (slots * M) for b in want])
-
-    flat = srv.register_decoder("flat", lm, slots=2, max_prompt=8, max_new=6,
-                                kv_block_size=0, prefill_token_budget=0)
-    flat.warmup()
-    srv.submit("flat", {"prompt": np.asarray([3, 4], np.int32),
-                        "max_new": 3}).result(timeout=120)
-    assert "kv_live_block_share" not in flat.stats()
-    assert all(r["kv_live_block_share"] == -1
-               for r in flat.recorder.records())

@@ -699,7 +699,7 @@ def test_replay_determinism_real_engines_kill_mid_generation(mv_session):
         engine = DecodeEngine(f"flt{r}", TransformerLM(cfg),
                               DecodeEngineConfig(
                                   slots=2, max_prompt=8, max_new=10,
-                                  prompt_buckets=(8,), watchdog=False))
+                                  watchdog=False))
         engine.warmup()
         engines.append(engine)
     rng = np.random.default_rng(5)
@@ -711,9 +711,14 @@ def test_replay_determinism_real_engines_kill_mid_generation(mv_session):
         for label, chaos in (("clean", ""), ("chaos",
                                              "kill_at_request=2")):
             kv = _KV()
+            # a DEAD verdict after 50 silent heartbeat periods, not
+            # the default 2: this test counts deaths, and on a shared
+            # CPU a heartbeat 120 ms late is a starved thread (a
+            # survivor flapped and the count read 2)
             router = FleetRouter(4, kv, label=f"replay_{label}",
                                  fleet_config=FleetConfig(
-                                     heartbeat_ms=60, deadline_s=120.0))
+                                     heartbeat_ms=60, dead_after_s=3.0,
+                                     deadline_s=120.0))
             replicas = [ReplicaServer(r + 1, 4, kv, engines[r],
                                       label=f"replay_{label}",
                                       heartbeat_ms=60)
@@ -796,7 +801,6 @@ _REPLICA_WORKER = textwrap.dedent("""
                             label="fleet",
                             engine_kw=dict(slots=2, max_prompt=8,
                                            max_new=10,
-                                           prompt_buckets=(8,),
                                            watchdog=False))
     print(f"REPLICA{rank}_UP", flush=True)
     FileKV().blocking_key_value_get("phase/done", 300_000)

@@ -222,9 +222,9 @@ def test_sp_chunks_column_and_pre_seqpar_tuple_tolerance():
 
 
 def test_kv_live_block_share_column_and_older_tuple_tolerance():
-    """The live-block share rides the END of FIELDS: a paged engine
+    """The live-block share rides the END of FIELDS: the engine
     records the share of its ``slots x M`` table entries the pass's step
-    had to read, -1 where no step ran or the cache is contiguous, and a
+    had to read, -1 where no step ran, and a
     23-field tuple from before the column still reads cleanly."""
     assert FIELDS[-1] == "kv_live_block_share"
     fr = FlightRecorder(capacity=8, name="eng")

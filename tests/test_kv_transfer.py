@@ -369,7 +369,7 @@ class _KV:
 
 
 def _mk_disagg_fleet(label, lm, roles=("prefill", "decode"), hb_ms=60,
-                     chaos=None, **engine_kw):
+                     chaos=None, dead_after_s=None, **engine_kw):
     from multiverso_tpu.serving import (FleetConfig, FleetRouter,
                                         ReplicaServer)
     from multiverso_tpu.serving.decode_engine import (DecodeEngine,
@@ -387,6 +387,7 @@ def _mk_disagg_fleet(label, lm, roles=("prefill", "decode"), hb_ms=60,
     size = len(roles) + 1
     router = FleetRouter(size, kv, label=label, name=label,
                          fleet_config=FleetConfig(heartbeat_ms=hb_ms,
+                                                  dead_after_s=dead_after_s,
                                                   deadline_s=120.0))
     replicas = [ReplicaServer(r + 1, size, kv, engines[r], label=label,
                               heartbeat_ms=hb_ms, role=role)
@@ -522,8 +523,15 @@ def test_fleet_prefill_kill_falls_back_to_unified(mv_session):
 
     cfg = _small_cfg()
     lm = TransformerLM(cfg)
+    # a DEAD verdict after 50 silent heartbeat periods, not the default
+    # 2: this test counts deaths, and on a CPU it shares with five other
+    # workers a heartbeat 120 ms late is a starved thread, not a death
+    # (the survivor flapped DEAD -> PROBING -> UP and read 5 deaths)
+    hb_ms = 60
+    dead_after_s = 50 * hb_ms / 1000.0
     kv, router, replicas, engines = _mk_disagg_fleet(
-        "pfkill", lm, chaos="kill_at_request=2")
+        "pfkill", lm, hb_ms=hb_ms, chaos="kill_at_request=2",
+        dead_after_s=dead_after_s)
     params, _ = lm.snapshot_params()
     rng = np.random.default_rng(23)
     prompts = [rng.integers(1, cfg.vocab_size, 8).astype(np.int32)
@@ -538,9 +546,9 @@ def test_fleet_prefill_kill_falls_back_to_unified(mv_session):
         assert st["requests_lost"] == 0
         assert st["output_mismatches"] == 0
         assert st["deaths"] == 1
-        # the survivor may read PROBING transiently under CPU
-        # contention (a late heartbeat, not a death) — poll briefly
-        deadline = time.monotonic() + 10
+        # the rows follow the router's own clock: allow the survivor
+        # a few verdict periods to read UP, not a fixed wall time
+        deadline = time.monotonic() + 10 * dead_after_s
         while router.replica_rows()[1]["state"] != "UP":
             assert time.monotonic() < deadline, router.replica_rows()
             time.sleep(0.05)

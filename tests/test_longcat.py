@@ -270,8 +270,6 @@ def test_route_topk_gates_are_scaled_and_not_renormalised():
     ("spec_k", dict(spec_k=2)),
     ("decode_tp", dict(decode_tp=2)),
     ("prefill_sp", dict(prefill_sp=True)),
-    ("contiguous", dict(kv_block_size=0)),
-    ("monolithic", dict(prefill_token_budget=0)),
 ])
 def test_unsupported_features_refused_at_construction(mv_session, feature,
                                                       kwargs):

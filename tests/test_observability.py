@@ -525,8 +525,8 @@ def test_tail_sampled_decode_keeps_only_sampled_trees(mv_session):
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
                             n_layers=2, d_ff=64, max_seq=48)
     srv = InferenceServer("t")
-    srv.register_decoder("lm", TransformerLM(cfg), slots=2, max_prompt=8,
-                         max_new=4)
+    engine = srv.register_decoder("lm", TransformerLM(cfg), slots=2,
+                                  max_prompt=8, max_new=4)
     try:
         trace.enable(4096, tail=trace.TailConfig(slo_ms=1e9, head_n=0))
         for _ in range(2):
@@ -558,6 +558,13 @@ def test_tail_sampled_decode_keeps_only_sampled_trees(mv_session):
         assert root.attrs["tail_keep"] == "head"
         assert all(s.parent_id == root.span_id for s in tree
                    if s is not root)
+        # tracing adds no compiled trace, and a clean traced run trips
+        # no watchdog while the flight recorder runs throughout
+        stats = engine.stats()
+        assert stats["step_traces"] == stats["prefill_traces"] == 1
+        assert stats["decode_step_retraces"] == 0
+        assert stats["watchdog_trips"] == 0
+        assert stats["flight_records"] > 0
     finally:
         trace.disable()
         trace.collector().clear()

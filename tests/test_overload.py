@@ -144,6 +144,10 @@ def test_livelock_two_oversized_requests_terminate(mv_session):
             _oracle(cfg, params, p, 16))
     stats = engine.stats()
     assert stats["preemptions"] > 0
+    # the capacity optimistic admission buys: two worst cases (6 + 6
+    # blocks) never fit the 8-block pool together, two prompt
+    # reservations (2 + 2) do, so both sequences were live at once
+    assert stats["peak_live_seqs"] == 2
     # churn bound: each preemption burns budget, and a spent budget
     # means pessimistic re-admission (no further churn possible)
     assert stats["preemptions"] <= 2 * (3 + 1)

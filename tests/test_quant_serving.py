@@ -304,19 +304,6 @@ def test_kv_quant_off_stats_surface_unchanged(mv_session):
         assert key not in st
 
 
-def test_kv_quant_rejects_contiguous_cache(mv_session):
-    from multiverso_tpu.log import FatalError
-    from multiverso_tpu.models.transformer import TransformerLM
-    from multiverso_tpu.serving import InferenceServer
-
-    lm = TransformerLM(_small_cfg())
-    srv = InferenceServer("t")
-    with pytest.raises(FatalError):
-        srv.register_decoder("bad", lm, slots=2, max_prompt=16,
-                             max_new=4, kv_block_size=0,
-                             kv_quant="int8", watchdog=False)
-
-
 def test_param_quant_pin_memoized_and_serving(mv_session):
     """decode_param_quant=int8: the engine serves with quantized pins
     (high agreement with fp on a small model), the host-side quant runs

@@ -83,7 +83,10 @@ def test_quick_sweep_sane_and_saturation_annotated():
     assert by_dp[1]["eff_norm"] == 1.0 and not by_dp[1]["saturated"]
     for r in rows:
         assert np.isfinite(r["pairs_per_sec"]) and r["pairs_per_sec"] > 0
-        assert 0.0 < r["eff_raw"] <= 1.0 + 1e-9
+        # eff_raw is a ratio of two CPU timings (over 1 whenever the
+        # dp=1 leg was the one a neighbour slowed): finite and positive
+        # is all a shared host can promise
+        assert np.isfinite(r["eff_raw"]) and r["eff_raw"] > 0
         # the annotation contract: > 1 values carry the saturated flag
         assert r["saturated"] == (r["eff_norm"] > 1.0 + 1e-9)
 

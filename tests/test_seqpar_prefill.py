@@ -158,23 +158,16 @@ def test_seqpar_ulysses_matches_single_lane(mv_session):
 
 
 def test_seqpar_validation(mv_session):
-    """Fail-fast surface: seqpar needs the paged+chunked prefill plane,
-    refuses the int8 pool encoding, checks the backend name, and the
-    ring backend's layout constraint (T divisible by tp) is caught at
-    registration, not at the first long prompt."""
+    """Fail-fast surface: seqpar refuses the int8 pool encoding, checks
+    the backend name, and the ring backend's layout constraint (T
+    divisible by tp) is caught at registration, not at the first long
+    prompt."""
     from multiverso_tpu.log import FatalError
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
     lm = TransformerLM(_sp_cfg())
     srv = InferenceServer("t")
-    with pytest.raises(FatalError):     # contiguous cache: no block plane
-        srv.register_decoder("bad_paged", lm, max_prompt=24, max_new=8,
-                             kv_block_size=0, prefill_sp=True)
-    with pytest.raises(FatalError):     # fused admission: no chunk stream
-        srv.register_decoder("bad_chunk", lm, max_prompt=24, max_new=8,
-                             kv_block_size=4, prefill_token_budget=0,
-                             prefill_sp=True)
     with pytest.raises(FatalError):     # int8 pools decode via their own
         srv.register_decoder("bad_quant", lm, max_prompt=24, max_new=8,
                              kv_block_size=4, prefill_token_budget=4,

@@ -233,26 +233,6 @@ def test_register_decoder_builds_engine_outside_registry_lock(mv_session):
         server_mod.DecodeEngine = real
 
 
-@pytest.mark.slow
-def test_decode_engine_ab_speedup(mv_session):
-    """The serving_bench mixed-length trace: continuous batching must
-    beat the static micro-batched path on useful tokens/sec (measured
-    2.4-2.8x on the CI container; asserted with slack for noisy hosts)
-    with exactly one fused-step trace."""
-    from multiverso_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM)
-    from multiverso_tpu.serving import InferenceServer
-    from tools.serving_bench import _decode_ab
-
-    srv = InferenceServer("t")
-    ab_cfg = TransformerConfig(vocab_size=256, d_model=128, n_heads=4,
-                               n_layers=2, d_ff=256, max_seq=112)
-    row = _decode_ab(srv, TransformerLM(ab_cfg), quick=True)
-    assert row["step_traces"] == 1
-    assert row["speedup_engine"] >= 1.5
-    assert row["ttft_p50_ms"] < row["ttft_p50_ms_static"]
-
-
 def test_lm_greedy_decode_matches_forward_oracle():
     """KV-cache decode == token-by-token full forward (pure function,
     ragged lengths in one right-padded batch)."""
@@ -351,163 +331,6 @@ def test_derived_cache_single_compute_under_concurrent_readers():
     assert len(calls) == 2
 
 
-@pytest.mark.slow
-def test_prefix_cache_ab_capacity_and_saved_tokens(mv_session):
-    """The serving_bench prefix-cache A/B on the shared-prefix zipf
-    trace: at EQUAL pool bytes the cached engine must hold strictly
-    more concurrent sequences, save a strictly positive prefill-token
-    count, and keep the one-trace invariant — the acceptance gate's
-    capacity-led face (latency columns stay _info per the 2-CPU
-    noise-floor rule)."""
-    from multiverso_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM)
-    from multiverso_tpu.serving import InferenceServer
-    from tools.serving_bench import _prefix_cache_ab
-
-    srv = InferenceServer("t")
-    cfg = TransformerConfig(vocab_size=256, d_model=128, n_heads=4,
-                            n_layers=2, d_ff=256, max_seq=96)
-    row = _prefix_cache_ab(srv, TransformerLM(cfg), quick=True)
-    on, off = row["cache_on"], row["cache_off"]
-    assert on["capacity_seqs"] > off["capacity_seqs"]
-    assert on["prefill_tokens_saved"] > 0
-    assert off["prefill_tokens_saved"] == 0
-    assert on["prefix_hit_rate"] > 0.0
-    assert on["prefill_tokens"] < off["prefill_tokens"]
-    assert on["step_traces"] == off["step_traces"] == 1
-    assert on["prefill_traces"] == off["prefill_traces"] == 1
-
-
-@pytest.mark.slow
-def test_overload_ab_preemption_face(mv_session):
-    """The serving_bench overload A/B: at 2x pool pressure the
-    priority+preemption leg must pack strictly more concurrent
-    sequences than FIFO+worst-case-reserve, actually preempt, keep
-    every output bit-identical to the FIFO leg's (zero
-    preempt_output_mismatches), starve nobody, drop no met-by-design
-    deadlines, and hold the one-trace invariant on both legs."""
-    from multiverso_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM)
-    from multiverso_tpu.serving import InferenceServer
-    from tools.serving_bench import _overload_ab
-
-    srv = InferenceServer("t")
-    cfg = TransformerConfig(vocab_size=256, d_model=128, n_heads=4,
-                            n_layers=2, d_ff=256, max_seq=64)
-    row = _overload_ab(srv, TransformerLM(cfg), quick=True)
-    pre, fifo = row["preempt"], row["fifo"]
-    assert pre["capacity_seqs"] > fifo["capacity_seqs"]
-    assert pre["preemptions_info"] > 0
-    assert fifo["preemptions_info"] == 0
-    assert row["preempt_output_mismatches"] == 0
-    assert pre["starved_requests"] == fifo["starved_requests"] == 0
-    assert pre["deadline_drops"] == fifo["deadline_drops"] == 0
-    assert pre["step_traces"] == fifo["step_traces"] == 1
-    assert pre["prefill_traces"] == fifo["prefill_traces"] == 1
-
-
-@pytest.mark.slow
-def test_observability_ab_black_box_clean(mv_session):
-    """The serving_bench observability A/B: tracing-off vs tail-sampled
-    tracing on the same engine — the black box (flight recorder +
-    watchdog) stays on throughout, adds no compiled trace, and a clean
-    run trips NO watchdog."""
-    from multiverso_tpu import trace
-    from multiverso_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM)
-    from multiverso_tpu.serving import InferenceServer
-    from tools.serving_bench import _observability_ab
-
-    srv = InferenceServer("t")
-    cfg = TransformerConfig(vocab_size=256, d_model=128, n_heads=4,
-                            n_layers=2, d_ff=256, max_seq=80)
-    trace.enable(65536, tail=trace.TailConfig())
-    try:
-        row, engine = _observability_ab(srv, TransformerLM(cfg),
-                                        quick=True)
-    finally:
-        trace.disable()
-        trace.collector().clear()
-    assert row["step_traces"] == 1
-    assert row["tokens_per_s_untraced_info"] > 0
-    assert row["tokens_per_s_traced_info"] > 0
-    assert row["flight_iterations_info"] > 0
-    assert row["tail_completed_info"] > 0
-    assert engine.watchdog is not None and engine.watchdog.trip_count == 0
-
-
-@pytest.mark.slow
-def test_spec_decode_ab_speedup(mv_session):
-    """The serving_bench speculative-decoding A/B on the repetitive-
-    tail trace: spec_k=4 must beat the spec_k=0 baseline on useful
-    tokens/sec (pure schedule amortization — outputs are
-    token-identical by construction), accept more than one extra token
-    per verify dispatch on this trace, and keep one step + one verify
-    trace with zero retraces on both sides."""
-    from multiverso_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM)
-    from multiverso_tpu.serving import InferenceServer
-    from tools.serving_bench import _spec_decode_ab
-
-    srv = InferenceServer("t")
-    cfg = TransformerConfig(vocab_size=256, d_model=128, n_heads=4,
-                            n_layers=2, d_ff=256, max_seq=80)
-    row = _spec_decode_ab(srv, TransformerLM(cfg), quick=True)
-    sp, base = row["spec"], row["baseline"]
-    assert sp["step_traces"] == base["step_traces"] == 1
-    assert sp["verify_traces"] == 1
-    assert sp["decode_step_retraces"] == base["decode_step_retraces"] == 0
-    assert sp["accepted_per_step"] > 1.0
-    assert 0.0 < sp["acceptance_rate_info"] <= 1.0
-    # the headline: more tokens per second from the same model, same
-    # pool, same trace (asserted with slack for noisy hosts — measured
-    # well above this on the CI container)
-    assert row["speedup_spec"] >= 1.1
-
-
-def test_slow_marker_audit_classifier():
-    """The conftest @slow audit's classifier (PR 7's lost-marker
-    regression, made structural): perf A/B names and serving_bench
-    INVOCATIONS require the marker; prose mentions of serving_bench in
-    a docstring do not."""
-    from conftest import _needs_slow_marker
-
-    # probe sources are built by concatenation so THIS test's own
-    # source never matches the invocation patterns it is probing
-    bench = "tools.serving_" + "bench"
-    assert _needs_slow_marker("test_decode_engine_ab_speedup", "")
-    assert _needs_slow_marker("test_spec_decode_ab_speedup", "")
-    assert _needs_slow_marker("test_x", f"from {bench} import _decode_ab")
-    assert _needs_slow_marker("test_x", f"import {bench}")
-    assert _needs_slow_marker("test_x", f"{bench}.run(1.0)")
-    assert not _needs_slow_marker(
-        "test_x", '"""the tier-1 face of the slow serving_' + 'bench '
-        'A/B"""')
-    assert not _needs_slow_marker("test_lock_inversion_trips", "")
-
-
-@pytest.mark.slow
-def test_chunked_prefill_ab_bounds_itl(mv_session):
-    """The serving_bench pulse/burst trace: chunked admission must cut
-    ITL p99 versus monolithic whole-prompt admission (measured 2.4-3.6x
-    on the CI container; asserted with slack — the 2-CPU container's
-    scheduling noise puts ~50 ms on any schedule's p99) while keeping
-    useful tokens/sec close, with one chunk trace + one step trace."""
-    from multiverso_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM)
-    from multiverso_tpu.serving import InferenceServer
-    from tools.serving_bench import _chunked_prefill_ab
-
-    srv = InferenceServer("t")
-    cfg = TransformerConfig(vocab_size=256, d_model=256, n_heads=4,
-                            n_layers=2, d_ff=768, max_seq=448)
-    row = _chunked_prefill_ab(srv, TransformerLM(cfg), quick=True)
-    assert row["chunked"]["prefill_traces"] == 1
-    assert row["chunked"]["step_traces"] == 1
-    assert row["itl_p99_speedup"] >= 1.5
-    assert row["tokens_per_s_ratio"] >= 0.75
-
-
 def test_register_decoder_losing_race_to_stop_stops_the_engine(
         mv_session, monkeypatch):
     """Regression: register_decoder's post-construction re-check only
@@ -556,75 +379,3 @@ def test_register_decoder_losing_race_to_stop_stops_the_engine(
     assert result and "stopped during" in result[0]
     assert stopped == ["lm"], "racing engine was never stopped"
     assert "lm" not in srv._models
-
-
-@pytest.mark.slow
-def test_obs_plane_ab_zero_dropped_reports(mv_session):
-    """The serving_bench obs-plane A/B: no agents vs a real two-rank
-    wire plane (publisher sockets + collector drain/ack) on the warm
-    engine. The gated number is the publisher's obs_dropped_reports —
-    with a live, acking collector the bounded publish window must
-    never fill, so a drop means the ack/release machinery broke; tok/s
-    columns archive as noise-floor _info."""
-    from multiverso_tpu.models.transformer import (TransformerConfig,
-                                                   TransformerLM)
-    from multiverso_tpu.serving import InferenceServer
-    from tools.serving_bench import _obs_plane_ab, _play_decode_trace
-
-    srv = InferenceServer("t")
-    cfg = TransformerConfig(vocab_size=256, d_model=128, n_heads=4,
-                            n_layers=2, d_ff=256, max_seq=80)
-    engine = srv.register_decoder(
-        "lm_obs", TransformerLM(cfg), slots=8, max_prompt=8, max_new=64,
-        max_queue=64, prompt_buckets=(8,))
-    engine.warmup()
-    _play_decode_trace(srv, "lm_obs",
-                       [(0.0, np.ones(4, np.int32), 2)] * 4, True)
-    row = _obs_plane_ab(srv, quick=True)
-    assert row["obs_dropped_reports"] == 0
-    assert row["obs_reports_info"] > 0
-    assert row["obs_collector_nodes_info"] == 2   # the wire rank landed
-    assert row["tokens_per_s_obs_off_info"] > 0
-    assert row["tokens_per_s_obs_on_info"] > 0
-
-
-@pytest.mark.slow
-def test_fleet_chaos_ab_recovery_face(mv_session):
-    """The serving_bench fleet-chaos A/B face: a 3-replica fleet under
-    a seeded mid-trace replica kill must lose NOTHING — requests_lost
-    and fleet_redispatch_output_mismatches gate at zero (replayed
-    outputs are bit-identical to the fault-free leg), the death is
-    observed (recovery_time_s > 0), and both fleet throughput columns
-    are live numbers."""
-    from tools.serving_bench import _fleet_chaos_ab
-
-    row = _fleet_chaos_ab(quick=True)
-    assert row["requests_lost"] == 0
-    assert row["fleet_redispatch_output_mismatches"] == 0
-    assert row["deaths_info"] == 1
-    assert row["recovery_time_s"] > 0
-    assert row["fleet_tokens_per_s"] > 0
-    assert row["fleet_tokens_per_s_chaos_info"] > 0
-    assert row["chaos_completed_info"] == row["requests"]
-
-
-@pytest.mark.slow
-def test_trainer_chaos_ab_durability_face(mv_session):
-    """The serving_bench trainer-chaos A/B face: a seeded mid-stream
-    trainer kill must lose NO acknowledged update — checkpoint+WAL
-    recovery reaches the exact pre-crash state (updates_lost 0), the
-    recovered-and-republished fleet state is bit-identical to the
-    fault-free leg (output_mismatches 0), exactly the staged zombie
-    publish is fenced, and the staleness/recovery wall clocks are live
-    numbers."""
-    from tools.serving_bench import _trainer_chaos_ab
-
-    row = _trainer_chaos_ab(quick=True)
-    assert row["trainer_killed_info"] == 1
-    assert row["updates_lost"] == 0
-    assert row["output_mismatches"] == 0
-    assert row["epoch_fence_rejections_unexpected"] == 0
-    assert row["trainer_recovery_time_s"] > 0
-    assert row["staleness_peak_s_info"] >= 0.2      # the flag threshold
-    assert row["wal_replay_records_info"] >= 1      # replay did work
-    assert row["checkpoint_step_info"] >= 1         # ...past a real ckpt

@@ -65,11 +65,14 @@ def _serve(srv, model, reqs):
     return [f.result(timeout=120)["result"].tolist() for f in futs]
 
 
+@pytest.mark.parametrize("budget", [5, 16])
 @pytest.mark.parametrize("prefix", [True, False])
-def test_sharded_matches_replicated_oracle(mv_session, prefix):
+def test_sharded_matches_replicated_oracle(mv_session, prefix, budget):
     """Randomized-trace oracle: tp=2 output tokens are identical to the
     tp=1 replicated path's, prefix cache on and off — and when it is
-    on, the trace actually exercises cache hits."""
+    on, the trace actually exercises cache hits. Budget 5 splits a
+    prompt into up to four chunks; 16 = ``max_prompt`` holds every
+    prompt in one."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
@@ -85,8 +88,8 @@ def test_sharded_matches_replicated_oracle(mv_session, prefix):
     for tp in (1, 2):
         engines[tp] = srv.register_decoder(
             f"lm_tp{tp}", lm, slots=4, max_prompt=16, max_new=8,
-            kv_block_size=4, prefill_token_budget=5, prefix_cache=prefix,
-            decode_tp=tp)
+            kv_block_size=4, prefill_token_budget=budget,
+            prefix_cache=prefix, decode_tp=tp)
         engines[tp].warmup()
         outs[tp] = _serve(srv, f"lm_tp{tp}", reqs)
     assert outs[2] == outs[1]
@@ -98,31 +101,6 @@ def test_sharded_matches_replicated_oracle(mv_session, prefix):
         if prefix:
             assert s["prefix_hits"] > 0, \
                 "trace never hit the prefix cache; test needs a new seed"
-
-
-def test_sharded_monolithic_admission_matches(mv_session):
-    """The paged fused-admission path (prefill_token_budget=0 — whole
-    prompts through cache_insert_paged's sharded variant) is also
-    token-identical across tp."""
-    from multiverso_tpu.models.transformer import TransformerLM
-    from multiverso_tpu.serving import InferenceServer
-
-    cfg = _tp_cfg()
-    lm = TransformerLM(cfg)
-    srv = InferenceServer("t")
-    rng = np.random.default_rng(7)
-    reqs = _random_reqs(rng, 10, cfg.vocab_size, max_prompt=8, max_new=6)
-
-    outs = {}
-    for tp in (1, 2):
-        engine = srv.register_decoder(
-            f"lm_mono_tp{tp}", lm, slots=4, max_prompt=8, max_new=6,
-            kv_block_size=4, prefill_token_budget=0,
-            prompt_buckets=(8,), decode_tp=tp)
-        engine.warmup()
-        outs[tp] = _serve(srv, f"lm_mono_tp{tp}", reqs)
-        assert engine.stats()["decode_step_retraces"] == 0
-    assert outs[2] == outs[1]
 
 
 def test_sharded_spec_decode_matches_replicated(mv_session):
@@ -192,8 +170,8 @@ def test_sharded_stats_and_recorder_are_mesh_aware(mv_session):
 
 
 def test_decode_tp_validation(mv_session):
-    """Fail-fast surface: tp must divide n_heads/d_ff, needs the paged
-    cache, and cannot exceed the visible device count."""
+    """Fail-fast surface: tp must divide n_heads/d_ff and cannot exceed
+    the visible device count."""
     from multiverso_tpu.log import FatalError
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
@@ -203,9 +181,6 @@ def test_decode_tp_validation(mv_session):
     with pytest.raises(FatalError):        # 3 does not divide n_heads=4
         srv.register_decoder("bad_heads", lm, kv_block_size=4,
                              decode_tp=3)
-    with pytest.raises(FatalError):        # contiguous strips: no mesh
-        srv.register_decoder("bad_paged", lm, kv_block_size=0,
-                             decode_tp=2)
     with pytest.raises(FatalError):        # more than the 8 test devices
         srv.register_decoder("bad_ndev", lm, kv_block_size=4,
                              decode_tp=100)

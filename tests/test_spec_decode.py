@@ -8,7 +8,7 @@ The acceptance contract of the spec-decode PR (docs/SERVING.md,
   a ``spec_k > 0`` engine's outputs are token-for-token the
   ``spec_k=0`` engine's and the per-request ``greedy_decode`` oracle's:
   speculation changes the schedule, never the tokens. Covered with the
-  prefix cache on and off, and under chunked and monolithic admission
+  prefix cache on and off, and with a prompt in several chunks and in one
   (``decode_tp=2`` rides in tests/test_sharded_decode.py);
 * **one trace each** — exactly one compiled step + one verify trace
   (+ one chunk / one CoW where applicable) per engine config, with
@@ -79,13 +79,13 @@ def _spec_trace(rng, vocab, max_prompt, max_new, n=10):
 
 
 @pytest.mark.parametrize("budget,prefix", [(4, True), (4, False),
-                                           (0, False)])
+                                           (12, False)])
 def test_spec_matches_baseline_and_oracle(mv_session, budget, prefix):
     """The correctness oracle: spec_k=3 outputs are token-identical to
     the spec_k=0 engine AND the per-request greedy oracle — prefix
-    cache on/off, chunked (budget=4) and monolithic (budget=0)
-    admission — while the engine actually speculates (accepted > 0)
-    and the compiled-trace set stays at one step + one verify (+ one
+    cache on/off, prompts in chunks of 4 and whole in one chunk
+    (budget 12 = ``max_prompt``) — while the engine actually speculates
+    (accepted > 0) and the compiled-trace set stays at one step + one verify (+ one
     chunk / one CoW)."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
@@ -98,7 +98,7 @@ def test_spec_matches_baseline_and_oracle(mv_session, budget, prefix):
         k: srv.register_decoder(
             f"lm_k{k}", lm, slots=4, max_prompt=12, max_new=10,
             kv_block_size=4, prefill_token_budget=budget,
-            prompt_buckets=(12,), prefix_cache=prefix, spec_k=k)
+            prefix_cache=prefix, spec_k=k)
         for k in (3, 0)
     }
     for e in engines.values():
@@ -131,9 +131,7 @@ def test_spec_matches_baseline_and_oracle(mv_session, budget, prefix):
         s = e.stats()
         assert s["step_traces"] == 1, s
         assert s["decode_step_retraces"] == 0
-        assert e.prefill_cache_size() >= 1
-    if budget > 0:
-        assert engines[3].prefill_cache_size() == 1
+        assert e.prefill_cache_size() == 1
     if prefix:
         assert spec["prefix_hits"] > 0, \
             "trace never hit the prefix cache; test needs a new seed"
@@ -416,16 +414,13 @@ def test_spec_flight_recorder_columns_and_timeline(mv_session, tmp_path):
 
 
 def test_spec_validation_fail_fasts(mv_session):
-    """spec_k needs the paged pool (the verify window parks rejected/pad
-    writes in the scratch block) and rejects negatives."""
+    """spec_k rejects negatives."""
     from multiverso_tpu.log import FatalError
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
     lm = TransformerLM(_small_cfg())
     srv = InferenceServer("t")
-    with pytest.raises(FatalError):          # contiguous strips: no spec
-        srv.register_decoder("bad_contig", lm, kv_block_size=0, spec_k=2)
     with pytest.raises(FatalError):
         srv.register_decoder("bad_neg", lm, kv_block_size=4, spec_k=-1)
 

@@ -279,8 +279,7 @@ def test_engine_health_ships_staleness(mv_session):
                             n_layers=1, d_ff=32, max_seq=16)
     lm = TransformerLM(cfg)
     eng = DecodeEngine("stale_probe", lm, DecodeEngineConfig(
-        slots=1, max_prompt=4, max_new=4, prompt_buckets=(4,),
-        watchdog=False))
+        slots=1, max_prompt=4, max_new=4, watchdog=False))
     try:
         h = eng.health()
         assert {"snapshot_version", "snapshot_epoch", "params_age_s",

@@ -254,16 +254,17 @@ def test_pool_drift_detector_on_real_engine(mv_session):
     assert engine.pool_drift() is None
     # corrupt: a reservation nothing owns (the leak signature)
     engine._pool.alloc(1)
-    # ... but the same pool state mid-monolithic-admission is NOT a
-    # leak: _admit holds reservations across its (possibly seconds-long)
-    # cold-bucket compile before any slot goes active
-    engine._admitting = True
+    # ... but the same pool state mid-prefill is NOT a leak: the
+    # admission holds its reservation across its chunks before its slot
+    # goes active (the loop sleeps on its condition, so it never sees
+    # this stand-in)
+    engine._pf = object()
     assert engine.pool_drift() is None
     # ... and that same in-flight admission IS live work to the stall
-    # check: its requests are off the queue with no slot active yet, so
-    # a wedged fused prefill would otherwise be invisible
+    # check: its request is off the queue with no slot active yet, so a
+    # wedged chunk would otherwise be invisible
     assert engine.health()["live_seqs"] == 1
-    engine._admitting = False
+    engine._pf = None
     assert engine.health()["live_seqs"] == 0
     assert wd.check_once() == []                  # first sighting
     fired = wd.check_once()                       # persisted -> trip

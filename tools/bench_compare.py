@@ -1,9 +1,15 @@
 """Diff two serving_bench JSON lines -> regression verdict (exit code).
 
-The standing perf gate for serving PRs: run ``tools/serving_bench.py``
-on the base and on the candidate, feed both JSON lines here, and the
-exit code says whether any tracked metric regressed past its threshold
-— no eyeballing twenty numbers per round.
+Its producer is gone: ``tools/serving_bench.py`` (CPU timings of toy
+models) was deleted in PR 30, speed is read from ``benchmarks/`` on the
+chip, and nothing in the tree writes the JSON lines this tool diffs any
+more. It stays only because ``tests/test_bench_compare.py`` stands on
+it; ROADMAP.md, Design 5, decides whether it is pointed at
+``benchmarks/run.py``'s result lines or deleted with those tests.
+
+It was the perf gate of the CPU serving series: run the bench on the
+base and on the candidate, feed both JSON lines here, and the exit code
+says whether any tracked metric regressed past its threshold.
 
 Direction is metric-aware: throughput-like metrics (``qps``,
 ``tokens_per_s``, ``speedup_*``) regress DOWN, latency/overload-like
@@ -17,10 +23,8 @@ tolerance for one metric name (applies wherever that name appears),
 and tiny latencies below ``--min-ms`` are ignored (sub-millisecond
 percentiles are scheduler noise, not signal).
 
-Usage::
+Usage (``base.json`` / ``new.json``: one such JSON line each)::
 
-    python tools/serving_bench.py > base.json     # on main
-    python tools/serving_bench.py > new.json      # on the candidate
     python tools/bench_compare.py base.json new.json [--tolerance 0.25]
         [--metric itl_p99_ms=0.5] [--min-ms 1.0]
 

@@ -1,8 +1,8 @@
 """Render utilization / bubble analysis from a flight-recorder dump.
 
 Input is the JSONL a :class:`serving.flight_recorder.FlightRecorder`
-writes (``engine.recorder.export_jsonl(path)``, a watchdog bundle's
-``ring.jsonl``, or ``tools/serving_bench.py --flight FILE``): one meta
+writes (``engine.recorder.export_jsonl(path)`` or a watchdog bundle's
+``ring.jsonl``): one meta
 line, then one record per engine iteration. This tool answers the
 post-hoc capacity questions the ring exists for:
 
@@ -91,8 +91,9 @@ def timeline_report(records: List[Dict[str, Any]], buckets: int = 40,
               "gaps": digest["gaps"][:top_gaps], "buckets": []}
     report.pop("max_idle_gap_ms")
     # prefix-cache effectiveness over time: the shared-block count rides
-    # every record since the prefix-caching PR (-1 on contiguous engines;
-    # absent in older dumps — both render as "no cache data")
+    # every record since the prefix-caching PR (-1 in dumps of the
+    # contiguous-cache engines that predate PR 30; absent in older dumps
+    # — both render as "no cache data")
     report["peak_shared"] = max(
         (r.get("pool_shared", -1) for r in records), default=-1)
     # speculative decoding: drafts verified/accepted ride every record

@@ -15,8 +15,8 @@ trace's ``device_duration_ps`` values come from the hardware counters
 and are the device-time number.
 
 ``--host-trace FILE`` adds the REQUEST dimension (docs/OBSERVABILITY.md):
-FILE is a Chrome trace JSON from ``multiverso_tpu.trace`` (e.g.
-``tools/serving_bench.py --trace``). Per request (one root span per
+FILE is a Chrome trace JSON from ``multiverso_tpu.trace``
+(``trace.export_chrome(FILE)``). Per request (one root span per
 trace id) the report breaks host wall time into queue wait, admission/
 prefill, batch execution and decode iterations — the stages that explain
 a p99 outlier. Given BOTH a host trace and an xprof TRACE_DIR, the two
