@@ -140,7 +140,8 @@ from ..analysis import lockwatch
 import time
 from concurrent.futures import Future
 from dataclasses import dataclass
-from typing import Deque, Dict, List, Optional, Sequence
+from typing import (Deque, Dict, List, NamedTuple, Optional,
+                    Sequence)
 
 import jax
 import jax.numpy as jnp
@@ -631,6 +632,26 @@ class _Request:
         self.usage: Optional[accounting.ResourceUsage] = None
 
 
+class _StepInFlight(NamedTuple):
+    """A dispatched step (or verify window) until its booking."""
+    nxt: object                     # the tokens, still on the device
+    reqs: List[Optional[_Request]]  # each slot's request AT DISPATCH
+    spec_toks: Optional[np.ndarray]
+    n_valid: Optional[np.ndarray]
+    t0: float                       # monotonic, before growth and drafts
+    launch_ms: float                # growth, drafts and the dispatch
+
+
+class _ChunkInFlight(NamedTuple):
+    """A dispatched prefill chunk until it is retired."""
+    req: _Request
+    logits: object                  # the last real row's, on the device
+    off: int
+    n: int                          # real tokens of the chunk
+    size: int                       # the program's chunk shape
+    t0: float                       # monotonic at dispatch (tracing only)
+
+
 class DecodeEngine:
     """One LM's continuous-batching decode loop.
 
@@ -994,6 +1015,7 @@ class DecodeEngine:
         self._it_spec_accepted = 0
         self._it_sp_chunks = 0
         self._it_live_blocks = -1
+        self._it_behind = 0
         self.completed = 0
         self.shed = 0
         self.tokens = 0
@@ -1027,6 +1049,12 @@ class DecodeEngine:
         # sequence-parallel prefill mirror (resets with the bench
         # window): chunks dispatched through the seqpar program
         self.seqpar_chunks = 0
+        # prefill chunks dispatched, and those of them dispatched while
+        # a step of the same pass was in flight (queued behind it on the
+        # device): with something live every chunk is one; resets with
+        # the bench window
+        self.prefill_chunks = 0
+        self.chunks_behind_step = 0
         # overload mirrors (the PREEMPTIONS/DEADLINE_DROPS counters
         # stay monotonic; these reset with the bench window):
         # preemption EVENTS, distinct requests preempted at least
@@ -1424,7 +1452,25 @@ class DecodeEngine:
                     f"after {now - req.t_enq:.3f}s queued "
                     f"(engine {self.name!r})"))
 
+    def _pop_admissible(self):
+        """One pop through the weighted-fair lane scheduler (expired
+        deadlines dropped at pop, bounded lookahead past a block-starved
+        head), gated on the block pool covering the arrival's
+        reservation: ``(request or None, expired)``. The pop decision
+        and the queue mutation are one step under the engine lock."""
+        with self._cv:
+            if not self._q:
+                return None, []
+            return self._q.pop_admissible(time.monotonic(),
+                                          self._blocks_cover)
+
     def _loop(self) -> None:
+        """The loop thread: wait for work, then one :meth:`_iteration`
+        a pass. Every program a pass dispatches is retired in that pass
+        (at most one step and one chunk in flight, the chunk queued
+        behind the step), so between passes, and wherever ``stop()``,
+        ``_maybe_refresh`` and ``_preempt`` run, nothing of the engine
+        is in flight."""
         while True:
             splices: List[tuple] = []
             with self._cv:
@@ -1444,65 +1490,88 @@ class DecodeEngine:
                         info["skipped"] = "stopped"
                         done.set()
                     return
-                # Phases on the profiler's clock (trace.phase; the shared
-                # NULL_SPAN with no session). A pass that did work is ONE
-                # engine.iter, to the end of _record_iteration, and opens
-                # with engine.admit, to its first dispatch. With
-                # something live, prefilling or to splice the work is
-                # sure and both open here; else the queue decides below:
-                # an arrival opens them late, and a pass that finds only
-                # block-starved or expired waiters is engine.wait. They
-                # outlive this lock's block, so they are entered and
-                # left by hand
-                it_phase = admit_phase = trace.NULL_SPAN
-                sure = bool(self._loop_work or self._pf is not None
-                            or self._active.any())
-                if sure:
-                    it_phase = trace.phase("engine.iter")
-                    it_phase.__enter__()
-                    admit_phase = trace.phase("engine.admit")
-                    admit_phase.__enter__()
                 if self._loop_work:
                     splices = list(self._loop_work)
                     self._loop_work.clear()
-                # admission pops through the weighted-fair lane
-                # scheduler (expired deadlines dropped at pop,
-                # bounded lookahead past a block-starved head) onto
-                # the explicit free-slot set, gated on the block pool
-                # covering the arrival's reservation. One admission
-                # prefills at a time; the NEXT request is only picked
-                # up once the current one goes live
-                arrivals: List[_Request] = []
-                expired: List[_Request] = []
-                if self._pf is None and self._free_q and self._q:
-                    req, expired = self._q.pop_admissible(
-                        time.monotonic(), self._blocks_cover)
-                    if req is not None:
-                        arrivals.append(req)
-                if not sure:
-                    it_phase = trace.phase(
-                        "engine.iter" if arrivals else "engine.wait")
-                    it_phase.__enter__()
-                    if arrivals:
-                        admit_phase = trace.phase("engine.admit")
-                        admit_phase.__enter__()
-            if expired:
+            # Phases on the profiler's clock (trace.phase; the shared
+            # NULL_SPAN with no session). With something live,
+            # prefilling or to splice the pass is sure of work and is
+            # ONE engine.iter, to the end of _record_iteration. From
+            # idle the queue decides: an arrival makes it an iteration
+            # (the pop itself, microseconds, is in neither), and a pass
+            # that finds only block-starved or expired waiters is an
+            # engine.wait
+            arrival, expired = None, []
+            if not (splices or self._pf is not None or self._active.any()):
+                if self._free_q:
+                    arrival, expired = self._pop_admissible()
+                if arrival is None:
+                    # nothing live and nothing admissible: the queue
+                    # holds only block-starved waiters (a budget-
+                    # exhausted pessimistic re-admission, or a chaos-
+                    # squeezed pool) or expired ones: yield briefly
+                    # instead of hot-spinning until blocks free
+                    with trace.phase("engine.wait"):
+                        self._drop_expired(expired)
+                        time.sleep(0.0005)
+                    continue
+            with trace.phase("engine.iter"):
                 self._drop_expired(expired)
-            # the progress clock restarts when the loop picks work up:
-            # last_iter_age_s then measures how long THIS pass has been
-            # stuck, not how long the engine idled beforehand (an idle
-            # engine is not a stalled one — the watchdog's distinction)
-            t_work0 = time.monotonic()
-            self._last_progress = t_work0
-            self._it_admitted.clear()
-            self._it_completed.clear()
-            self._it_prefill = self._it_decode = 0
-            self._it_spec_proposed = self._it_spec_accepted = 0
-            self._it_sp_chunks = 0
-            self._it_live_blocks = -1
-            step_ms = 0.0
-            worked = False
-            try:
+                if not self._iteration(splices, arrival):
+                    return
+
+    def _iteration(self, splices: List[tuple],
+                   arrival: Optional[_Request]) -> bool:
+        """One pass that does work. Nothing is in flight on entry and
+        nothing on exit; in between the device is handed its programs
+        back to back and the host's own work runs under them:
+
+        1. ``engine.step``: grow reservations (preempting under
+           pressure), propose drafts, dispatch the step (or verify
+           window) over the slots live NOW. No wait.
+        2. ``engine.admit``: KV splices, then pop and reserve: full
+           prefix hits go live (they join the NEXT pass's step), the
+           first admission that needs prefill becomes ``_pf``.
+        3. ``engine.prefill_chunk``: build and dispatch ONE budget-sized
+           chunk of ``_pf``, queued on the device behind the step. No
+           wait.
+        4. ``engine.step.sync`` then ``engine.step.book``: fetch the
+           step's tokens and book them against the slots it was
+           dispatched with, while the device runs the chunk.
+        5. ``engine.prefill_chunk.sync``: retire the chunk (its pools,
+           or a prompt's last chunk's logits), then the first token and
+           the slot going live.
+        6. ``engine.record``.
+
+        With nothing live there is no step and the chunk is dispatched
+        and retired at once; with nothing prefilling the step is
+        dispatched, synced and booked. A slot freed by the booking in 4
+        takes its next admission in the next pass. Returns False when
+        the pass failed and the loop must die."""
+        # the progress clock restarts when the loop picks work up:
+        # last_iter_age_s then measures how long THIS pass has been
+        # stuck, not how long the engine idled beforehand (an idle
+        # engine is not a stalled one — the watchdog's distinction)
+        t_work0 = time.monotonic()
+        self._last_progress = t_work0
+        self._it_admitted.clear()
+        self._it_completed.clear()
+        self._it_prefill = self._it_decode = 0
+        self._it_spec_proposed = self._it_spec_accepted = 0
+        self._it_sp_chunks = 0
+        self._it_live_blocks = -1
+        self._it_behind = 0
+        step_ms = 0.0
+        arrivals = [] if arrival is None else [arrival]
+        try:
+            step = chunk = None
+            if self._active.any():
+                with trace.phase("engine.step"):
+                    step = self._dispatch_step()
+            # the pools as the step's sync retires them (with no step:
+            # as the last pass left them, retired)
+            retired = self._pools
+            with trace.phase("engine.admit"):
                 # inbound KV transfers apply OUTSIDE the engine lock on
                 # this (loop) thread — the only thread allowed to
                 # reassign the donated caches. A bad payload degrades
@@ -1515,70 +1584,68 @@ class DecodeEngine:
                         info["error"] = exc
                     finally:
                         done.set()
-                    worked = True
-                if arrivals:
-                    self._begin_prefill(arrivals[0],
-                                        self._free_q.popleft())
-                # zero-cost admissions (a full prefix hit goes live
-                # without a single prefill chunk) must not consume
-                # the iteration's one admission slot: keep admitting
-                # until a chunk is actually pending or nothing is
-                # admissible, so a full-hit-heavy trace admits at
-                # slot rate instead of one request per iteration
-                # (the per-iteration chunk budget below is what
-                # bounds ITL, and these admissions cost no chunk)
-                while self._pf is None and self._free_q:
-                    with self._cv:
-                        if not self._q:
-                            break
-                        req, exp = self._q.pop_admissible(
-                            time.monotonic(), self._blocks_cover)
-                    if exp:
-                        self._drop_expired(exp)
-                    if req is None:
-                        break
-                    arrivals.append(req)
-                    self._begin_prefill(req, self._free_q.popleft())
-                    if req.slot == -1:
-                        # the reservation raced a pool claimant and
-                        # the request was requeued — retry next
-                        # iteration rather than spinning here
-                        break
-                admit_phase.__exit__(None, None, None)
-                if self._pf is not None:
-                    # AT MOST one budget-sized chunk per iteration:
-                    # the stall an admission can add to every live
-                    # generation's next token is one chunk of work
-                    with trace.phase("engine.prefill_chunk"):
-                        self._prefill_one_chunk()
-                    worked = True
-                live = int(self._active.sum()) + (self._pf is not None)
-                if live > self.peak_live:
-                    self.peak_live = live
-                if self._active.any():
-                    t_step0 = time.monotonic()
-                    with trace.phase("engine.step"):
-                        self._step()
-                    step_ms = (time.monotonic() - t_step0) * 1e3
-                    worked = True
-            except Exception as exc:          # pragma: no cover - defensive
-                # arrivals are already popped from the queue but may not
-                # be slotted yet — include them so their futures fail too
-                self._fail_all(exc, arrivals)
-                admit_phase.__exit__(None, None, None)
-                it_phase.__exit__(None, None, None)
-                return
-            if worked:
-                with trace.phase("engine.record"):
-                    self._record_iteration(t_work0, step_ms)
-            elif not arrivals and not expired:
-                # nothing live and nothing admissible: the queue holds
-                # only block-starved waiters (a budget-exhausted
-                # pessimistic re-admission, or a chaos-squeezed pool) —
-                # yield briefly instead of hot-spinning until blocks
-                # free
-                time.sleep(0.0005)
-            it_phase.__exit__(None, None, None)
+                self._admit(arrivals)
+            if self._pf is not None:
+                # AT MOST one budget-sized chunk per iteration: the
+                # stall an admission can add to every live generation's
+                # next token is one chunk of work
+                with trace.phase("engine.prefill_chunk"):
+                    chunk = self._dispatch_chunk()
+                if step is not None:
+                    # queued on the device behind the step in flight:
+                    # its launch falls under the step's run
+                    self.chunks_behind_step += 1
+                    self._it_behind = 1
+            live = int(self._active.sum()) + (self._pf is not None)
+            if live > self.peak_live:
+                self.peak_live = live
+            if step is not None:
+                step_ms = self._retire_step(step)
+            if chunk is not None:
+                self._retire_chunk(chunk)
+            elif self._pools is not retired:
+                # a copy-on-write or a splice with no chunk after it to
+                # wait on: retired here too (microseconds of device
+                # work, long done when a step was booked meanwhile)
+                jax.block_until_ready(self._pools)
+        except Exception as exc:          # pragma: no cover - defensive
+            # arrivals are already popped from the queue but may not
+            # be slotted yet — include them so their futures fail too
+            self._fail_all(exc, arrivals)
+            return False
+        if (step is not None or chunk is not None or splices
+                or self._it_admitted):
+            with trace.phase("engine.record"):
+                self._record_iteration(t_work0, step_ms)
+        return True
+
+    def _admit(self, arrivals: List[_Request]) -> None:
+        """Admission onto the explicit free-slot set. One admission
+        prefills at a time: the NEXT request is only picked up once the
+        current one goes live. Zero-cost admissions (a full prefix hit
+        goes live without a single prefill chunk) must not consume the
+        iteration's one admission slot: keep admitting until a chunk is
+        actually pending or nothing is admissible, so a full-hit-heavy
+        trace admits at slot rate instead of one request per iteration
+        (the per-iteration chunk budget is what bounds ITL, and these
+        admissions cost no chunk). ``arrivals`` holds the request a pass
+        from idle already popped, and collects every later pop, so the
+        failure path reaches requests that are popped and not yet
+        slotted."""
+        if arrivals:
+            self._begin_prefill(arrivals[0], self._free_q.popleft())
+        while self._pf is None and self._free_q:
+            req, expired = self._pop_admissible()
+            self._drop_expired(expired)
+            if req is None:
+                break
+            arrivals.append(req)
+            self._begin_prefill(req, self._free_q.popleft())
+            if req.slot == -1:
+                # the reservation raced a pool claimant and the request
+                # was requeued — retry next iteration rather than
+                # spinning here
+                break
 
     def _record_iteration(self, t_work0: float, step_ms: float) -> None:
         """One iteration retired: bump the progress clock/counters and
@@ -1637,7 +1704,10 @@ class DecodeEngine:
             # entries this pass's step had to read
             (self._it_live_blocks
              / (self.config.slots * self._blocks_per_seq))
-            if self._it_live_blocks >= 0 else -1.0))
+            if self._it_live_blocks >= 0 else -1.0,
+            # overlap tail (FIELDS append at the END): 1 when this
+            # pass's chunk was dispatched behind its in-flight step
+            self._it_behind))
 
     def _xfer_block_shape(self) -> tuple:
         """One block of the first pool as the transfer plane ships it:
@@ -1898,32 +1968,43 @@ class DecodeEngine:
         req.sp = self._sp and len(req.prompt) >= self._sp_threshold
         self._pf = req
 
-    def _prefill_one_chunk(self) -> None:
-        """Run ONE budget-sized chunk of the in-flight admission's
-        prefill; on the final chunk the first token falls out and the
-        slot goes live (or resolves immediately on eos-at-first-token,
-        never occupying the slot)."""
+    def _dispatch_chunk(self) -> _ChunkInFlight:
+        """Build and dispatch ONE budget-sized chunk of the in-flight
+        admission's prefill; no wait."""
         req = self._pf
-        sp = req.sp
-        C = self._sp_chunk if sp else self._budget
+        C = self._sp_chunk if req.sp else self._budget
         off = req.pf_off
         n = min(C, len(req.prompt) - off)
         toks = np.zeros(C, np.int32)
         toks[: n] = req.prompt[off: off + n]
-        tracing = trace.enabled()
-        t0 = time.monotonic() if tracing else 0.0
-        chunk_fn = self._chunk_sp_fn if sp else self._chunk_fn
+        t0 = time.monotonic() if trace.enabled() else 0.0
+        chunk_fn = self._chunk_sp_fn if req.sp else self._chunk_fn
+        # the program gets its OWN block tables: the step's booking
+        # runs under the chunk and resets the rows of the slots it
+        # frees, and a dispatched program may read a host array late
         *pools, logits = chunk_fn(
-            self._pinned, *self._pools, self._block_tables,
+            self._pinned, *self._pools, self._block_tables.copy(),
             np.int32(req.slot), toks, np.int32(off), np.int32(n))
         self._pools = tuple(pools)
-        # block per chunk: letting chunk dispatches run ahead
-        # asynchronously looks free, but an idle->busy transition can
-        # queue several chunks on the device and the NEXT fused step's
-        # sync pays for all of them at once — exactly the unbounded ITL
-        # spike the budget exists to prevent (measured: p99 went from
-        # ~1 chunk+step to >100 ms under ramp). One chunk per iteration,
-        # retired per iteration, keeps the bound honest.
+        self.prefill_chunks += 1
+        return _ChunkInFlight(req, logits, off, n, C, t0)
+
+    def _retire_chunk(self, chunk: _ChunkInFlight) -> None:
+        """Wait for the chunk dispatched earlier in this pass and book
+        it; on the final chunk the first token falls out and the slot
+        goes live (or resolves immediately on eos-at-first-token, never
+        occupying the slot)."""
+        req, logits, off, n, C, t0 = chunk
+        sp = req.sp
+        tracing = trace.enabled()
+        # retired in the pass that dispatched it: letting chunk
+        # dispatches run ahead asynchronously looks free, but an
+        # idle->busy transition can queue several chunks on the device
+        # and the NEXT fused step's sync pays for all of them at once —
+        # exactly the unbounded ITL spike the budget exists to prevent
+        # (measured: p99 went from ~1 chunk+step to >100 ms under ramp).
+        # At most one step and one chunk are in flight, none between
+        # passes, which keeps the bound honest.
         with trace.phase("engine.prefill_chunk.sync"):
             jax.block_until_ready(self._pools[0])
         req.pf_off = off + n
@@ -2369,13 +2450,16 @@ class DecodeEngine:
                     break
                 self._preempt(victim, why=f"growth for rid {req.rid}")
 
-    def _step(self) -> None:
-        # ONE branch decides all per-iteration trace work: when tracing
-        # is off this loop allocates nothing trace-related (guarded by
-        # test_observability's overhead test)
-        tracing = trace.enabled()
-        ledger_on = self.ledger is not None
-        t_it0 = time.monotonic() if (tracing or ledger_on) else 0.0
+    def _dispatch_step(self) -> Optional[_StepInFlight]:
+        """Grow every live reservation, propose drafts and dispatch the
+        fused step (or the verify window) over the slots live NOW; no
+        wait. None when growth preempted every live slot. The program
+        gets COPIES of the host arrays and the booking gets the slots'
+        requests as they stand here: admission runs while the step is in
+        flight and writes block tables, ``_tok``, ``_pos`` and
+        ``_active`` (a full prefix hit goes live at once), and a
+        dispatched program may read a host array late."""
+        t0 = time.monotonic()
         spec_toks = n_valid = None
         if self._spec:
             spec_toks, n_valid = self._propose_drafts()
@@ -2386,7 +2470,7 @@ class DecodeEngine:
             self._ensure_growth(n_valid if spec_toks is not None
                                 else None)
             if not self._active.any():
-                return
+                return None
         # blocks this step's attention reads: every live slot's
         # positions <= pos (host state the loop already holds)
         self._it_live_blocks = int(np.sum(
@@ -2395,29 +2479,47 @@ class DecodeEngine:
         # host state (tok/pos/active and the block tables)
         # feeds the jit as plain numpy: the same aval signature warmup()
         # uses, so the two share one trace
+        tables = self._block_tables.copy()
+        pos, active = self._pos.copy(), self._active.copy()
         if spec_toks is not None:
             # fused verify: ONE forward scores every window position;
-            # acceptance is decided below on the host from the argmax
-            # chain (traced data in, plain ints out — never a shape)
+            # acceptance is decided at the booking on the host from the
+            # argmax chain (traced data in, plain ints out — never a
+            # shape)
             self.spec_steps += 1
             *pools, nxt = self._verify_fn(
-                self._pinned, *self._pools, self._block_tables,
-                spec_toks, self._pos, self._active, n_valid)
+                self._pinned, *self._pools, tables, spec_toks, pos,
+                active, n_valid)
         else:
             *pools, nxt, _ = self._step_fn(
-                self._pinned, *self._pools, self._block_tables,
-                self._tok, self._pos, self._active)
+                self._pinned, *self._pools, tables, self._tok.copy(),
+                pos, active)
         self._pools = tuple(pools)
-        with trace.phase("engine.step.sync"):
-            nxt = np.array(nxt)   # [S] or [S, K+1]; the host sync point
-        with trace.phase("engine.step.book"):
-            self._book_step(nxt, spec_toks, n_valid, t_it0, tracing)
+        return _StepInFlight(nxt, list(self._slot_req), spec_toks, n_valid,
+                             t0, (time.monotonic() - t0) * 1e3)
 
-    def _book_step(self, nxt, spec_toks, n_valid, t_it0: float,
-                   tracing: bool) -> None:
-        """What a step's synced tokens mean on the host: each live
-        slot's emissions booked, histograms and ledger charged, finished
-        requests released and resolved."""
+    def _retire_step(self, step: _StepInFlight) -> float:
+        """Fetch the in-flight step's tokens and book them; returns the
+        host's milliseconds on the step (its launch, this wait and the
+        booking: the flight recorder's ``step_ms``)."""
+        t1 = time.monotonic()
+        with trace.phase("engine.step.sync"):
+            nxt = np.array(step.nxt)   # [S] or [S, K+1]; the host sync point
+        with trace.phase("engine.step.book"):
+            self._book_step(step, nxt)
+        return step.launch_ms + (time.monotonic() - t1) * 1e3
+
+    def _book_step(self, step: _StepInFlight, nxt) -> None:
+        """What a step's synced tokens mean on the host: each slot that
+        was live AT ITS DISPATCH has its emissions booked, histograms
+        and ledger charged, finished requests released and resolved. A
+        slot that went live since (a full hit admitted under the step)
+        was not computed and is neither booked nor advanced."""
+        spec_toks, n_valid, t_it0 = step.spec_toks, step.n_valid, step.t0
+        # ONE branch decides all per-iteration trace work: when tracing
+        # is off this loop allocates nothing trace-related (guarded by
+        # test_observability's overhead test)
+        tracing = trace.enabled()
         ledger_on = self.ledger is not None
         now = time.monotonic()
         self.steps_counter.inc()
@@ -2428,11 +2530,10 @@ class DecodeEngine:
             # per-slot loop so a sequence completing this very step
             # still pays for it
             self.ledger.charge_step(
-                [r for r in self._slot_req if r is not None],
+                [r for r in step.reqs if r is not None],
                 (now - t_it0) * 1e3)
         n_active = 0
-        for s in range(self.config.slots):
-            req = self._slot_req[s]
+        for s, req in enumerate(step.reqs):
             if req is None:
                 continue
             n_active += 1
@@ -2797,6 +2898,8 @@ class DecodeEngine:
         self.spec_accepted = 0
         self.spec_steps = 0
         self.seqpar_chunks = 0
+        self.prefill_chunks = 0
+        self.chunks_behind_step = 0
         self.preemptions = 0
         self.preempted = 0
         self.deadline_drops = 0
@@ -2994,6 +3097,8 @@ class DecodeEngine:
             "prefill_traces": self.prefill_cache_size(),
             "prefill_token_budget": self._budget,
             "prefill_tokens": self.prefill_tokens,
+            "prefill_chunks": self.prefill_chunks,
+            "chunks_behind_step": self.chunks_behind_step,
         }
 
     # -- lifecycle ----------------------------------------------------------

@@ -23,8 +23,9 @@ Record schema (:data:`FIELDS`, positional):
 ``it``                  iteration index (1-based, monotonic per engine)
 ``ts``                  ``time.monotonic()`` at record time (iteration end)
 ``busy_ms``             wall of this loop pass's work (admit + chunk + step)
-``step_ms``             the fused decode step's share of ``busy_ms`` (0 if
-                        the pass ran no step)
+``step_ms``             the fused decode step's share of ``busy_ms``: its
+                        launch, the wait for its tokens and the booking
+                        (0 if the pass ran no step)
 ``live``                live slots after the pass
 ``reserved``            mid-prefill admissions (reserved-not-live slots)
 ``queue``               admission-queue depth after the pass
@@ -60,6 +61,9 @@ Record schema (:data:`FIELDS`, positional):
 ``kv_live_block_share`` KV blocks this pass's step had to read (live
                         slots' ``ceil((pos + 1) / Bs)``) over ``slots x
                         M`` (-1 when the pass ran no step)
+``chunks_behind_step``  1 when this pass's prefill chunk was dispatched
+                        while its step was in flight (queued behind it
+                        on the device), else 0
 ======================  =====================================================
 
 Timestamps are monotonic; the recorder captures a wall/mono anchor at
@@ -100,7 +104,7 @@ FIELDS = ("it", "ts", "busy_ms", "step_ms", "live", "reserved", "queue",
           "pool_live", "pool_shared", "version", "admitted", "completed",
           "spec_proposed", "spec_accepted", "kv_quant",
           "quant_scale_blocks", "kv_block_s", "tenants_live", "sp_chunks",
-          "kv_live_block_share")
+          "kv_live_block_share", "chunks_behind_step")
 
 
 def window_digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:

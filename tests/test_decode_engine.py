@@ -913,7 +913,225 @@ def test_kv_live_block_share_counts_the_steps_live_blocks(mv_session):
     stats = engine.stats()
     assert stats["kv_live_block_share"] == pytest.approx(
         sum(want) / (len(want) * slots * M))
-    shares = [r["kv_live_block_share"] for r in engine.recorder.records()
-              if r.get("kv_live_block_share", -1) >= 0]
-    assert shares[-len(want):] == pytest.approx(
+    def shares():
+        return [r["kv_live_block_share"] for r in engine.recorder.records()
+                if r.get("kv_live_block_share", -1) >= 0]
+
+    # a reply resolves at the booking, its pass's record comes after
+    deadline = time.monotonic() + 20.0
+    while len(shares()) < len(want) and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert shares()[-len(want):] == pytest.approx(
         [b / (slots * M) for b in want])
+
+
+# -- the loop's order: the chunk queued behind the step, the booking under it -
+
+def _pins_nothing_in_flight(engine):
+    """Wrap ``_record_iteration`` (the end of every pass that did work):
+    collects the passes at whose end a pool was still being written."""
+    late = []
+    record = engine._record_iteration
+
+    def checked(t_work0, step_ms):
+        if not all(p.is_ready() for p in engine._pools):
+            late.append(engine.iters_total + 1)
+        record(t_work0, step_ms)
+
+    engine._record_iteration = checked
+    return late
+
+
+def test_overlapped_loop_is_oracle_exact_under_churn(mv_session):
+    """Staggered arrivals, prompts of one to three chunks, a full prefix
+    hit (copy-on-write) and preemptions under a small pool: every answer
+    is ``greedy_decode``'s, the step compiled once, every chunk that was
+    dispatched while something was live went out behind that pass's
+    step, and no pass ends with a program of the engine in flight."""
+    from multiverso_tpu.models.transformer import TransformerLM
+    from multiverso_tpu.serving import InferenceServer
+
+    cfg = _small_cfg()
+    lm = TransformerLM(cfg)
+    srv = InferenceServer("t")
+    engine = srv.register_decoder("lm", lm, slots=4, max_prompt=12,
+                                  max_new=16, kv_block_size=4,
+                                  kv_pool_blocks=10, prefill_token_budget=4)
+    engine.warmup()
+    late = _pins_nothing_in_flight(engine)
+    params, _ = lm.snapshot_params()
+    rng = np.random.default_rng(31)
+    shared = rng.integers(1, cfg.vocab_size, 8).astype(np.int32)
+    # seed the cache, so that the repeat below is a full hit
+    srv.submit("lm", {"prompt": shared, "max_new": 3}).result(timeout=120)
+    reqs = [(shared, 6)]
+    for _ in range(17):
+        reqs.append((rng.integers(1, cfg.vocab_size, int(
+            rng.integers(1, 13))).astype(np.int32), int(rng.integers(2, 17))))
+    futs = []
+    for i, (prompt, max_new) in enumerate(reqs):
+        futs.append(srv.submit("lm", {"prompt": prompt, "max_new": max_new,
+                                      "priority": i % 3}))
+        if i % 5 == 4:
+            time.sleep(0.01)                # arrivals in waves
+    for (prompt, max_new), fut in zip(reqs, futs):
+        np.testing.assert_array_equal(
+            fut.result(timeout=120)["result"],
+            _oracle(cfg, params, prompt, max_new),
+            err_msg=f"prompt {prompt} max_new {max_new}")
+    assert engine.step_cache_size() == 1
+    assert engine.prefill_cache_size() == 1
+    stats = engine.stats()
+    assert stats["cow_copies"] >= 1 and stats["preemptions"] > 0
+    assert engine.pool_drift() is None
+    records = engine.recorder.records()
+    chunks = [r for r in records if r["prefill_toks"] > 0]
+    # a pass dispatches its step first: a chunk went out behind a step
+    # exactly where the pass had both
+    behind = sum(r["step_ms"] > 0 for r in chunks)
+    assert stats["prefill_chunks"] == len(chunks)
+    assert stats["chunks_behind_step"] == behind \
+        == sum(r["chunks_behind_step"] for r in records)
+    assert 0 < behind < len(chunks)         # from idle there is no step
+    assert late == []
+
+
+def test_programs_in_flight_read_their_own_host_arrays(mv_session):
+    """The aliasing guard. A dispatched program may read its numpy
+    arguments late, and admission and booking write the engine's block
+    tables, ``_tok``, ``_pos`` and ``_active`` while a step or a chunk
+    is in flight: so every dispatch gets arrays of its own. Here each
+    dispatch is followed by garbage over the engine's arrays until the
+    program has run: the tokens stay the oracle's."""
+    import jax
+
+    from multiverso_tpu.models.transformer import TransformerLM
+    from multiverso_tpu.serving import InferenceServer
+
+    cfg = _small_cfg()
+    lm = TransformerLM(cfg)
+    srv = InferenceServer("t")
+    engine = srv.register_decoder("lm", lm, slots=4, max_prompt=12,
+                                  max_new=10, kv_block_size=4,
+                                  prefill_token_budget=4)
+    engine.warmup()
+    params, _ = lm.snapshot_params()
+    live = ("_block_tables", "_tok", "_pos", "_active")
+    scribbled = []
+
+    def scribbling(fn):
+        def dispatch(pinned, *args):
+            mine = [getattr(engine, name) for name in live]
+            for arg in args:
+                if isinstance(arg, np.ndarray):
+                    assert not any(np.shares_memory(arg, a) for a in mine)
+            out = fn(pinned, *args)
+            saved = [a.copy() for a in mine]
+            for a in mine:
+                a[...] = 1 if a.dtype == bool else 99
+            jax.block_until_ready(out)
+            for a, was in zip(mine, saved):
+                a[...] = was
+            scribbled.append(fn)
+            return out
+        return dispatch
+
+    step_fn, chunk_fn = engine._step_fn, engine._chunk_fn
+    engine._step_fn, engine._chunk_fn = scribbling(step_fn), \
+        scribbling(chunk_fn)
+    rng = np.random.default_rng(32)
+    reqs = [(rng.integers(1, cfg.vocab_size, int(
+        rng.integers(1, 13))).astype(np.int32), int(rng.integers(2, 11)))
+        for _ in range(10)]
+    futs = [srv.submit("lm", {"prompt": p, "max_new": n}) for p, n in reqs]
+    for (prompt, max_new), fut in zip(reqs, futs):
+        np.testing.assert_array_equal(
+            fut.result(timeout=120)["result"],
+            _oracle(cfg, params, prompt, max_new))
+    engine._step_fn, engine._chunk_fn = step_fn, chunk_fn
+    assert step_fn in scribbled and chunk_fn in scribbled
+    assert engine.step_cache_size() == 1
+
+
+def test_full_hit_admitted_under_a_step_is_booked_nothing_from_it(
+        mv_session):
+    """A fully cached prompt goes live at admission, which now runs
+    while the pass's step is in flight: that step was dispatched without
+    the slot, so its booking walks the slots as they stood at the
+    dispatch and the newcomer's first token falls out of the NEXT
+    pass's step."""
+    from multiverso_tpu.models.transformer import TransformerLM
+    from multiverso_tpu.serving import InferenceServer
+
+    cfg = _small_cfg()
+    lm = TransformerLM(cfg)
+    srv = InferenceServer("t")
+    engine = srv.register_decoder("lm", lm, slots=4, max_prompt=12,
+                                  max_new=16, kv_block_size=4,
+                                  prefill_token_budget=4)
+    engine.warmup()
+    params, _ = lm.snapshot_params()
+    rng = np.random.default_rng(33)
+    cached = rng.integers(1, cfg.vocab_size, 8).astype(np.int32)
+    other = rng.integers(1, cfg.vocab_size, 11).astype(np.int32)
+    srv.submit("lm", {"prompt": cached, "max_new": 2}).result(timeout=120)
+    # the long one prefills in three chunks with the repeat queued behind
+    # it (one admission prefills at a time): the repeat is admitted in
+    # the first pass that steps the long one
+    long_fut = srv.submit("lm", {"prompt": other, "max_new": 16})
+    hit_fut = srv.submit("lm", {"prompt": cached, "max_new": 5})
+    np.testing.assert_array_equal(long_fut.result(timeout=120)["result"],
+                                  _oracle(cfg, params, other, 16))
+    np.testing.assert_array_equal(hit_fut.result(timeout=120)["result"],
+                                  _oracle(cfg, params, cached, 5))
+    assert engine.stats()["cow_copies"] == 1
+    records = engine.recorder.records()
+    _, _, admit = [r for r in records if r["admitted"]]   # seed, long, hit
+    (hit_rid,) = admit["admitted"]
+    # the pass that admitted it stepped the long one alone: one token
+    # booked, two slots live at its end; the next pass books two
+    assert admit["step_ms"] > 0 and admit["prefill_toks"] == 0
+    assert (admit["decode_toks"], admit["live"]) == (1, 2)
+    after = records[records.index(admit) + 1]
+    assert after["decode_toks"] == 2
+    done = [r for r in records if hit_rid in r["completed"]]
+    assert done[0]["it"] == admit["it"] + 5       # five tokens, five steps
+
+
+def test_failure_under_a_step_in_flight_fails_every_future(mv_session):
+    """An exception between a step's dispatch and the end of the pass
+    (here the chunk's dispatch) fails the live requests, the admission
+    that was popped under the step and the queue, and returns every
+    block."""
+    from multiverso_tpu.models.transformer import TransformerLM
+    from multiverso_tpu.serving import InferenceServer
+
+    cfg = _small_cfg()
+    lm = TransformerLM(cfg)
+    srv = InferenceServer("t")
+    engine = srv.register_decoder("lm", lm, slots=2, max_prompt=8,
+                                  max_new=16, kv_block_size=4,
+                                  prefill_token_budget=4)
+    engine.warmup()
+    chunk_fn = engine._chunk_fn
+    went = []
+
+    def boom(*args):
+        if engine._active.any():            # a step is in flight
+            raise RuntimeError("injected chunk failure")
+        went.append(1)
+        return chunk_fn(*args)
+
+    engine._chunk_fn = boom
+    rng = np.random.default_rng(34)
+    futs = [srv.submit("lm", {"prompt": rng.integers(
+        1, cfg.vocab_size, 6).astype(np.int32), "max_new": 16})
+        for _ in range(4)]
+    for fut in futs:
+        with pytest.raises(RuntimeError, match="injected chunk failure"):
+            fut.result(timeout=60)
+    engine._chunk_fn = chunk_fn
+    assert went                 # the first admission prefilled from idle
+    assert engine.stats()["kv_blocks_live"] == 0
+    assert engine.pool_drift() is None
+    engine._pool.check()

@@ -8,9 +8,11 @@ for the per-layer readers. Checked here, on the CPU:
 
 * the names in the engine's source are the documented nine, and the
   prefix is the one the reduction admits;
-* a toy engine under a real session leaves all nine, nested as
-  documented and counted as the flight recorder counts, and a pass that
-  finds only block-starved waiters is a wait and no iteration;
+* a toy engine under a real session leaves all nine, in the documented
+  order (a pass's phases are children of ``engine.iter``, one after
+  another: the chunk's dispatch lies between the step's dispatch and the
+  step's sync) and counted as the flight recorder counts, and a pass
+  that finds only block-starved waiters is a wait and no iteration;
 * the reduction books a device-idle gap to the engine's phase and not to
   the longer harness span around it;
 * each reader added with the phases computes its value from the summed
@@ -81,6 +83,12 @@ def _inside(child, parents):
                for p in parents)
 
 
+# the phases of one pass, in the order the loop enters them
+_PASS_ORDER = ("engine.step", "engine.admit", "engine.prefill_chunk",
+               "engine.step.sync", "engine.step.book",
+               "engine.prefill_chunk.sync", "engine.record")
+
+
 def test_toy_engine_leaves_all_nine_phases(mv_session, tmp_path):
     import jax
 
@@ -124,31 +132,40 @@ def test_toy_engine_leaves_all_nine_phases(mv_session, tmp_path):
         return sum(spans[P + name]["s"] for name in names)
 
     assert n("engine.iter") == len(records)
-    assert n("engine.record") == len(records)
+    assert n("engine.record") == n("engine.admit") == len(records)
     assert n("engine.step") == n("engine.step.sync") \
         == n("engine.step.book") == steps
-    assert n("engine.prefill_chunk") == sum(r["prefill_toks"] > 0
-                                            for r in records)
-    # each parent holds its children's time, and each sync lies in one
-    assert s("engine.admit", "engine.prefill_chunk", "engine.step",
-             "engine.record") <= s("engine.iter")
-    assert s("engine.step.sync", "engine.step.book") <= s("engine.step")
-    assert s("engine.prefill_chunk.sync") <= s("engine.prefill_chunk")
+    chunks = sum(r["prefill_toks"] > 0 for r in records)
+    assert n("engine.prefill_chunk") == chunks > 0
+    # a chunk is waited for once, a prompt's last chunk twice (its
+    # pools, then its logits)
+    assert chunks <= n("engine.prefill_chunk.sync") <= 2 * chunks
     by_name = {}
     for row in rows:
         by_name.setdefault(row[2][len(P):], []).append(row)
-    for child, parent in (("engine.step.sync", "engine.step"),
-                          ("engine.step.book", "engine.step"),
-                          ("engine.prefill_chunk.sync",
-                           "engine.prefill_chunk"),
-                          ("engine.step", "engine.iter"),
-                          ("engine.admit", "engine.iter"),
-                          ("engine.record", "engine.iter")):
-        assert all(_inside(c, by_name[parent]) for c in by_name[child]), \
-            (child, parent)
-    # the loop thread is in a wait or in an iteration, never in both
+    # every phase of a pass is a child of engine.iter; the loop thread
+    # is in a wait or in an iteration, never in both
+    assert s(*_PASS_ORDER) <= s("engine.iter")
+    for child in _PASS_ORDER:
+        assert all(_inside(c, by_name["engine.iter"])
+                   for c in by_name[child]), child
     assert not any(_inside(w, by_name["engine.iter"])
                    for w in by_name["engine.wait"])
+    # inside a pass they follow one another in the documented order and
+    # none overlaps another: so each .sync holds only a wait, and the
+    # chunk is dispatched between the step's dispatch and its sync
+    behind = 0
+    for it in by_name["engine.iter"]:
+        kids = sorted((r for name in _PASS_ORDER for r in by_name[name]
+                       if _inside(r, [it])), key=lambda r: r[3])
+        for a, b in zip(kids, kids[1:]):
+            assert a[3] + a[4] <= b[3], (a[2], b[2])
+        names = [r[2][len(P):] for r in kids]
+        assert names == [name for name in _PASS_ORDER
+                         for _ in range(names.count(name))], names
+        behind += {"engine.step", "engine.prefill_chunk"} <= set(names)
+    assert behind == sum(r["chunks_behind_step"] for r in records) > 0
+    assert behind == engine.stats()["chunks_behind_step"]
 
 
 def _engine_spans(trace_dir):
@@ -205,16 +222,16 @@ def test_block_starved_pass_is_a_wait_and_no_iteration(mv_session,
 
 # -- the reduction books an idle gap to the engine's phase --------------------
 
-@pytest.mark.parametrize("wait_ms,step_head_goes_to", [
+@pytest.mark.parametrize("wait_ms,first_token_goes_to", [
     # one long wait on the harness's thread: every gap goes to a phase
-    (100, "engine.step"),
+    (100, "engine.iter"),
     # the rule's limit, as read on the v5e (PERF.md section 5): the
-    # serving driver waits in slices of 50 ms, shorter than engine.step,
+    # serving driver waits in slices of 50 ms, shorter than engine.iter,
     # and a gap goes to the SHORTEST span over its middle
     (50, "wait_reply"),
 ])
 def test_idle_gaps_go_to_the_engine_phase_not_the_harness_wait(
-        wait_ms, step_head_goes_to):
+        wait_ms, first_token_goes_to):
     ms = 1e6
     dev, host = "/device:TPU:0", "/host:CPU"
     rows = [[host, "main", P + "window", 0.0, 100 * ms]]
@@ -222,26 +239,28 @@ def test_idle_gaps_go_to_the_engine_phase_not_the_harness_wait(
     rows += [[host, "main", P + "wait_reply", at * ms, wait_ms * ms]
              for at in range(0, 100, wait_ms)]
     rows += [
-        # device: a chunk 10..30, a step 34..80; idle 0..10, 30..34, 80..100
+        # device: a step 10..30, the chunk queued behind it 34..70; idle
+        # 0..10, 30..34, 70..100
         [dev, "XLA Ops", "%fusion.1 = f32[8] fusion()", 10 * ms, 20 * ms],
-        [dev, "XLA Ops", "%fusion.2 = f32[8] fusion()", 34 * ms, 46 * ms],
-        # the engine's loop thread
+        [dev, "XLA Ops", "%fusion.2 = f32[8] fusion()", 34 * ms, 36 * ms],
+        # the engine's loop thread, one pass in the loop's order
         [host, "loop", P + "engine.iter", 2 * ms, 96 * ms],
-        [host, "loop", P + "engine.admit", 2 * ms, 6 * ms],
-        [host, "loop", P + "engine.prefill_chunk", 8 * ms, 23 * ms],
-        [host, "loop", P + "engine.prefill_chunk.sync", 9 * ms, 21 * ms],
-        [host, "loop", P + "engine.step", 31 * ms, 65 * ms],
-        [host, "loop", P + "engine.step.sync", 35 * ms, 45 * ms],
-        [host, "loop", P + "engine.step.book", 80 * ms, 16 * ms],
+        [host, "loop", P + "engine.step", 2 * ms, 7 * ms],
+        [host, "loop", P + "engine.admit", 9 * ms, 1 * ms],
+        [host, "loop", P + "engine.prefill_chunk", 10 * ms, 2 * ms],
+        [host, "loop", P + "engine.step.sync", 12 * ms, 18 * ms],
+        [host, "loop", P + "engine.step.book", 30 * ms, 6 * ms],
+        [host, "loop", P + "engine.prefill_chunk.sync", 36 * ms, 34 * ms],
         [host, "loop", P + "engine.record", 96 * ms, 2 * ms],
     ]
     r = tracered.reduce_events(rows, 0.0)
     gaps = dict(r["breakdown"]["idle_gaps"])
-    # 0..10 has its middle in the admit; 30..34 in the step's head,
-    # before its sync (the launch); 80..100 in the booking
-    assert gaps == pytest.approx({P + "engine.admit": 0.010,
-                                  P + step_head_goes_to: 0.004,
-                                  P + "engine.step.book": 0.020})
+    # 0..10 has its middle in the step's launch; 30..34 in the booking;
+    # 70..100 after the chunk's sync, in the first token's booking,
+    # which is the iteration's own time
+    assert gaps == pytest.approx({P + "engine.step": 0.010,
+                                  P + "engine.step.book": 0.004,
+                                  P + first_token_goes_to: 0.030})
     assert sum(gaps.values()) == pytest.approx(r["window_s"] - r["busy_s"])
 
 

@@ -22,12 +22,12 @@ def _rec(it, ts, busy=1.0, step=0.5, live=1, reserved=0, queue=0,
          pool_shared=-1, version=0, admitted=(), completed=(),
          spec_proposed=-1, spec_accepted=-1, kv_quant=-1,
          quant_scale_blocks=-1, kv_block_s=-1.0, tenants_live=-1,
-         sp_chunks=-1, kv_live_block_share=-1.0):
+         sp_chunks=-1, kv_live_block_share=-1.0, chunks_behind_step=0):
     return (it, ts, busy, step, live, reserved, queue, queue_age,
             prefill, decode, pool_free, pool_live, pool_shared, version,
             admitted, completed, spec_proposed, spec_accepted, kv_quant,
             quant_scale_blocks, kv_block_s, tenants_live, sp_chunks,
-            kv_live_block_share)
+            kv_live_block_share, chunks_behind_step)
 
 
 # -- ring ---------------------------------------------------------------------
@@ -226,7 +226,7 @@ def test_kv_live_block_share_column_and_older_tuple_tolerance():
     records the share of its ``slots x M`` table entries the pass's step
     had to read, -1 where no step ran, and a
     23-field tuple from before the column still reads cleanly."""
-    assert FIELDS[-1] == "kv_live_block_share"
+    assert FIELDS[23] == "kv_live_block_share"
     fr = FlightRecorder(capacity=8, name="eng")
     fr.record(_rec(1, time.monotonic(), kv_live_block_share=0.15))
     assert fr.records()[0]["kv_live_block_share"] == 0.15
@@ -236,6 +236,25 @@ def test_kv_live_block_share_column_and_older_tuple_tolerance():
     older.record(_rec(1, time.monotonic(), sp_chunks=2)[:23])
     recs = older.records()
     assert "kv_live_block_share" not in recs[0] and recs[0]["sp_chunks"] == 2
+    assert older.summary()["iterations"] == 1
+    older.chrome_counter_events()
+
+
+def test_chunks_behind_step_column_and_older_tuple_tolerance():
+    """The overlap flag rides the END of FIELDS: 1 where a pass's
+    prefill chunk was dispatched while its step was in flight; a
+    24-field tuple from before the column still reads cleanly."""
+    assert FIELDS[-1] == "chunks_behind_step"
+    fr = FlightRecorder(capacity=8, name="eng")
+    fr.record(_rec(1, time.monotonic(), prefill=4, chunks_behind_step=1))
+    assert fr.records()[0]["chunks_behind_step"] == 1
+    assert fr.summary()["iterations"] == 1
+
+    older = FlightRecorder(capacity=8, name="old")
+    older.record(_rec(1, time.monotonic(), kv_live_block_share=0.25)[:24])
+    recs = older.records()
+    assert "chunks_behind_step" not in recs[0]
+    assert recs[0]["kv_live_block_share"] == 0.25
     assert older.summary()["iterations"] == 1
     older.chrome_counter_events()
 
