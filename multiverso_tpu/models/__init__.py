@@ -1,8 +1,10 @@
 """Model families: word2vec (skip-gram/CBOW), logistic regression/FTRL,
 the transformer LM parallelism showcase, and the serve-only LongCat-Flash
-share; :func:`from_config` builds the LMs from a configuration dict."""
+and DeepSeek-V3-architecture shares; :func:`from_config` builds the LMs
+from a configuration dict."""
 
-from . import longcat
+from . import deepseek_v3, longcat
+from .deepseek_v3 import DeepSeekV3Config, DeepSeekV3LM
 from .logreg import FTRLLogReg, LogReg, LogRegConfig, SparseLogReg
 from .longcat import LongCatConfig, LongCatLM
 from .transformer import TransformerConfig, TransformerLM
@@ -27,16 +29,20 @@ def from_config(cfg: dict, seed: int, **overrides):
             dtype=jnp.dtype(cfg["dtype"]),
             learning_rate=cfg["learning_rate"], momentum=cfg["momentum"],
             seed=int(seed), **overrides))
-    if kind == "longcat_flash":
+    if kind in ("longcat_flash", "deepseek_v3"):
         if overrides:
             raise TypeError(f"from_config: a {kind!r} model takes no "
                             f"overrides, got {sorted(overrides)}")
-        return LongCatLM(longcat.config_from_dict(cfg, seed))
+        if kind == "longcat_flash":
+            return LongCatLM(longcat.config_from_dict(cfg, seed))
+        return DeepSeekV3LM(deepseek_v3.config_from_dict(cfg, seed))
     raise ValueError(f"from_config: no model of kind {kind!r}")
 
 
 __all__ = [
     "from_config",
+    "DeepSeekV3Config",
+    "DeepSeekV3LM",
     "LongCatConfig",
     "LongCatLM",
     "FTRLLogReg",

@@ -34,6 +34,18 @@ LATENT: ``W_kvb``'s key half is absorbed into the query
 ``heads x (192 + 128)``. :func:`mla_expanded` and :func:`mla_latent` are
 the two forms; a test holds them equal.
 
+**One copy for two models.** ``models/deepseek_v3.py`` (PR 32) serves
+over the same latent pool with this module's MLA, norm, rotary, pool
+view, per-sublayer attention against the pool (:func:`step_attend`,
+:func:`chunk_attend`), copy-on-write and seam builder
+(:func:`latent_pool_programs`). They take what differs between the two
+from the configuration they are handed: ``mla_scale_q_lora`` /
+``mla_scale_kv_lora``, ``rope_scaling`` (:func:`rope_frequencies`) and
+``softmax_divisor``. They stay HERE, under these names, because the
+benchmark's accepted tests plant their faults by these names
+(``benchmarks/tests/test_longcat_cell.py`` patches ``longcat.rope``,
+``route_topk``, ``held_expert_layer``, ``config_from_dict``).
+
 Weights are drawn ON THE DEVICE, leaf by leaf, from one law
 (:func:`init_params`); the model is serve-only, its weights never move,
 so its snapshot is the weights themselves (no copy: 10 GB cannot exist
@@ -59,8 +71,27 @@ from ..ops.moe import COUNT_SCALARS, held_expert_layer, route_topk, swiglu
 _NEG_INF = -1e30
 
 
+class LatentCacheSizes:
+    """What a latent pool's shape follows from, for any configuration
+    with ``kv_lora_rank``, ``qk_rope_head_dim`` and
+    ``max_position_embeddings``."""
+
+    @property
+    def max_seq(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_width(self) -> int:
+        """A cache row as the pool holds it: whole 128-lane tiles."""
+        return -(-self.cache_width // 128) * 128
+
+
 @dataclasses.dataclass(frozen=True)
-class LongCatConfig:
+class LongCatConfig(LatentCacheSizes):
     vocab_size: int = 131072           # rows held here (a slice)
     hidden_size: int = 6144
     ffn_hidden_size: int = 12288
@@ -87,19 +118,6 @@ class LongCatConfig:
     seed: int = 0
 
     @property
-    def max_seq(self) -> int:
-        return self.max_position_embeddings
-
-    @property
-    def cache_width(self) -> int:
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
-    def pool_width(self) -> int:
-        """A cache row as the pool holds it: whole 128-lane tiles."""
-        return -(-self.cache_width // 128) * 128
-
-    @property
     def n_sublayers(self) -> int:
         return 2 * self.num_layers
 
@@ -107,18 +125,32 @@ class LongCatConfig:
     def router_outputs(self) -> int:
         return self.total_routed_experts + self.zero_expert_num
 
+    # what the MLA code below takes from whichever configuration it is
+    # handed (``models/deepseek_v3.py`` hands it another): the two
+    # ``mla_scale_*`` switches above, the rotary frequencies' scaling
+    # (none here) and what the attention scores are divided by
+    rope_scaling = None
 
-def config_from_dict(cfg: dict, seed: int) -> LongCatConfig:
-    """The configuration file's dict (HF key names; ``n_routed_experts``
-    is what is held here, ``published.n_routed_experts`` what the router
-    addresses)."""
-    names = {f.name for f in dataclasses.fields(LongCatConfig)}
+    @property
+    def softmax_divisor(self) -> float:
+        return math.sqrt(self.qk_nope_head_dim + self.qk_rope_head_dim)
+
+
+def share_config(cls, cfg: dict, seed: int):
+    """``cls`` from a configuration file's dict (HF key names;
+    ``n_routed_experts`` is what is held here,
+    ``published.n_routed_experts`` what the router addresses)."""
+    names = {f.name for f in dataclasses.fields(cls)}
     kw = {k: v for k, v in cfg.items() if k in names and k != "dtype"}
     kw["total_routed_experts"] = int(
         cfg.get("published", {}).get("n_routed_experts",
                                      cfg["n_routed_experts"]))
-    return LongCatConfig(dtype=jnp.dtype(cfg.get("dtype", "bfloat16")),
-                         seed=int(seed), **kw)
+    return cls(dtype=jnp.dtype(cfg.get("dtype", "bfloat16")),
+               seed=int(seed), **kw)
+
+
+def config_from_dict(cfg: dict, seed: int) -> LongCatConfig:
+    return share_config(LongCatConfig, cfg, seed)
 
 
 # -- the weight law -----------------------------------------------------------
@@ -146,13 +178,31 @@ def _draw(key, shape, std: float, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
+def draw_mla(cfg, draw, key, qb_gain: float, wo_gain: float = 1.0):
+    """One MLA sublayer's leaves: ``draw(key, shape, std, dtype)`` with
+    ``key(leaf)`` the leaf's key; std ``1/sqrt(fan_in)`` times the two
+    gains a model's law sets."""
+    D, H, rq, rkv = cfg.hidden_size, cfg.num_attention_heads, \
+        cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = cfg.dtype
+    ones = lambda n: jnp.ones((n,), jnp.float32)
+    return {
+        "norm": ones(D), "q_norm": ones(rq), "kv_norm": ones(rkv),
+        "w_qa": draw(key("w_qa"), (D, rq), D ** -0.5, dt),
+        "w_qb": draw(key("w_qb"), (rq, H * (dn + dr)),
+                     qb_gain * rq ** -0.5, dt),
+        "w_kva": draw(key("w_kva"), (D, rkv + dr), D ** -0.5, dt),
+        "w_kvb": draw(key("w_kvb"), (rkv, H * (dn + dv)), rkv ** -0.5, dt),
+        "w_o": draw(key("w_o"), (H * dv, D), wo_gain * (H * dv) ** -0.5,
+                    dt)}
+
+
 def init_params(cfg: LongCatConfig) -> Dict[str, Any]:
     """The share's weights, each leaf one jitted draw on the default
     device (never the whole tree at once, never on the host)."""
     D, F, Fe = cfg.hidden_size, cfg.ffn_hidden_size, \
         cfg.expert_ffn_hidden_size
-    H, rq, rkv = cfg.num_attention_heads, cfg.q_lora_rank, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     dt = cfg.dtype
     draw = jax.jit(_draw, static_argnums=(1, 2, 3))
     draw_experts = jax.jit(
@@ -162,15 +212,9 @@ def init_params(cfg: LongCatConfig) -> Dict[str, Any]:
     ones = lambda n: jnp.ones((n,), jnp.float32)
 
     def mla(b, j):
-        k = lambda leaf: _leaf_key(cfg.seed, b, leaf, j)
-        return {
-            "norm": ones(D), "q_norm": ones(rq), "kv_norm": ones(rkv),
-            "w_qa": draw(k("w_qa"), (D, rq), D ** -0.5, dt),
-            "w_qb": draw(k("w_qb"), (rq, H * (dn + dr)),
-                         _QB_GAIN * rq ** -0.5, dt),
-            "w_kva": draw(k("w_kva"), (D, rkv + dr), D ** -0.5, dt),
-            "w_kvb": draw(k("w_kvb"), (rkv, H * (dn + dv)), rkv ** -0.5, dt),
-            "w_o": draw(k("w_o"), (H * dv, D), (H * dv) ** -0.5, dt)}
+        return draw_mla(cfg, draw,
+                        lambda leaf: _leaf_key(cfg.seed, b, leaf, j),
+                        _QB_GAIN)
 
     def ffn(b, j):
         k = lambda leaf: _leaf_key(cfg.seed, b, leaf, j)
@@ -217,12 +261,33 @@ def rmsnorm(x, g, eps: float, dtype):
     return y.astype(dtype)
 
 
-def rope(x, pos, theta: float):
-    """Rotate interleaved pairs ``(x[2i], x[2i+1])`` of the last axis by
-    ``pos * theta^(-2i/d)`` (DeepSeek-V3's pairing). ``x`` [T, ..., d],
-    ``pos`` [T]; float32 in and out."""
-    d = x.shape[-1]
+def rope_frequencies(cfg):
+    """The rotary slice's ``d/2`` angular frequencies, ``theta^(-2i/d)``;
+    under ``cfg.rope_scaling`` (YaRN, as DeepSeek-V3 publishes it) pair
+    ``i`` is slowed by ``factor`` to the degree ``r_i`` that it turns
+    fewer than ``beta_fast`` (r = 0) down to ``beta_slow`` (r = 1) times
+    over the original context: ``f_i = e_i (1 - r_i) + e_i / factor
+    r_i``."""
+    d, theta = cfg.qk_rope_head_dim, cfg.rope_theta
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if cfg.rope_scaling is None:
+        return inv
+    y = cfg.rope_scaling
+    turns_at = lambda n: d * math.log(
+        y["original_max_position_embeddings"] / (n * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    low = max(math.floor(turns_at(y["beta_fast"])), 0)
+    high = min(math.ceil(turns_at(y["beta_slow"])), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inv * (1.0 - ramp) + inv / y["factor"] * ramp
+
+
+def rope(x, pos, inv):
+    """Rotate interleaved pairs ``(x[2i], x[2i+1])`` of the last axis by
+    ``pos * inv[i]`` (DeepSeek-V3's pairing; ``inv`` from
+    :func:`rope_frequencies`). ``x`` [T, ..., d], ``pos`` [T]; float32
+    in and out."""
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]       # [T, d/2]
     ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -248,10 +313,10 @@ def mla_project(cfg: LongCatConfig, w, x, pos, width: int = 0):
                   w["q_norm"] * sq, cfg.rms_norm_eps, dt)
     q = jnp.dot(c_q, w["w_qb"], preferred_element_type=f32).reshape(
         T, H, dn + dr)
-    q_rope = rope(q[..., dn:], pos, cfg.rope_theta).astype(dt)
+    q_rope = rope(q[..., dn:], pos, rope_frequencies(cfg)).astype(dt)
     kv = jnp.dot(x, w["w_kva"], preferred_element_type=f32)
     c = rmsnorm(kv[:, :rkv], w["kv_norm"] * skv, cfg.rms_norm_eps, dt)
-    k_rope = rope(kv[:, rkv:], pos, cfg.rope_theta).astype(dt)
+    k_rope = rope(kv[:, rkv:], pos, rope_frequencies(cfg)).astype(dt)
     pad = [jnp.zeros((T, width - rkv - dr), dt)] if width > rkv + dr else []
     return (q[..., :dn].astype(dt), q_rope,
             jnp.concatenate([c, k_rope] + pad, -1))
@@ -270,7 +335,6 @@ def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask):
     the sublayer's output [C, D] in float32."""
     f32, dt = jnp.float32, cfg.dtype
     rkv = cfg.kv_lora_rank
-    dq = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     wk, wv = _kvb(cfg, w)
     c, k_rope = rows[:, :rkv], rows[:, rkv:cfg.cache_width]
     k_nope = jnp.einsum("tc,chd->thd", c, wk,
@@ -280,7 +344,7 @@ def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask):
     s = (jnp.einsum("qhd,thd->hqt", q_nope, k_nope,
                     preferred_element_type=f32)
          + jnp.einsum("qhr,tr->hqt", q_rope, k_rope,
-                      preferred_element_type=f32)) / math.sqrt(dq)
+                      preferred_element_type=f32)) / cfg.softmax_divisor
     p = jax.nn.softmax(jnp.where(mask[None], s, _NEG_INF), axis=-1)
     o = jnp.einsum("hqt,thd->qhd", p.astype(dt), v,
                    preferred_element_type=f32).astype(dt)
@@ -321,10 +385,9 @@ def mla_latent(cfg: LongCatConfig, w, q_nope, q_rope, view, pos):
     the query and the value half into the output, so both products run
     over the cache rows. Returns [S, D] float32."""
     f32 = jnp.float32
-    dq = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     q_cat = latent_query(cfg, w, q_nope, q_rope, view.shape[-1])
     s = jnp.einsum("shc,stc->sht", q_cat, view,
-                   preferred_element_type=f32) / math.sqrt(dq)
+                   preferred_element_type=f32) / cfg.softmax_divisor
     live = jnp.arange(view.shape[1])[None, :] <= pos[:, None]
     p = jax.nn.softmax(jnp.where(live[:, None, :], s, _NEG_INF), axis=-1)
     o_lat = jnp.einsum("sht,stc->shc", p.astype(cfg.dtype),
@@ -385,6 +448,11 @@ def forward(cfg: LongCatConfig, params, tokens) -> jax.Array:
 
 
 # -- paged programs ---------------------------------------------------------------
+# What every model with a latent pool shares (this one and
+# ``models/deepseek_v3.py``): where a step and a chunk write, one MLA
+# sublayer of each against the pool, the copy-on-write, and the builder
+# of the engine's seam. A model's own programs are the walk over its
+# layers.
 def _view(pool, sub: int, tables, t: int):
     """Sublayer ``sub``'s blocks named by ``tables`` ([S, M] or [M]),
     gathered from the pool seen as ``[subs * N, Bs, W]`` (the sublayer
@@ -396,53 +464,116 @@ def _view(pool, sub: int, tables, t: int):
     return rows[..., :t, :]
 
 
+def step_rows(pool, block_tables, pos, active):
+    """Where a one-token step writes and what it attends over: ``(block
+    [S], offset [S], lengths [S])``. A live slot writes its row at
+    ``(block_tables[s, pos // Bs], pos % Bs)``, a dead lane parks its in
+    scratch block 0 and attends over nothing."""
+    Bs = pool.shape[2]
+    blk_ix = jnp.take_along_axis(block_tables, (pos // Bs)[:, None],
+                                 axis=1)[:, 0]
+    return (jnp.where(active, blk_ix, 0), jnp.where(active, pos % Bs, 0),
+            jnp.where(active, pos + 1, 0))
+
+
+def step_attend(cfg, w, x, pool, sub: int, block_tables, pos, rows,
+                t_logical: int, paged_attention=None):
+    """MLA sublayer ``sub`` of a one-token step on the normed ``x``
+    [S, D]: each slot's cache row written at ``rows``
+    (:func:`step_rows`), then attention IN THE LATENT. Returns ``(pool,
+    out [S, D] float32)``. ``paged_attention``
+    (``ops.paged_attention.paged_mq_attention``, where a model's
+    ``serving_programs`` finds it applies) reads each slot's live blocks
+    out of the pool in place of the gathered view: the same latent
+    query, keys the whole rows, values their first ``rkv`` columns."""
+    n_sub, N, Bs, W = pool.shape
+    write_blk, write_off, lengths = rows
+    q_nope, q_rope, row = mla_project(cfg, w, x, pos, W)
+    pool = pool.at[sub, write_blk, write_off].set(row)
+    if paged_attention is not None:
+        o_lat = paged_attention(
+            latent_query(cfg, w, q_nope, q_rope, W),
+            pool.reshape(n_sub * N, Bs, W), None,
+            sub * N + block_tables, lengths,
+            scale=1.0 / cfg.softmax_divisor, wv=cfg.kv_lora_rank)
+        return pool, latent_output(cfg, w, o_lat)
+    # the barrier holds the view as ONE array between its two readers
+    # (scores, values): with weights and pool filling the chip the TPU
+    # compiler otherwise rematerializes the gather, once a reader (14
+    # gathers of 377 MB a step for 8)
+    view = jax.lax.optimization_barrier(
+        _view(pool, sub, block_tables, t_logical))
+    return pool, mla_latent(cfg, w, q_nope, q_rope, view, pos)
+
+
+def chunk_rows(pool, block_tables, slot, chunk: int, offset, length,
+               t_logical: int):
+    """Where a prefill chunk of ONE slot writes and what it sees:
+    ``(bt_row [M], pos_ix [C], valid [C], block [C], offset [C], mask
+    [C, T])``; ``slot``/``offset``/``length`` traced, pad rows routed to
+    scratch."""
+    Bs = pool.shape[2]
+    M = block_tables.shape[1]
+    bt_row = jax.lax.dynamic_index_in_dim(block_tables, slot, 0,
+                                          keepdims=False)
+    pos_ix = offset + jnp.arange(chunk)
+    valid = jnp.arange(chunk) < length
+    blk_ix = jnp.where(
+        valid, jnp.take(bt_row, jnp.clip(pos_ix // Bs, 0, M - 1)), 0)
+    off = jnp.where(valid, pos_ix % Bs, 0)
+    mask = jnp.arange(t_logical)[None, :] <= pos_ix[:, None]
+    return bt_row, pos_ix, valid, blk_ix, off, mask
+
+
+def chunk_attend(cfg, w, x, pool, sub: int, rows, t_logical: int):
+    """MLA sublayer ``sub`` of a prefill chunk on the normed ``x``
+    [C, D]: the chunk's cache rows written at ``rows``
+    (:func:`chunk_rows`), then its queries attend the slot's rows
+    EXPANDED to per-head keys and values. Returns ``(pool, out [C, D]
+    float32)``."""
+    bt_row, pos_ix, _, blk_ix, off, mask = rows
+    q_nope, q_rope, new = mla_project(cfg, w, x, pos_ix, pool.shape[-1])
+    pool = pool.at[sub, blk_ix, off].set(new)
+    view = _view(pool, sub, bt_row, t_logical)
+    return pool, mla_expanded(cfg, w, q_nope, q_rope, view, mask)
+
+
+def greedy_next(cfg, params, h, tok, pos, active):
+    """A step's tail: ``(next_tok, pos)``, the greedy choice over the
+    vocabulary held here, dead lanes at token 0 and where they were."""
+    nxt = jnp.argmax(_logits(cfg, params, h), axis=-1).astype(tok.dtype)
+    nxt = jnp.where(active, nxt, jnp.zeros_like(nxt))
+    return nxt, jnp.where(active, pos + 1, pos)
+
+
+def last_logits(cfg, params, h, length):
+    """A chunk's tail: the logits [V] of its last valid row."""
+    last = jnp.take(h, length - 1, axis=0)
+    return _logits(cfg, params, last[None])[0]
+
+
 def decode_step_paged(cfg: LongCatConfig, params, pool, counters,
                       block_tables, tok, pos, active, t_logical: int,
                       paged_attention=None):
     """One fused token step over S slots against the paged latent pool
-    ``[subs, N + 1, Bs, pool_width]`` (block 0 = scratch). The engine's
-    contract (``models.transformer.decode_step_paged``): a live slot
-    writes its row at ``(block_tables[s, pos // Bs], pos % Bs)``, dead
-    lanes park theirs in scratch. ``counters`` accumulates the routing
-    counts of live slots. ``paged_attention``
-    (``ops.paged_attention.paged_mq_attention``, where
-    ``LongCatLM.serving_programs`` finds it applies) reads each slot's
-    live blocks out of the pool in place of the gathered view: the same
-    latent query, keys the whole rows, values their first ``rkv``
-    columns. Returns ``(pool, counters, next_tok, pos)``."""
-    n_sub, N, Bs, W = pool.shape
-    blk_ix = jnp.take_along_axis(block_tables, (pos // Bs)[:, None],
-                                 axis=1)[:, 0]
-    write_blk = jnp.where(active, blk_ix, 0)
-    write_off = jnp.where(active, pos % Bs, 0)
-    lengths = jnp.where(active, pos + 1, 0)
-    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    ``[subs, N + 1, Bs, pool_width]`` (block 0 = scratch), by the
+    engine's contract (``models.transformer.decode_step_paged``;
+    :func:`step_rows`, :func:`step_attend`). ``counters`` accumulates
+    the routing counts of live slots. Returns ``(pool, counters,
+    next_tok, pos)``."""
+    rows = step_rows(pool, block_tables, pos, active)
     h = jnp.take(params["embed"], tok, axis=0).astype(jnp.float32)
     for b, blk in enumerate(params["blocks"]):
         def attend(j, w, x, b=b):
             nonlocal pool
-            q_nope, q_rope, row = mla_project(cfg, w, x, pos, W)
-            pool = pool.at[2 * b + j, write_blk, write_off].set(row)
-            if paged_attention is not None:
-                o_lat = paged_attention(
-                    latent_query(cfg, w, q_nope, q_rope, W),
-                    pool.reshape(n_sub * N, Bs, W), None,
-                    (2 * b + j) * N + block_tables, lengths, scale=scale,
-                    wv=cfg.kv_lora_rank)
-                return latent_output(cfg, w, o_lat)
-            # the barrier holds the view as ONE array between its two
-            # readers (scores, values): with weights and pool filling
-            # the chip the TPU compiler otherwise rematerializes the
-            # gather, once a reader (14 gathers of 377 MB a step for 8)
-            view = jax.lax.optimization_barrier(
-                _view(pool, 2 * b + j, block_tables, t_logical))
-            return mla_latent(cfg, w, q_nope, q_rope, view, pos)
+            pool, out = step_attend(cfg, w, x, pool, 2 * b + j,
+                                    block_tables, pos, rows, t_logical,
+                                    paged_attention)
+            return out
 
         h, counts = block_apply(cfg, blk, h, attend, valid=active)
         counters = counters.at[b].add(counts)
-    nxt = jnp.argmax(_logits(cfg, params, h), axis=-1).astype(tok.dtype)
-    nxt = jnp.where(active, nxt, jnp.zeros_like(nxt))
-    return pool, counters, nxt, jnp.where(active, pos + 1, pos)
+    return (pool, counters) + greedy_next(cfg, params, h, tok, pos, active)
 
 
 def prefill_chunk_paged(cfg: LongCatConfig, params, pool, counters,
@@ -450,40 +581,83 @@ def prefill_chunk_paged(cfg: LongCatConfig, params, pool, counters,
                         t_logical: int):
     """Incremental prefill of one fixed-size chunk of ONE slot into the
     paged latent pool (``models.transformer.prefill_chunk_paged``'s
-    contract: ``slot``/``offset``/``length`` traced, pad rows routed to
-    scratch). The chunk's queries attend the slot's rows expanded to
-    per-head keys and values. Returns ``(pool, counters, last_logits
-    [V])``."""
-    C = tokens.shape[0]
-    Bs = pool.shape[2]
-    M = block_tables.shape[1]
-    bt_row = jax.lax.dynamic_index_in_dim(block_tables, slot, 0,
-                                          keepdims=False)
-    pos_ix = offset + jnp.arange(C)
-    valid = jnp.arange(C) < length
-    blk_ix = jnp.where(
-        valid, jnp.take(bt_row, jnp.clip(pos_ix // Bs, 0, M - 1)), 0)
-    off = jnp.where(valid, pos_ix % Bs, 0)
-    mask = jnp.arange(t_logical)[None, :] <= pos_ix[:, None]
+    contract; :func:`chunk_rows`, :func:`chunk_attend`). Returns
+    ``(pool, counters, last_logits [V])``."""
+    rows = chunk_rows(pool, block_tables, slot, tokens.shape[0], offset,
+                      length, t_logical)
     h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
     for b, blk in enumerate(params["blocks"]):
         def attend(j, w, x, b=b):
             nonlocal pool
-            q_nope, q_rope, rows = mla_project(cfg, w, x, pos_ix,
-                                               pool.shape[-1])
-            pool = pool.at[2 * b + j, blk_ix, off].set(rows)
-            view = _view(pool, 2 * b + j, bt_row, t_logical)
-            return mla_expanded(cfg, w, q_nope, q_rope, view, mask)
+            pool, out = chunk_attend(cfg, w, x, pool, 2 * b + j, rows,
+                                     t_logical)
+            return out
 
-        h, counts = block_apply(cfg, blk, h, attend, valid=valid)
+        h, counts = block_apply(cfg, blk, h, attend, valid=rows[2])
         counters = counters.at[b].add(counts)
-    last = jnp.take(h, length - 1, axis=0)
-    return pool, counters, _logits(cfg, params, last[None])[0]
+    return pool, counters, last_logits(cfg, params, h, length)
 
 
 def cow_block_copy(pool, counters, src, dst):
     """Copy-on-write of one block of the latent pool (every sublayer)."""
     return pool.at[:, dst].set(pool[:, src]), counters
+
+
+def latent_pool_programs(cfg, spec, model: str, step, chunk,
+                         counter_shape: tuple, counters):
+    """The engine's seam (``serving/programs.py``) for a model whose
+    cache is ONE latent pool ``[cfg.n_sublayers, N + 1, Bs,
+    cfg.pool_width]`` beside one small counters array: ``step`` and
+    ``chunk`` (the model's :func:`decode_step_paged` /
+    :func:`prefill_chunk_paged`) jitted over it with the pool donated,
+    and the copy-on-write. The programs' names in a profile are
+    ``jit_<model>_decode_step``, ``jit_<model>_prefill_chunk`` and
+    ``jit_<model>_cow_block``. What such a model lacks is refused here,
+    by name, never run wrong."""
+    from ..serving.programs import ServingPrograms, refuse
+
+    who = f"DecodeEngine {spec.name!r} ({model})"
+    refuse(who, spec, kv_quant="no int8 latent pool",
+           param_quant="no int8 parameter pin", decode_tp="no "
+           "tensor-parallel decode programs", spec_k="no verify step",
+           prefill_sp="no sequence-parallel prefill")
+    if spec.cache_len > cfg.max_seq:
+        Log.fatal(f"{who}: max_prompt + max_new {spec.cache_len} "
+                  f"exceeds max_position_embeddings {cfg.max_seq}")
+    T = spec.cache_len
+    donate = (1,) if spec.donate else ()
+    # the one-token step reads the live blocks in place where a
+    # block is whole tiles on a TPU; the chunk keeps the view
+    attend = paged_kernel.step_attention(cfg.dtype, spec.block_size,
+                                         cfg.pool_width)
+
+    def decode_step(params, pool, counters, bt, tok, pos, active):
+        return step(cfg, params, pool, counters, bt, tok, pos, active, T,
+                    paged_attention=attend)
+
+    def prefill_chunk(params, pool, counters, bt, slot, toks, off, n):
+        return chunk(cfg, params, pool, counters, bt, slot, toks, off, n, T)
+
+    def cow_block(pool, counters, src, dst):
+        return cow_block_copy(pool, counters, src, dst)
+
+    # a function's name is its program's in a profile (jit_<name>)
+    for fn in (decode_step, prefill_chunk, cow_block):
+        fn.__name__ = fn.__qualname__ = f"{model}_{fn.__name__}"
+    pool_shape = (cfg.n_sublayers, spec.pool_blocks + 1, spec.block_size,
+                  cfg.pool_width)
+    return ServingPrograms(
+        pools=((pool_shape, jnp.dtype(cfg.dtype)),
+               (counter_shape, jnp.dtype(jnp.float32))),
+        bytes_per_block=(cfg.n_sublayers * spec.block_size * cfg.pool_width
+                         * jnp.dtype(cfg.dtype).itemsize),
+        step=jax.jit(decode_step, donate_argnums=donate),
+        chunk=jax.jit(prefill_chunk, donate_argnums=donate),
+        cow=(jax.jit(cow_block, donate_argnums=(0,) if spec.donate else ())
+             if spec.prefix else None),
+        # pin: the default, the snapshot itself (a serve-only model's
+        # weights never move and nothing donates them)
+        counter_pool=1, counters=counters)
 
 
 def routing_summary(cfg: LongCatConfig, counts: np.ndarray) -> dict:
@@ -532,57 +706,12 @@ class LongCatLM:
                        jnp.asarray(tokens, jnp.int32))
 
     def serving_programs(self, spec):
-        """The engine's seam (``serving/programs.py``): one latent pool
-        and one small counters array, the decode step (latent form), the
-        prefill chunk (expanded form) and the copy-on-write. What this
-        model lacks is refused here, by name, never run wrong."""
-        from ..serving.programs import ServingPrograms, refuse
-
+        """The engine's seam: one latent pool and one small counters
+        array, the decode step (latent form), the prefill chunk
+        (expanded form) and the copy-on-write
+        (:func:`latent_pool_programs`)."""
         cfg = self.config
-        who = f"DecodeEngine {spec.name!r} (LongCat)"
-        refuse(who, spec, kv_quant="no int8 latent pool",
-               param_quant="no int8 parameter pin", decode_tp="no "
-               "tensor-parallel decode programs", spec_k="no verify step",
-               prefill_sp="no sequence-parallel prefill")
-        if spec.cache_len > cfg.max_seq:
-            Log.fatal(f"{who}: max_prompt + max_new {spec.cache_len} "
-                      f"exceeds max_position_embeddings {cfg.max_seq}")
-        T = spec.cache_len
-        donate = (1,) if spec.donate else ()
-        # the one-token step reads the live blocks in place where a
-        # block is whole tiles on a TPU; the chunk keeps the view
-        attend = paged_kernel.step_attention(cfg.dtype, spec.block_size,
-                                             cfg.pool_width)
-
-        # the functions' names are the programs' in a profile (jit_<name>)
-        def longcat_decode_step(params, pool, counters, bt, tok, pos,
-                                active):
-            return decode_step_paged(cfg, params, pool, counters, bt, tok,
-                                     pos, active, T, paged_attention=attend)
-
-        def longcat_prefill_chunk(params, pool, counters, bt, slot, toks,
-                                  off, n):
-            return prefill_chunk_paged(cfg, params, pool, counters, bt,
-                                       slot, toks, off, n, T)
-
-        def longcat_cow_block(pool, counters, src, dst):
-            return cow_block_copy(pool, counters, src, dst)
-
-        pool_shape = (cfg.n_sublayers, spec.pool_blocks + 1,
-                      spec.block_size, cfg.pool_width)
-        return ServingPrograms(
-            pools=((pool_shape, jnp.dtype(cfg.dtype)),
-                   ((cfg.num_layers, COUNT_SCALARS + cfg.n_routed_experts),
-                    jnp.dtype(jnp.float32))),
-            bytes_per_block=(cfg.n_sublayers * spec.block_size
-                             * cfg.pool_width
-                             * jnp.dtype(cfg.dtype).itemsize),
-            step=jax.jit(longcat_decode_step, donate_argnums=donate),
-            chunk=jax.jit(longcat_prefill_chunk, donate_argnums=donate),
-            cow=(jax.jit(longcat_cow_block,
-                         donate_argnums=(0,) if spec.donate else ())
-                 if spec.prefix else None),
-            # pin: the default, the snapshot itself (a serve-only model's
-            # weights never move and nothing donates them)
-            counter_pool=1,
-            counters=lambda delta: routing_summary(cfg, delta))
+        return latent_pool_programs(
+            cfg, spec, "longcat", decode_step_paged, prefill_chunk_paged,
+            (cfg.num_layers, COUNT_SCALARS + cfg.n_routed_experts),
+            lambda delta: routing_summary(cfg, delta))

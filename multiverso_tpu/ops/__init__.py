@@ -5,7 +5,8 @@ from .flash_attention import (flash_attention, flash_attention_partial,
                               merge_partials)
 from .paged_attention import paged_mq_attention
 from .moe import (EXPERT_AXIS, held_expert_layer, init_moe_params, mlp_expert,
-                  moe_apply, route_topk, swiglu, top1_gating)
+                  moe_apply, route_group_limited, route_topk, swiglu,
+                  top1_gating)
 from .ring_attention import (reference_attention, ring_attention,
                              ring_prefill_attention)
 from .ulysses import ulysses_attention, ulysses_prefill_attention
@@ -20,6 +21,7 @@ __all__ = [
     "paged_mq_attention",
     "EXPERT_AXIS",
     "held_expert_layer",
+    "route_group_limited",
     "route_topk",
     "swiglu",
     "init_moe_params",

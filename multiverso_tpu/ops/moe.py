@@ -21,11 +21,14 @@ Everything is expressed with einsums over one-hot tensors, so the layer is
 differentiable end-to-end (gate weights carry the gradient through routing).
 
 The second half of the module is the serving-side expert layer of a chip
-that holds a SHARE of a layer's experts (:func:`route_topk`,
-:func:`held_expert_layer`): top-k routing over every router output with
-no capacity and no dropped token, identity ("zero-compute") experts, and
-the part of the layer's result that the held experts give. It runs
-without an exchange: what the absent experts would add is left out.
+that holds a SHARE of a layer's experts (:func:`route_topk` or
+:func:`route_group_limited`, then :func:`held_expert_layer`): top-k
+routing over every router output with no capacity and no dropped token
+(a softmax with unnormalised gates, or sigmoid scores limited to a
+token's best groups of experts with normalised gates), identity
+("zero-compute") experts, and the part of the layer's result that the
+held experts give. It runs without an exchange: what the absent experts
+would add is left out.
 """
 
 from __future__ import annotations
@@ -183,6 +186,40 @@ def route_topk(u: jax.Array, router_w: jax.Array, router_bias: jax.Array,
     _, idx = jax.lax.top_k(p + router_bias.astype(jnp.float32), top_k)
     gates = scale * jnp.take_along_axis(p, idx, axis=-1)
     return idx.astype(jnp.int32), gates
+
+
+def route_group_limited(u: jax.Array, router_w: jax.Array,
+                        router_bias: jax.Array, top_k: int, n_group: int,
+                        topk_group: int, scale: float
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Sigmoid router with a group limit (DeepSeek-V3's ``noaux_tc``), in
+    float32.
+
+    ``s = sigmoid(float32(u) @ router_w)`` and ``b = s + router_bias``;
+    the outputs lie in ``n_group`` groups of consecutive experts; a
+    group's score is the sum of its two largest ``b``; the token keeps
+    its ``topk_group`` best groups and picks the ``top_k`` largest ``b``
+    inside them (the bias moves the choice, never the gate). A pick's
+    gate is its ``s``, divided by the picks' sum (+ 1e-20), times
+    ``scale``. Outputs outside the kept groups are
+    masked with -inf (the published code fills them with 0.0, which is
+    the same choice wherever ``top_k`` kept outputs have ``b > 0``).
+    Returns ``(idx [T, k] int32, gates [T, k] float32, kept [T, n_group]
+    bool)``. No capacity: every token keeps all of its picks."""
+    logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    T, E = s.shape
+    b = (s + router_bias.astype(jnp.float32)).reshape(T, n_group,
+                                                      E // n_group)
+    group_score = jnp.sum(jax.lax.top_k(b, 2)[0], axis=-1)      # [T, G]
+    _, best = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.any(jax.nn.one_hot(best, n_group, dtype=jnp.bool_), axis=1)
+    _, idx = jax.lax.top_k(
+        jnp.where(kept[..., None], b, -jnp.inf).reshape(T, E), top_k)
+    gates = jnp.take_along_axis(s, idx, axis=-1)
+    gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), scale * gates, kept
 
 
 def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,

@@ -405,35 +405,25 @@ def test_sharded_decode_programs_compile_on_two_chips(topo, serving_shapes):
         assert mem.argument_size_in_bytes < 2 * pool_bytes
 
 
-# -- LongCat-Flash share: the latent-pool programs at the cell's shapes ----------
-@pytest.mark.parametrize("program,temp_mb", [("step", 150), ("chunk", 850)])
-def test_longcat_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
-                                            temp_mb):
-    """``LongCatLM.serving_programs`` at the shapes of
-    ``serve_longcat_ep32_closed128`` (``benchmarks/configs/
-    longcat-flash-ep32.json`` under ``traffic/closed128_gen768.json``):
-    10.4 GB of weights and the 3.0 GB latent pool are arguments, the
-    pool is aliased (a 576-wide row made the compiler copy the pool
-    whole around every scatter: the row is padded to 640), no copy of
-    the pool and no float32 copy of the ``slots x T`` view appear, and
-    arguments plus temporaries stay inside the chip. The step takes the
+# -- the latent-pool models: both programs at their cells' shapes ----------------
+def _latent_cell_programs(one_chip, monkeypatch, module, model, config,
+                          traffic):
+    """``serving_programs`` of a latent-pool model at its cell's shapes,
+    no weights drawn: ``(cfg, t, progs, args of step, args of chunk)``
+    with every array a shape on the described chip. The step takes the
     kernel, as on the chip (``serving_programs`` asks the backend, which
-    is the CPU here: the test answers for it), so it gathers no
-    ``[128, 2304, 640]`` view: 8 gathers of 377 MB a step before."""
+    is the CPU here: the test answers for it)."""
     import json
 
     import jax
     import jax.numpy as jnp
 
-    from multiverso_tpu.models import longcat
     from multiverso_tpu.serving.programs import EngineSpec
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "configs",
-                           "longcat-flash-ep32.json")) as fh:
-        cfg = longcat.config_from_dict(json.load(fh), 1)
-    with open(os.path.join(root, "benchmarks", "traffic",
-                           "closed128_gen768.json")) as fh:
+    with open(os.path.join(root, "benchmarks", "configs", config)) as fh:
+        cfg = module.config_from_dict(json.load(fh), 1)
+    with open(os.path.join(root, "benchmarks", "traffic", traffic)) as fh:
         t = json.load(fh)
     S, Bs, C = t["slots"], 16, t["prefill_token_budget"]
     T = t["max_prompt"] + t["max_new"]
@@ -442,7 +432,7 @@ def test_longcat_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
 
     monkeypatch.setattr(sys.modules["multiverso_tpu.ops.paged_attention"],
                         "_on_tpu", lambda: True)
-    lm = object.__new__(longcat.LongCatLM)      # no weights drawn
+    lm = object.__new__(model)                  # no weights drawn
     lm.config = cfg
     progs = lm.serving_programs(EngineSpec(
         name="cell", slots=S, max_prompt=t["max_prompt"],
@@ -453,31 +443,62 @@ def test_longcat_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
     place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip)
     params = jax.tree.map(place,
-                          jax.eval_shape(lambda: longcat.init_params(cfg)))
+                          jax.eval_shape(lambda: module.init_params(cfg)))
     (pshape, pdtype), (cshape, cdtype) = progs.pools
-    assert pshape == (8, S * M + 1, Bs, 640)
+    assert pshape == (cfg.n_sublayers, S * M + 1, Bs, 640)
     pool = jax.ShapeDtypeStruct(pshape, pdtype, sharding=one_chip)
     counters = jax.ShapeDtypeStruct(cshape, cdtype, sharding=one_chip)
     bt = _ints(one_chip, S, M)
-    if program == "step":
-        compiled = progs.step.lower(
-            params, pool, counters, bt, _ints(one_chip, S),
-            _ints(one_chip, S), jax.ShapeDtypeStruct(
-                (S,), jnp.bool_, sharding=one_chip)).compile()
-    else:
-        compiled = progs.chunk.lower(
-            params, pool, counters, bt, _ints(one_chip), _ints(one_chip, C),
-            _ints(one_chip), _ints(one_chip)).compile()
+    step = (params, pool, counters, bt, _ints(one_chip, S),
+            _ints(one_chip, S),
+            jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip))
+    chunk = (params, pool, counters, bt, _ints(one_chip), _ints(one_chip, C),
+             _ints(one_chip), _ints(one_chip))
+    return cfg, t, progs, step, chunk
+
+
+@pytest.mark.parametrize("cell,program,temp_mb", [
+    ("longcat", "step", 150), ("longcat", "chunk", 850),
+    ("dotsvlm1", "step", 150), ("dotsvlm1", "chunk", 850)])
+def test_latent_cell_programs_fit_the_chip(one_chip, monkeypatch, cell,
+                                           program, temp_mb):
+    """The two programs of ``serve_longcat_ep32_closed128``
+    (``longcat-flash-ep32.json`` under ``closed128_gen768.json``: 10.4 GB
+    of weights, a 3.0 GB pool) and of ``serve_dotsvlm1_ep16_closed128``
+    (``dots-vlm1-ep16.json`` under ``closed128_p512_gen512.json``: 11.0
+    GB of weights, a 1.0 GB pool, 128 heads): weights and pool are
+    arguments, the pool is aliased (a 576-wide row made the compiler
+    copy the pool whole around every scatter: the row is padded to 640),
+    no copy of the pool and no float32 copy of the ``slots x T`` view
+    appear, and arguments plus temporaries stay inside the chip. The
+    step runs one ``paged_mq_attention`` a sublayer and gathers no
+    ``[slots, T, 640]`` view (LongCat: 8 gathers of 377 MB a step
+    before)."""
+    from multiverso_tpu.models import deepseek_v3, longcat
+
+    module, model, config, traffic, min_args = {
+        "longcat": (longcat, longcat.LongCatLM, "longcat-flash-ep32.json",
+                    "closed128_gen768.json", 13.3e9),
+        "dotsvlm1": (deepseek_v3, deepseek_v3.DeepSeekV3LM,
+                     "dots-vlm1-ep16.json", "closed128_p512_gen512.json",
+                     12.0e9)}[cell]
+    cfg, t, progs, step, chunk = _latent_cell_programs(
+        one_chip, monkeypatch, module, model, config, traffic)
+    compiled = (progs.step.lower(*step) if program == "step"
+                else progs.chunk.lower(*chunk)).compile()
+    pshape = progs.pools[0][0]
+    S, T, Bs = t["slots"], t["max_prompt"] + t["max_new"], pshape[2]
     mem = compiled.memory_analysis()
     pool_bytes = int(np.prod(pshape)) * 2
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < temp_mb * 10 ** 6
-    assert mem.argument_size_in_bytes > 13.3e9      # weights + pool
+    assert mem.argument_size_in_bytes > min_args       # weights + pool
     assert _fits_hbm(compiled, budget=15.75 * 2 ** 30)
     text = compiled.as_text()
     assert f"copy(bf16[{pshape[0]},{pshape[1]},{Bs},640]" not in text
     assert f"f32[{S},{T},640]" not in text
     if program == "step":
         assert _kernel_calls(compiled) == cfg.n_sublayers
-        for view in (f"= bf16[{S * M},{Bs},640]", f"= bf16[{S},{T},640]"):
+        for view in (f"= bf16[{pshape[1] - 1},{Bs},640]",
+                     f"= bf16[{S},{T},640]"):
             assert view not in text, view
