@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..log import Log
+from ..ops.embedding import scatter_add_rows
 from ..topology import SERVER_AXIS, WORKER_AXIS
 
 _ADAGRAD_EPS = 1e-8
@@ -148,33 +149,6 @@ class Word2VecConfig:
     # divergent ~2300); False = reference-equivalent sum always. Falsy
     # when a Word2Vec is built directly without resolution.
     row_mean_updates: Optional[bool] = None
-    # scatter-apply strategy for the embedding updates:
-    #   "scatter"  — XLA scatter-add straight into the (bf16) table;
-    #   "segsum"   — segment-sum the updates into a dense f32 delta, then
-    #                one vector add (collision-free; wins when the rows
-    #                are zipf-hot and the scatter serialises on duplicates);
-    #   "split8"   — 8 shadow copies indexed by update position % 8, then
-    #                summed (caps any row's collision chain at N/8).
-    # Measured on-chip by tools/w2v_profile.py; default picked by it.
-    update_impl: str = "scatter"
-    # Candidate-compaction implementation (device-corpus path, M > B):
-    #   "scatter" (default) — prefix-rank scatter into a zero slab
-    #               (mode="drop");
-    #   "gather"  — searchsorted over the survivor prefix-sum +
-    #               one dense row gather per packed array.
-    # Same packing either way (slot b <- the row whose inclusive
-    # survivor count first reaches b+1; tests/test_compact_impl.py
-    # asserts bit-identical training). The G=64 step spends ~25% on the
-    # pack, so both alternatives were MEASURED on-chip and rejected:
-    # "gather" hits 4.2-4.5M pairs/s vs scatter's 9.8M — binary search
-    # costs ceil(log2(M))x more scalar element accesses and narrow
-    # gathers pay the same per-element issue cost as scatters — and a
-    # fused single wide scatter of all K arrays measured 9.74M (a wash:
-    # narrow-row scatter cost is per ELEMENT, not per row, so stacking
-    # K arrays into one scatter moves the same element count). The
-    # compaction, like the update scatter, sits at a hardware
-    # element-granularity floor.
-    compact_impl: str = "scatter"
     # with row_mean_updates: use a STATIC expected-count scale table
     # (computed once per corpus chunk from the sampling laws — subsampled
     # unigram for centers/contexts, unigram^0.75 for negatives) instead of
@@ -303,6 +277,39 @@ def pool_negatives(rng_key, pool: jax.Array,
     return jax.lax.dynamic_slice(pool, (start,), (n,)).reshape(shape)
 
 
+def pack_survivors(ok: jax.Array, n_slots: int, *arrays: jax.Array):
+    """Pack the ``ok`` rows of each ``[M, ...]`` array into ``[n_slots,
+    ...]``, in their order: ``(*packed, valid)``, slot b holding the
+    b-th survivor, the slots past the survivors zero and ``valid`` false
+    there; survivors past ``n_slots`` are left out.
+
+    ONE sort of the candidates by a unique key (a survivor's position,
+    anyone else's position + M), so it need not be stable; 1-D arrays
+    ride along as payloads, wider ones are gathered by the sorted
+    position, which rides along only for them. Priced on a TPU v5e at the
+    benchmark cell's 163,840 candidates into 65,536 slots, two id arrays
+    (tools/w2v_kernel_probe.py ``compact.*``, PR 33): this sort 0.246
+    ms, each survivor scattered to its prefix-count rank 1.539 (a narrow
+    scatter costs 9.4 ns a candidate and array); a binary search over the
+    survivor prefix-sum plus row gathers was 2.2x slower than that
+    scatter end to end on an earlier stack."""
+    M = ok.shape[0]
+    pos = jnp.arange(M, dtype=jnp.int32)
+    flat = [a for a in arrays if a.ndim == 1]
+    wide = len(flat) < len(arrays)
+    out = jax.lax.sort(
+        (jnp.where(ok, pos, M + pos),) + (pos,) * wide + tuple(flat),
+        num_keys=1, is_stable=False)
+    rode = iter(out[1 + wide:])
+    rows = [next(rode)[:n_slots] if a.ndim == 1 else a[out[1][:n_slots]]
+            for a in arrays]
+    valid = jnp.arange(n_slots) < ok.sum()
+    return tuple(
+        jnp.where(valid.reshape((n_slots,) + (1,) * (r.ndim - 1)), r,
+                  jnp.zeros((), r.dtype))
+        for r in rows) + (valid,)
+
+
 class Word2Vec:
     """Jitted trainer bound to input/output embedding tables."""
 
@@ -328,9 +335,6 @@ class Word2Vec:
         if (config.shared_negatives > 1
                 and config.batch_size % config.shared_negatives != 0):
             Log.fatal("batch_size must divide by shared_negatives group")
-        if config.compact_impl not in ("gather", "scatter"):
-            Log.fatal(f"unknown compact_impl {config.compact_impl!r} "
-                      "(gather|scatter)")
         self._host_counts = (None if counts is None
                              else np.asarray(counts, np.float64))
         if config.row_mean_updates and config.row_mean_static:
@@ -467,23 +471,10 @@ class Word2Vec:
         batch_sharding = NamedSharding(mesh, P(WORKER_AXIS))
         emb_sharding = self.input_table.sharding
 
-        impl = cfg.update_impl
-
         def apply_sgd(w, rows, grads, lr, scale=None):
             upd = -lr * grads if scale is None \
                 else (-lr) * scale[:, None] * grads
-            if impl == "segsum":
-                dense = jax.ops.segment_sum(upd, rows,
-                                            num_segments=w.shape[0])
-                return (w.astype(jnp.float32) + dense).astype(w.dtype)
-            if impl == "split8":
-                R = 8
-                lane = jax.lax.rem(
-                    jnp.arange(rows.shape[0], dtype=jnp.int32), R)
-                shadow = jnp.zeros((R,) + w.shape, jnp.float32)
-                shadow = shadow.at[lane, rows].add(upd)
-                return (w.astype(jnp.float32) + shadow.sum(0)).astype(w.dtype)
-            return w.at[rows].add(upd.astype(w.dtype))
+            return scatter_add_rows(w, rows, upd)
 
         def apply_adagrad(w, g_acc, rows, grads, lr):
             g_rows = jnp.take(g_acc, rows, axis=0) + grads * grads
@@ -660,26 +651,13 @@ class Word2Vec:
                     w_out, g_out = apply_adagrad(w_out, g_out, rows, grads, lr)
             else:
                 w_in = apply_sgd(w_in, in_rows, in_grads, lr, in_scale)
-                if len(scatters) > 1 and impl in ("segsum", "split8"):
-                    # dense impls pay per-pass table traffic: combine sets.
-                    # (for the scatter impl the concat's extra [N, D]
-                    # materialisation costs more than the second scatter)
-                    rows = jnp.concatenate([s[0] for s in scatters])
-                    grads = jnp.concatenate([s[1] for s in scatters])
+                for i, (rows, grads, _) in enumerate(scatters):
                     if out_scales is not None:
-                        scale = jnp.concatenate(out_scales)
+                        scale = out_scales[i]
                     else:
                         scale = (None if out_counts is None
                                  else _row_scale_vec(out_counts, rows))
                     w_out = apply_sgd(w_out, rows, grads, lr, scale)
-                else:
-                    for i, (rows, grads, _) in enumerate(scatters):
-                        if out_scales is not None:
-                            scale = out_scales[i]
-                        else:
-                            scale = (None if out_counts is None
-                                     else _row_scale_vec(out_counts, rows))
-                        w_out = apply_sgd(w_out, rows, grads, lr, scale)
             return w_in, w_out, g_in, g_out
 
         if not cfg.cbow:
@@ -859,44 +837,6 @@ class Word2Vec:
         neg_pool = (self._ensure_neg_pool(draws_per_call)
                     if cfg.negative > 0 and cfg.neg_pool_size > 0 else None)
 
-        def compact_one(ok, n_valid, *arrays):
-            """Pack the ``ok`` rows of each [Ml, ...] array into [Bl, ...].
-
-            Linear-time alternative to sorting (TPU sorts are slow). Both
-            impls fill slot b with the row whose inclusive survivor count
-            first reaches b+1, and zero the slots past ``n_valid``:
-
-            * "scatter" (default): each survivor scatters to its
-              prefix-count rank (overflow/rejected rows drop out of
-              bounds);
-            * "gather": ``searchsorted`` over the prefix-sum + one dense
-              row gather per array — measured 2.2x slower end-to-end
-              (the log2(Ml) search rounds multiply scalar element
-              accesses; see ``compact_impl`` docs).
-            """
-            valid = jnp.arange(Bl) < n_valid
-            if cfg.compact_impl == "gather":
-                csum = jnp.cumsum(ok.astype(jnp.int32))
-                # method matters: the default 'scan' lowers to a
-                # SEQUENTIAL loop; 'scan_unrolled' is ceil(log2(Ml))
-                # vectorised gather rounds — but that log factor is the
-                # impl's downfall (see compact_impl docs)
-                src = jnp.searchsorted(csum, jnp.arange(1, Bl + 1),
-                                       method="scan_unrolled")
-                src = jnp.minimum(src, Ml - 1)
-                packed = tuple(
-                    jnp.where(valid.reshape((Bl,) + (1,) * (a.ndim - 1)),
-                              a[src], jnp.zeros((), a.dtype))
-                    for a in arrays)
-                return packed + (valid,)
-            rank = jnp.cumsum(ok.astype(jnp.int32)) - 1
-            dest = jnp.where(ok & (rank < Bl), rank, Bl)
-            packed = tuple(
-                jnp.zeros((Bl,) + a.shape[1:], a.dtype).at[dest].set(
-                    a, mode="drop")
-                for a in arrays)
-            return packed + (valid,)
-
         def fused(w_in, w_out, g_in, g_out, ext_ids, ext_sents, ext_disc,
                   lr, key, start0):
             """Sequential corpus streaming (the reference reads sentences in
@@ -976,9 +916,8 @@ class Word2Vec:
                 keep = (u_center >= cdisc) & (u_ctx >= xdisc)
                 ok = valid & keep
                 if Ml > Bl:
-                    n_valid = jnp.minimum(ok.sum(), Bl)
-                    centers, contexts, ok = compact_one(
-                        ok, n_valid, centers, contexts)
+                    centers, contexts, ok = pack_survivors(
+                        ok, Bl, centers, contexts)
                 return centers, contexts, ok.astype(jnp.float32)
 
             def sample_cbow(start, shrink, u_center, u_ctx):
@@ -992,10 +931,8 @@ class Word2Vec:
                 keep = (u_center >= cdisc)[:, None] & (u_ctx >= xdisc)
                 ok = valid & keep
                 if Ml > Bl:
-                    ex_ok = ok.any(axis=1)
-                    n_valid = jnp.minimum(ex_ok.sum(), Bl)
-                    centers, contexts, ok, ex_packed = compact_one(
-                        ex_ok, n_valid, centers, contexts, ok)
+                    centers, contexts, ok, ex_packed = pack_survivors(
+                        ok.any(axis=1), Bl, centers, contexts, ok)
                     ok = ok & ex_packed[:, None]
                 return centers, contexts, ok.astype(jnp.float32)
 

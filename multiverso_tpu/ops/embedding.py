@@ -21,7 +21,11 @@ def embedding_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
 
 def scatter_add_rows(table: jax.Array, ids: jax.Array,
                      deltas: jax.Array) -> jax.Array:
-    """Scatter-accumulate row deltas (duplicates sum, XLA scatter-add)."""
+    """Scatter-accumulate row deltas (duplicates sum, XLA scatter-add):
+    the word2vec step's one table write. Every delta is rounded to the
+    table's dtype and added on its own, so a bfloat16 row hit ``c`` times
+    rounds ``c`` times: summing a row's deltas first is faster on the
+    chip and another result (docs/W2V_KERNEL.md, 4 October 2026)."""
     return table.at[ids].add(deltas.astype(table.dtype))
 
 

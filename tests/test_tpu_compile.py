@@ -502,3 +502,111 @@ def test_latent_cell_programs_fit_the_chip(one_chip, monkeypatch, cell,
         for view in (f"= bf16[{pshape[1] - 1},{Bs},640]",
                      f"= bf16[{S},{T},640]"):
             assert view not in text, view
+
+
+_COLLECTIVES = ("all-reduce(", "all-gather(", "all-to-all(",
+                "collective-permute(", "reduce-scatter(")
+
+
+@pytest.mark.parametrize("workers,servers,temp_gb", [
+    (1, 1, 5.0), (2, 2, 4.2)])
+def test_w2v_cell_step_writes_rows_without_a_dense_delta(
+        topo, monkeypatch, workers, servers, temp_gb):
+    """The fused step of ``w2v_news3m_sgns`` and of ``w2v_news3m_dp2x2``
+    (``w2v-news3m-d300.json``: two ``bf16[3000000, 300]`` tables, 65,536
+    pairs a worker and step, 25 steps a dispatch).
+
+    The program is still ``jit_fused``; no float32 copy or delta of a
+    table (or of a server's half) exists; the candidates are packed by
+    ONE 163,840-key sort; the tables are written by three plain
+    scatter-adds (centres, contexts, negatives), told nothing about
+    their ids (``indices_are_sorted`` makes XLA:TPU sweep the whole
+    table, docs/W2V_KERNEL.md); temporaries stay under the bound (all
+    but ~0.3 GB of them are the two tables in the row-major 384-column
+    layout the loop carries, converted at the program's edges). On the
+    (2, 2) mesh the scan holds ONE collective, the all-reduce over the
+    server axis of the rows each server gathered from its half."""
+    import json
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from multiverso_tpu.apps.wordembedding import subsample_probs
+    from multiverso_tpu.models.word2vec import Word2Vec, Word2VecConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "w2v-news3m-d300.json")) as fh:
+        cfg = json.load(fh)
+    V, D = cfg["vocab_size"], cfg["embedding_size"]
+    mesh = Mesh(np.array(topo.devices[:workers * servers]).reshape(
+        workers, servers), ("worker", "server"))
+
+    class Table:            # what the trainer reads of a table
+        def __init__(self):
+            self.mesh, self.num_row, self.padded_shape = mesh, V, (V, D)
+            self.sharding = NamedSharding(mesh, P("server", None))
+            self._lock, self.version = threading.Lock(), 0
+
+    # the zipf law of benchmarks/gen.py's w2v_counts, in its own words
+    r = np.arange(1, V + 1, dtype=np.float64)
+    counts = cfg["total_words"] * np.log1p(1.0 / r) / np.log(V + 1.0)
+    # nothing can be put on a described device: the trainer's key stays
+    # where it was made
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    model = Word2Vec(Word2VecConfig(
+        vocab_size=V, embedding_size=D, window=cfg["window"],
+        negative=cfg["negative"], init_lr=cfg["init_lr"],
+        batch_size=cfg["batch_size_per_worker"] * workers,
+        oversample=cfg["oversample"], neg_pool_size=cfg["neg_pool_size"],
+        row_mean_updates=cfg["row_mean_updates"],
+        row_mean_static=cfg["row_mean_static"],
+        row_update_cap=cfg["row_update_cap"],
+        shared_negatives=cfg["shared_negatives"],
+        seed=cfg["trainer_seed"]), Table(), Table(), counts=counts)
+    monkeypatch.undo()
+    assert model._dp_local() == workers
+    model._build_static_scales(subsample_probs(counts, cfg["sample"]))
+    n, W = cfg["corpus_words"], cfg["window"]
+    M = model._candidate_batch(n)
+    fused = model._build_corpus_step(cfg["steps_per_dispatch"], M)
+
+    def arg(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    table = arg((V, D), jnp.bfloat16, P("server", None))
+    ext = n + M + 2 * W
+    compiled = fused.lower(
+        table, table, None, None, arg((ext,), jnp.int32),
+        arg((ext,), jnp.int32), arg((ext,), jnp.float32),
+        arg((), jnp.float32), arg((2,), jnp.uint32),
+        arg((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_fused")
+    shard = V // servers
+    for dense in (f"f32[{V},{D}]", f"f32[{shard},{D}]"):
+        assert dense not in text, dense
+    packs = [ln for ln in text.splitlines()
+             if " sort(" in ln and f"s32[{M // workers}]" in ln]
+    assert len(packs) == 1, packs
+    writes = [ln for ln in text.splitlines()
+              if f"= bf16[{shard},{D}]" in ln and " scatter(" in ln]
+    assert len(writes) == 3, writes     # centres, contexts, negatives
+    assert not any("indices_are_sorted=true" in ln
+                   or "unique_indices=true" in ln for ln in writes)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * shard * D * 2      # donated
+    assert mem.temp_size_in_bytes < temp_gb * 1e9, mem.temp_size_in_bytes
+    assert _fits_hbm(compiled, budget=15.75 * 2 ** 30)
+    in_scan = [ln for ln in text.splitlines()
+               if "/while/body/" in ln and any(c in ln for c in _COLLECTIVES)]
+    if workers * servers == 1:
+        assert not in_scan
+    else:
+        assert len(in_scan) == 1 and "all-reduce(" in in_scan[0], in_scan
+        for gathered in (f"bf16[{cfg['batch_size_per_worker']},{D}]",
+                         f"bf16[1024,{cfg['negative']},{D}]"):
+            assert gathered in in_scan[0].split(" all-reduce(")[0]

@@ -511,10 +511,13 @@ def test_row_mean_static_matches_realized(mv_session):
     assert abs(stat[-1] - real[-1]) < 0.3, (stat[-1], real[-1])
 
 
-def test_dp_dispatch_exchange_exact_vs_sequential_oracle(tmp_path):
-    """dp_sync="dispatch" contract: the multi-batch dispatch on a dp-worker
-    mesh equals w0 + sum over workers of that worker's SEQUENTIAL local
-    deltas (each worker sees its own updates immediately, peers' at the
+@pytest.mark.parametrize("mesh_shape", ["4,1", "2,2"])
+def test_dp_dispatch_exchange_exact_vs_sequential_oracle(tmp_path,
+                                                         mesh_shape):
+    """dp_sync="dispatch" contract ("2,2" also shards each table over two
+    servers, as the benchmark's dp cell does): the multi-batch dispatch on
+    a dp-worker mesh equals w0 + sum over workers of that worker's
+    SEQUENTIAL local deltas (each worker sees its own updates immediately, peers' at the
     dispatch boundary). HS mode keeps the step RNG-free, so the per-worker
     oracle is bit-reproducible; the only tolerance is psum summation order.
     """
@@ -524,7 +527,8 @@ def test_dp_dispatch_exchange_exact_vs_sequential_oracle(tmp_path):
                                                 Word2VecConfig, build_huffman)
     from multiverso_tpu.runtime import Session
 
-    vocab, dim, dp, S, B = 32, 8, 4, 3, 16
+    vocab, dim, S, B = 32, 8, 3, 16
+    dp = int(mesh_shape.split(",")[0])
     counts = np.arange(1, vocab + 1, dtype=np.float64)
     huff = build_huffman(counts)
     rng = np.random.default_rng(11)
@@ -554,7 +558,7 @@ def test_dp_dispatch_exchange_exact_vs_sequential_oracle(tmp_path):
 
     # deterministic shared init for every run
     rng0 = np.random.default_rng(99)
-    got_in, got_out = train(f"{dp},1", "dispatch", centers, contexts, mask)
+    got_in, got_out = train(mesh_shape, "dispatch", centers, contexts, mask)
 
     # oracle: each worker trains its batch COLUMNS shard sequentially on a
     # 1-worker mesh; deltas sum onto the shared init

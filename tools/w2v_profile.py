@@ -54,13 +54,6 @@ def main(argv=None):
     ap.add_argument("--row_mean", type=int, default=1)
     ap.add_argument("--static", type=int, default=0,
                     help="row_mean_static (the shipped bench stabiliser)")
-    ap.add_argument("--impl", default="scatter",
-                    choices=["scatter", "segsum", "split8"])
-    ap.add_argument("--compact", default="scatter",
-                    choices=["scatter", "gather"],
-                    help="candidate-compaction impl (Word2VecConfig."
-                         "compact_impl; gather is the measured-rejected "
-                         "alternative)")
     ap.add_argument("--shared", type=int, default=0,
                     help="shared_negatives group size G (bench default 64)")
     ap.add_argument("--trace", default="")
@@ -86,8 +79,6 @@ def main(argv=None):
                          neg_pool_size=1 << 22,
                          row_mean_updates=bool(args.row_mean),
                          row_mean_static=bool(args.static),
-                         update_impl=args.impl,
-                         compact_impl=args.compact,
                          shared_negatives=args.shared)
     w_in = mv.create_table("matrix", vocab, D, init_value="random",
                            dtype=dtype, name="w_in")

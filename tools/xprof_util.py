@@ -29,8 +29,13 @@ import shutil
 import tempfile
 
 
-def trace_device_ms(run_fn, iters: int = 5) -> float:
+def trace_device_ms(run_fn, iters: int = 5, program: str = "") -> float:
     """Average device ms per call of ``run_fn`` over ``iters`` traced calls.
+
+    ``program`` narrows the sum to the spans of one jitted program
+    (``jit_<program>``), for a ``run_fn`` whose result the closing fetch
+    below can only reach through a program of its own (a reshape of a
+    table is a copy of the table).
 
     ``run_fn()`` must dispatch the program under test and return a value
     whose completion the caller's final fetch forces; this helper blocks
@@ -55,6 +60,6 @@ def trace_device_ms(run_fn, iters: int = 5) -> float:
     total = sum(int(e["args"]["device_duration_ps"]) / 1e9 for e in events
                 if e.get("ph") == "X"
                 and "device_duration_ps" in e.get("args", {})
-                and e.get("name", "").startswith("jit_"))
+                and e.get("name", "").startswith("jit_" + program))
     shutil.rmtree(trace_dir, ignore_errors=True)
     return total / iters
