@@ -1,10 +1,11 @@
 """Model families: word2vec (skip-gram/CBOW), logistic regression/FTRL,
-the transformer LM parallelism showcase, and the serve-only LongCat-Flash
-and DeepSeek-V3-architecture shares; :func:`from_config` builds the LMs
+the transformer LM parallelism showcase, and the serve-only LongCat-Flash,
+DeepSeek-V3-architecture and ``bailing_hybrid`` (Ling) shares; :func:`from_config` builds the LMs
 from a configuration dict."""
 
-from . import deepseek_v3, longcat
+from . import deepseek_v3, ling, longcat
 from .deepseek_v3 import DeepSeekV3Config, DeepSeekV3LM
+from .ling import LingConfig, LingLM
 from .logreg import FTRLLogReg, LogReg, LogRegConfig, SparseLogReg
 from .longcat import LongCatConfig, LongCatLM
 from .transformer import TransformerConfig, TransformerLM
@@ -29,12 +30,14 @@ def from_config(cfg: dict, seed: int, **overrides):
             dtype=jnp.dtype(cfg["dtype"]),
             learning_rate=cfg["learning_rate"], momentum=cfg["momentum"],
             seed=int(seed), **overrides))
-    if kind in ("longcat_flash", "deepseek_v3"):
+    if kind in ("longcat_flash", "deepseek_v3", "bailing_hybrid"):
         if overrides:
             raise TypeError(f"from_config: a {kind!r} model takes no "
                             f"overrides, got {sorted(overrides)}")
         if kind == "longcat_flash":
             return LongCatLM(longcat.config_from_dict(cfg, seed))
+        if kind == "bailing_hybrid":
+            return LingLM(ling.config_from_dict(cfg, seed))
         return DeepSeekV3LM(deepseek_v3.config_from_dict(cfg, seed))
     raise ValueError(f"from_config: no model of kind {kind!r}")
 
@@ -43,6 +46,8 @@ __all__ = [
     "from_config",
     "DeepSeekV3Config",
     "DeepSeekV3LM",
+    "LingConfig",
+    "LingLM",
     "LongCatConfig",
     "LongCatLM",
     "FTRLLogReg",

@@ -91,6 +91,7 @@ class DeepSeekV3Config(longcat.LatentCacheSizes):
     # what longcat.py's MLA code asks of a configuration
     mla_scale_q_lora = False
     mla_scale_kv_lora = False
+    mla_head_gate = False
 
     @property
     def n_sublayers(self) -> int:
@@ -158,50 +159,63 @@ def _leaf_key(seed: int, layer: int, leaf: str):
     return jax.random.fold_in(key, _LEAVES.index(leaf))
 
 
+def drawers(dtype):
+    """``(draw(key, shape, std, dtype), draw_experts(keys, shape, std))``:
+    one leaf, and a stack of leaves a key each in ``dtype``, each one
+    jitted draw on the default device."""
+    return (jax.jit(longcat._draw, static_argnums=(1, 2, 3)),
+            jax.jit(lambda keys, shape, std: jax.vmap(
+                lambda k: longcat._draw(k, shape, std, dtype))(keys),
+                static_argnums=(1, 2)))
+
+
+def draw_ffn_layer(cfg, draws, key, layer: int) -> Dict[str, Any]:
+    """Layer ``layer``'s FFN leaves, ``key(leaf)`` the leaf's key: a
+    dense SwiGLU (``ffn``) in the ``first_k_dense_replace`` leading
+    layers; after them the router and its bias (float32), the held
+    routed experts (a key an expert, by its ROUTER OUTPUT index) and the
+    shared expert. std ``1/sqrt(fan_in)``, the bias ``_BIAS_STD``."""
+    draw, draw_experts = draws
+    D, Fe, dt = cfg.hidden_size, cfg.moe_intermediate_size, cfg.dtype
+
+    def ffn(names, width):
+        return {"w_gate": draw(key(names[0]), (D, width), D ** -0.5, dt),
+                "w_up": draw(key(names[1]), (D, width), D ** -0.5, dt),
+                "w_down": draw(key(names[2]), (width, D), width ** -0.5, dt)}
+
+    if layer < cfg.first_k_dense_replace:
+        return {"ffn": ffn(("w_gate", "w_up", "w_down"),
+                           cfg.intermediate_size)}
+    ids = cfg.expert_offset + jnp.arange(cfg.n_routed_experts)
+    keys = lambda leaf: jax.vmap(
+        lambda e: jax.random.fold_in(key(leaf), e))(ids)
+    E = cfg.total_routed_experts
+    return {
+        "router": draw(key("router"), (D, E), D ** -0.5, jnp.float32),
+        "router_bias": draw(key("router_bias"), (E,), _BIAS_STD,
+                            jnp.float32),
+        "experts": {
+            "w_gate": draw_experts(keys("e_gate"), (D, Fe), D ** -0.5),
+            "w_up": draw_experts(keys("e_up"), (D, Fe), D ** -0.5),
+            "w_down": draw_experts(keys("e_down"), (Fe, D), Fe ** -0.5)},
+        "shared": ffn(("s_gate", "s_up", "s_down"),
+                      cfg.n_shared_experts * Fe)}
+
+
 def init_params(cfg: DeepSeekV3Config) -> Dict[str, Any]:
     """The share's weights, each leaf one jitted draw on the default
     device (never the whole tree at once, never on the host)."""
-    D, F, Fe = cfg.hidden_size, cfg.intermediate_size, \
-        cfg.moe_intermediate_size
-    E, dt = cfg.total_routed_experts, cfg.dtype
-    draw = jax.jit(longcat._draw, static_argnums=(1, 2, 3))
-    draw_experts = jax.jit(
-        lambda keys, shape, std: jax.vmap(
-            lambda k: longcat._draw(k, shape, std, dt))(keys),
-        static_argnums=(1, 2))
+    D, dt = cfg.hidden_size, cfg.dtype
+    draws = drawers(dt)
+    draw = draws[0]
     ones = lambda n: jnp.ones((n,), jnp.float32)
-
-    def ffn(l, names, width):
-        k = lambda leaf: _leaf_key(cfg.seed, l, leaf)
-        return {"w_gate": draw(k(names[0]), (D, width), D ** -0.5, dt),
-                "w_up": draw(k(names[1]), (D, width), D ** -0.5, dt),
-                "w_down": draw(k(names[2]), (width, D), width ** -0.5, dt)}
-
-    def experts(l):
-        ids = cfg.expert_offset + jnp.arange(cfg.n_routed_experts)
-        keys = lambda leaf: jax.vmap(
-            lambda e: jax.random.fold_in(_leaf_key(cfg.seed, l, leaf), e))(ids)
-        return {
-            "w_gate": draw_experts(keys("e_gate"), (D, Fe), D ** -0.5),
-            "w_up": draw_experts(keys("e_up"), (D, Fe), D ** -0.5),
-            "w_down": draw_experts(keys("e_down"), (Fe, D), Fe ** -0.5)}
 
     layers = []
     for l in range(cfg.num_hidden_layers):
         k = lambda leaf: _leaf_key(cfg.seed, l, leaf)
-        layer = {"mla": longcat.draw_mla(cfg, draw, k, _QB_GAIN, _WO_GAIN),
-                 "ffn_norm": ones(D)}
-        if l < cfg.first_k_dense_replace:
-            layer["ffn"] = ffn(l, ("w_gate", "w_up", "w_down"), F)
-        else:
-            layer.update(
-                router=draw(k("router"), (D, E), D ** -0.5, jnp.float32),
-                router_bias=draw(k("router_bias"), (E,), _BIAS_STD,
-                                 jnp.float32),
-                experts=experts(l),
-                shared=ffn(l, ("s_gate", "s_up", "s_down"),
-                           cfg.n_shared_experts * Fe))
-        layers.append(layer)
+        layers.append({
+            "mla": longcat.draw_mla(cfg, draw, k, _QB_GAIN, _WO_GAIN),
+            "ffn_norm": ones(D), **draw_ffn_layer(cfg, draws, k, l)})
     return {
         # unit variance an element, as longcat.py's (an embedding of norm
         # 1 is swamped by the first sublayer's output)
@@ -329,6 +343,28 @@ def routing_summary(cfg: DeepSeekV3Config, counts: np.ndarray) -> dict:
     return out
 
 
+def check_share(who: str, c) -> None:
+    """What a group-limited share has to satisfy whatever the model: the
+    held experts inside the router's outputs, groups that hold the
+    picks, the dense layers inside the layers held."""
+    if c.expert_offset + c.n_routed_experts > c.total_routed_experts:
+        Log.fatal(f"{who}: the held experts [{c.expert_offset}, "
+                  f"+{c.n_routed_experts}) lie outside the "
+                  f"{c.total_routed_experts} the router addresses")
+    if c.total_routed_experts % c.n_group \
+            or not 0 < c.topk_group <= c.n_group \
+            or c.total_routed_experts // c.n_group < 2 \
+            or c.num_experts_per_tok > c.topk_group \
+            * (c.total_routed_experts // c.n_group):
+        Log.fatal(f"{who}: {c.total_routed_experts} experts do not "
+                  f"make {c.n_group} groups of two or more of which "
+                  f"{c.topk_group} hold {c.num_experts_per_tok} picks")
+    if not 0 <= c.first_k_dense_replace <= c.num_hidden_layers:
+        Log.fatal(f"{who}: first_k_dense_replace "
+                  f"{c.first_k_dense_replace} outside the "
+                  f"{c.num_hidden_layers} layers")
+
+
 class DeepSeekV3LM:
     """Serve-only share of a DeepSeek-V3-architecture model: weights
     drawn on the device from ``config.seed``; the snapshot contract and
@@ -337,22 +373,7 @@ class DeepSeekV3LM:
     def __init__(self, config: DeepSeekV3Config) -> None:
         c = config
         who = "DeepSeekV3LM"
-        if c.expert_offset + c.n_routed_experts > c.total_routed_experts:
-            Log.fatal(f"{who}: the held experts [{c.expert_offset}, "
-                      f"+{c.n_routed_experts}) lie outside the "
-                      f"{c.total_routed_experts} the router addresses")
-        if c.total_routed_experts % c.n_group \
-                or not 0 < c.topk_group <= c.n_group \
-                or c.total_routed_experts // c.n_group < 2 \
-                or c.num_experts_per_tok > c.topk_group \
-                * (c.total_routed_experts // c.n_group):
-            Log.fatal(f"{who}: {c.total_routed_experts} experts do not "
-                      f"make {c.n_group} groups of two or more of which "
-                      f"{c.topk_group} hold {c.num_experts_per_tok} picks")
-        if not 0 <= c.first_k_dense_replace <= c.num_hidden_layers:
-            Log.fatal(f"{who}: first_k_dense_replace "
-                      f"{c.first_k_dense_replace} outside the "
-                      f"{c.num_hidden_layers} layers")
+        check_share(who, c)
         y = c.rope_scaling
         if y is None or y.get("type", "yarn") != "yarn" \
                 or y["factor"] <= 1 or y["mscale"] != y["mscale_all_dim"]:

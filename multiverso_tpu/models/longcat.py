@@ -128,8 +128,11 @@ class LongCatConfig(LatentCacheSizes):
     # what the MLA code below takes from whichever configuration it is
     # handed (``models/deepseek_v3.py`` hands it another): the two
     # ``mla_scale_*`` switches above, the rotary frequencies' scaling
-    # (none here) and what the attention scores are divided by
+    # (none here), a head-wise sigmoid gate on the attention output
+    # before W_o (leaf ``w_og``; none here) and what the attention scores
+    # are divided by
     rope_scaling = None
+    mla_head_gate = False
 
     @property
     def softmax_divisor(self) -> float:
@@ -181,17 +184,26 @@ def _draw(key, shape, std: float, dtype):
 def draw_mla(cfg, draw, key, qb_gain: float, wo_gain: float = 1.0):
     """One MLA sublayer's leaves: ``draw(key, shape, std, dtype)`` with
     ``key(leaf)`` the leaf's key; std ``1/sqrt(fan_in)`` times the two
-    gains a model's law sets."""
+    gains a model's law sets. Without a query latent (``q_lora_rank``
+    None) the query is one projection ``w_q`` at ``qb_gain``; with
+    ``mla_head_gate`` a ``w_og`` [D, H] makes the output gate's logits."""
     D, H, rq, rkv = cfg.hidden_size, cfg.num_attention_heads, \
         cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     dt = cfg.dtype
     ones = lambda n: jnp.ones((n,), jnp.float32)
+    if rq is None:
+        query = {"w_q": draw(key("w_q"), (D, H * (dn + dr)),
+                             qb_gain * D ** -0.5, dt)}
+    else:
+        query = {"q_norm": ones(rq),
+                 "w_qa": draw(key("w_qa"), (D, rq), D ** -0.5, dt),
+                 "w_qb": draw(key("w_qb"), (rq, H * (dn + dr)),
+                              qb_gain * rq ** -0.5, dt)}
+    if cfg.mla_head_gate:
+        query["w_og"] = draw(key("w_og"), (D, H), D ** -0.5, dt)
     return {
-        "norm": ones(D), "q_norm": ones(rq), "kv_norm": ones(rkv),
-        "w_qa": draw(key("w_qa"), (D, rq), D ** -0.5, dt),
-        "w_qb": draw(key("w_qb"), (rq, H * (dn + dr)),
-                     qb_gain * rq ** -0.5, dt),
+        "norm": ones(D), "kv_norm": ones(rkv), **query,
         "w_kva": draw(key("w_kva"), (D, rkv + dr), D ** -0.5, dt),
         "w_kvb": draw(key("w_kvb"), (rkv, H * (dn + dv)), rkv ** -0.5, dt),
         "w_o": draw(key("w_o"), (H * dv, D), wo_gain * (H * dv) ** -0.5,
@@ -307,12 +319,15 @@ def mla_project(cfg: LongCatConfig, w, x, pos, width: int = 0):
         cfg.qk_rope_head_dim
     rq, rkv, D = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.hidden_size
     T = x.shape[0]
-    sq = math.sqrt(D / rq) if cfg.mla_scale_q_lora else 1.0
     skv = math.sqrt(D / rkv) if cfg.mla_scale_kv_lora else 1.0
-    c_q = rmsnorm(jnp.dot(x, w["w_qa"], preferred_element_type=f32),
-                  w["q_norm"] * sq, cfg.rms_norm_eps, dt)
-    q = jnp.dot(c_q, w["w_qb"], preferred_element_type=f32).reshape(
-        T, H, dn + dr)
+    if rq is None:      # no query latent: one projection
+        q = jnp.dot(x, w["w_q"], preferred_element_type=f32)
+    else:
+        sq = math.sqrt(D / rq) if cfg.mla_scale_q_lora else 1.0
+        c_q = rmsnorm(jnp.dot(x, w["w_qa"], preferred_element_type=f32),
+                      w["q_norm"] * sq, cfg.rms_norm_eps, dt)
+        q = jnp.dot(c_q, w["w_qb"], preferred_element_type=f32)
+    q = q.reshape(T, H, dn + dr)
     q_rope = rope(q[..., dn:], pos, rope_frequencies(cfg)).astype(dt)
     kv = jnp.dot(x, w["w_kva"], preferred_element_type=f32)
     c = rmsnorm(kv[:, :rkv], w["kv_norm"] * skv, cfg.rms_norm_eps, dt)
@@ -329,10 +344,26 @@ def _kvb(cfg: LongCatConfig, w):
     return wb[..., :dn], wb[..., dn:]
 
 
-def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask):
+def head_gate(cfg, w, x):
+    """``sigmoid(x w_og)`` [T, H] in float32, one scalar a head that
+    multiplies the head's attention output before ``W_o``; None where
+    the configuration has no such gate."""
+    if not cfg.mla_head_gate:
+        return None
+    return jax.nn.sigmoid(jnp.dot(x, w["w_og"],
+                                  preferred_element_type=jnp.float32))
+
+
+def _gated(o, gate):
+    return o if gate is None else o * gate[..., None]
+
+
+def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask,
+                 gate=None):
     """The expanded form: ``rows`` [T, >= rkv + dr] become per-head keys
-    and values; queries [C, H, .] attend where ``mask`` [C, T]. Returns
-    the sublayer's output [C, D] in float32."""
+    and values; queries [C, H, .] attend where ``mask`` [C, T]; ``gate``
+    is :func:`head_gate`'s. Returns the sublayer's output [C, D] in
+    float32."""
     f32, dt = jnp.float32, cfg.dtype
     rkv = cfg.kv_lora_rank
     wk, wv = _kvb(cfg, w)
@@ -346,8 +377,8 @@ def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask):
          + jnp.einsum("qhr,tr->hqt", q_rope, k_rope,
                       preferred_element_type=f32)) / cfg.softmax_divisor
     p = jax.nn.softmax(jnp.where(mask[None], s, _NEG_INF), axis=-1)
-    o = jnp.einsum("hqt,thd->qhd", p.astype(dt), v,
-                   preferred_element_type=f32).astype(dt)
+    o = _gated(jnp.einsum("hqt,thd->qhd", p.astype(dt), v,
+                          preferred_element_type=f32), gate).astype(dt)
     return jnp.dot(o.reshape(o.shape[0], -1), w["w_o"],
                    preferred_element_type=f32)
 
@@ -366,18 +397,19 @@ def latent_query(cfg: LongCatConfig, w, q_nope, q_rope, width: int):
                            if pad else []), axis=-1)
 
 
-def latent_output(cfg: LongCatConfig, w, o_lat):
+def latent_output(cfg: LongCatConfig, w, o_lat, gate=None):
     """The decode form's tail: the attended latents ``o_lat`` [S, H,
-    rkv] through the value half of ``W_kvb`` and ``W_o``: [S, D]
-    float32."""
+    rkv] through the value half of ``W_kvb``, :func:`head_gate`'s
+    ``gate`` and ``W_o``: [S, D] float32."""
     f32, dt = jnp.float32, cfg.dtype
-    o = jnp.einsum("shc,chd->shd", o_lat.astype(dt), _kvb(cfg, w)[1],
-                   preferred_element_type=f32).astype(dt)
+    o = _gated(jnp.einsum("shc,chd->shd", o_lat.astype(dt), _kvb(cfg, w)[1],
+                          preferred_element_type=f32), gate).astype(dt)
     return jnp.dot(o.reshape(o.shape[0], -1), w["w_o"],
                    preferred_element_type=f32)
 
 
-def mla_latent(cfg: LongCatConfig, w, q_nope, q_rope, view, pos):
+def mla_latent(cfg: LongCatConfig, w, q_nope, q_rope, view, pos,
+               gate=None):
     """The decode form: one query a slot, ``q_nope``/``q_rope`` [S, H,
     .], against each slot's cache rows ``view`` [S, T, >= rkv + dr] as
     they lie (the query is padded with zeros to their width); positions
@@ -393,7 +425,7 @@ def mla_latent(cfg: LongCatConfig, w, q_nope, q_rope, view, pos):
     o_lat = jnp.einsum("sht,stc->shc", p.astype(cfg.dtype),
                        view[..., :cfg.kv_lora_rank],
                        preferred_element_type=f32)
-    return latent_output(cfg, w, o_lat)
+    return latent_output(cfg, w, o_lat, gate)
 
 
 def expert_layer(cfg: LongCatConfig, blk, u, valid=None, identity=True):
@@ -489,6 +521,7 @@ def step_attend(cfg, w, x, pool, sub: int, block_tables, pos, rows,
     n_sub, N, Bs, W = pool.shape
     write_blk, write_off, lengths = rows
     q_nope, q_rope, row = mla_project(cfg, w, x, pos, W)
+    gate = head_gate(cfg, w, x)
     pool = pool.at[sub, write_blk, write_off].set(row)
     if paged_attention is not None:
         o_lat = paged_attention(
@@ -496,14 +529,14 @@ def step_attend(cfg, w, x, pool, sub: int, block_tables, pos, rows,
             pool.reshape(n_sub * N, Bs, W), None,
             sub * N + block_tables, lengths,
             scale=1.0 / cfg.softmax_divisor, wv=cfg.kv_lora_rank)
-        return pool, latent_output(cfg, w, o_lat)
+        return pool, latent_output(cfg, w, o_lat, gate)
     # the barrier holds the view as ONE array between its two readers
     # (scores, values): with weights and pool filling the chip the TPU
     # compiler otherwise rematerializes the gather, once a reader (14
     # gathers of 377 MB a step for 8)
     view = jax.lax.optimization_barrier(
         _view(pool, sub, block_tables, t_logical))
-    return pool, mla_latent(cfg, w, q_nope, q_rope, view, pos)
+    return pool, mla_latent(cfg, w, q_nope, q_rope, view, pos, gate)
 
 
 def chunk_rows(pool, block_tables, slot, chunk: int, offset, length,
@@ -535,7 +568,8 @@ def chunk_attend(cfg, w, x, pool, sub: int, rows, t_logical: int):
     q_nope, q_rope, new = mla_project(cfg, w, x, pos_ix, pool.shape[-1])
     pool = pool.at[sub, blk_ix, off].set(new)
     view = _view(pool, sub, bt_row, t_logical)
-    return pool, mla_expanded(cfg, w, q_nope, q_rope, view, mask)
+    return pool, mla_expanded(cfg, w, q_nope, q_rope, view, mask,
+                              head_gate(cfg, w, x))
 
 
 def greedy_next(cfg, params, h, tok, pos, active):
@@ -604,39 +638,50 @@ def cow_block_copy(pool, counters, src, dst):
 
 
 def latent_pool_programs(cfg, spec, model: str, step, chunk,
-                         counter_shape: tuple, counters):
+                         counter_shape: tuple, counters,
+                         slot_pools: tuple = (), lacking=None):
     """The engine's seam (``serving/programs.py``) for a model whose
-    cache is ONE latent pool ``[cfg.n_sublayers, N + 1, Bs,
-    cfg.pool_width]`` beside one small counters array: ``step`` and
-    ``chunk`` (the model's :func:`decode_step_paged` /
-    :func:`prefill_chunk_paged`) jitted over it with the pool donated,
-    and the copy-on-write. The programs' names in a profile are
-    ``jit_<model>_decode_step``, ``jit_<model>_prefill_chunk`` and
-    ``jit_<model>_cow_block``. What such a model lacks is refused here,
-    by name, never run wrong."""
+    per-TOKEN cache is one latent pool ``[cfg.n_sublayers, N + 1, Bs,
+    cfg.pool_width]``. The pools, in this order: pool 0, the latent pool
+    (the engine takes block size and block shape from pool 0, so it
+    stays block-shaped); then ``slot_pools``, ``(shape, dtype)`` each
+    with ``slots`` as the second axis: what a model keeps per SEQUENCE
+    and not per token (a recurrent state), rows that a slot's first
+    chunk resets and its later chunks and steps carry; last, one small
+    counters array. ``step`` and ``chunk`` (the model's
+    ``decode_step_paged`` / ``prefill_chunk_paged``) are jitted over
+    them in that order with every pool but the counters donated, and
+    the copy-on-write over the latent pool. The programs' names in a
+    profile are ``jit_<model>_decode_step``,
+    ``jit_<model>_prefill_chunk`` and ``jit_<model>_cow_block``. What
+    such a model lacks is refused here, by name, never run wrong:
+    ``lacking`` adds a model's own refusals (feature -> why)."""
     from ..serving.programs import ServingPrograms, refuse
 
     who = f"DecodeEngine {spec.name!r} ({model})"
-    refuse(who, spec, kv_quant="no int8 latent pool",
-           param_quant="no int8 parameter pin", decode_tp="no "
-           "tensor-parallel decode programs", spec_k="no verify step",
-           prefill_sp="no sequence-parallel prefill")
+    refuse(who, spec, **{
+        "kv_quant": "no int8 latent pool",
+        "param_quant": "no int8 parameter pin",
+        "decode_tp": "no tensor-parallel decode programs",
+        "spec_k": "no verify step",
+        "prefill_sp": "no sequence-parallel prefill", **(lacking or {})})
     if spec.cache_len > cfg.max_seq:
         Log.fatal(f"{who}: max_prompt + max_new {spec.cache_len} "
                   f"exceeds max_position_embeddings {cfg.max_seq}")
     T = spec.cache_len
-    donate = (1,) if spec.donate else ()
+    n_state = len(slot_pools)
+    donate = tuple(range(1, 2 + n_state)) if spec.donate else ()
     # the one-token step reads the live blocks in place where a
     # block is whole tiles on a TPU; the chunk keeps the view
     attend = paged_kernel.step_attention(cfg.dtype, spec.block_size,
                                          cfg.pool_width)
 
-    def decode_step(params, pool, counters, bt, tok, pos, active):
-        return step(cfg, params, pool, counters, bt, tok, pos, active, T,
-                    paged_attention=attend)
+    # ``rest``: the slot pools, the counters, then the program's data
+    def decode_step(params, pool, *rest):
+        return step(cfg, params, pool, *rest, T, paged_attention=attend)
 
-    def prefill_chunk(params, pool, counters, bt, slot, toks, off, n):
-        return chunk(cfg, params, pool, counters, bt, slot, toks, off, n, T)
+    def prefill_chunk(params, pool, *rest):
+        return chunk(cfg, params, pool, *rest, T)
 
     def cow_block(pool, counters, src, dst):
         return cow_block_copy(pool, counters, src, dst)
@@ -648,16 +693,21 @@ def latent_pool_programs(cfg, spec, model: str, step, chunk,
                   cfg.pool_width)
     return ServingPrograms(
         pools=((pool_shape, jnp.dtype(cfg.dtype)),
+               *((tuple(shape), jnp.dtype(dtype))
+                 for shape, dtype in slot_pools),
                (counter_shape, jnp.dtype(jnp.float32))),
         bytes_per_block=(cfg.n_sublayers * spec.block_size * cfg.pool_width
                          * jnp.dtype(cfg.dtype).itemsize),
+        bytes_per_slot=sum(
+            int(np.prod(shape)) // spec.slots * jnp.dtype(dtype).itemsize
+            for shape, dtype in slot_pools),
         step=jax.jit(decode_step, donate_argnums=donate),
         chunk=jax.jit(prefill_chunk, donate_argnums=donate),
         cow=(jax.jit(cow_block, donate_argnums=(0,) if spec.donate else ())
              if spec.prefix else None),
         # pin: the default, the snapshot itself (a serve-only model's
         # weights never move and nothing donates them)
-        counter_pool=1, counters=counters)
+        counter_pool=1 + n_state, counters=counters)
 
 
 def routing_summary(cfg: LongCatConfig, counts: np.ndarray) -> dict:

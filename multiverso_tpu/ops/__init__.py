@@ -3,6 +3,7 @@
 from .embedding import embedding_lookup, scatter_add_rows, segment_mean_rows
 from .flash_attention import (flash_attention, flash_attention_partial,
                               merge_partials)
+from .kda import kda_chunk, kda_step, short_conv_chunk, short_conv_step
 from .paged_attention import paged_mq_attention
 from .moe import (EXPERT_AXIS, held_expert_layer, init_moe_params, mlp_expert,
                   moe_apply, route_group_limited, route_topk, swiglu,
@@ -18,6 +19,10 @@ __all__ = [
     "flash_attention",
     "flash_attention_partial",
     "merge_partials",
+    "kda_chunk",
+    "kda_step",
+    "short_conv_chunk",
+    "short_conv_step",
     "paged_mq_attention",
     "EXPERT_AXIS",
     "held_expert_layer",

@@ -2957,6 +2957,11 @@ class DecodeEngine:
                 "prefix_evictions": self._pool.evictions
                 - self._evictions_base,
                 "cow_copies": self.cow_copies}
+        if self._progs.bytes_per_slot:
+            # what the model keeps per SEQUENCE beside the blocks (a
+            # recurrent state): fixed bytes a slot, live or not
+            pool["slot_state_bytes_per_device"] = (
+                self.config.slots * self._progs.bytes_per_slot // self._tp)
         if self._kv_quant:
             # quant surface, present only on kv_quant=int8 engines (an
             # off-quant engine's stats dict stays byte-for-byte — the

@@ -8,9 +8,25 @@ construction the engine resolves its own knobs into an
 :class:`ServingPrograms`: the cache layout (one device array a *pool*)
 and the jitted programs over it. ``TransformerLM`` answers with two
 ``[L, N + 1, Bs, d_model]`` K/V pools (and two scale arrays under
-``kv_quant="int8"``); ``LongCatLM`` with one ``[2 L, N + 1, Bs, 640]``
-latent pool (a 576-value row padded to whole 128-lane tiles) and a small
-array of routing counters.
+``kv_quant="int8"``); ``LongCatLM`` and ``DeepSeekV3LM`` with one
+``[sublayers, N + 1, Bs, 640]`` latent pool (a 576-value row padded to
+whole 128-lane tiles) and a small array of counters; ``LingLM`` with a
+latent pool for its one latent layer in six, then two pools indexed by
+SLOT and not by block (``[6, slots, 32, 128, 128]`` float32 recurrent
+states, ``[6, slots, 3, 12288]`` convolution tails), then the counters.
+
+Two kinds of pool. A BLOCK pool's second axis is the block id
+(``N + 1``, block 0 scratch): the engine's allocator hands blocks out and
+the block tables name them. **Pool 0 is always a block pool**: the engine
+reads block size, block shape and dtype off ``_pools[0]`` (admission
+bounds, the transfer plane). A SLOT pool's second axis is the slot: no
+allocator, no table; its life cycle is the model's programs' own: a
+slot's rows are reset by the chunk that starts a prompt (``off == 0``),
+carried by later chunks and steps, left bit-identical by a step in which
+the slot is not ``active`` and by a chunk's padded rows, and never read
+between requests (warm-up's chunk, whose table names only the scratch
+block, leaves them as they are). ``bytes_per_slot`` is what ``stats()``
+needs to account them.
 
 Calling convention, the same for every model (``*pools`` in the order
 of ``ServingPrograms.pools``; block tables, tokens, positions and masks
@@ -92,12 +108,16 @@ class ServingPrograms:
     # cache, zero until the block is written: ``stats()`` counts the
     # blocks with a scale in any of them (``quant_scale_blocks``)
     scale_pools: Tuple[int, ...] = ()
+    # device bytes a SLOT holds whatever its length, all slot pools
+    # (a recurrent state): ``stats()["slot_state_bytes_per_device"]``
+    bytes_per_slot: int = 0
 
 
 def refuse(who: str, spec: EngineSpec, **lacking: str) -> None:
     """Fail construction when ``spec`` asks for a feature named in
     ``lacking`` (feature -> why the model lacks it)."""
-    asked = {"kv_quant": spec.kv_quant != "none",
+    asked = {"prefix_cache": spec.prefix,
+             "kv_quant": spec.kv_quant != "none",
              "param_quant": spec.param_quant != "none",
              "decode_tp": spec.tp > 1,
              "spec_k": spec.spec_k > 0,

@@ -504,6 +504,89 @@ def test_latent_cell_programs_fit_the_chip(one_chip, monkeypatch, cell,
             assert view not in text, view
 
 
+@pytest.mark.parametrize("program,temp_mb", [("step", 150), ("chunk", 850)])
+def test_ling_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
+                                         temp_mb):
+    """The two programs of ``serve_ling3_ep4_closed128``
+    (``ling3-flash-ep4.json`` under ``closed128_p1024_gen768.json``: 10.5
+    GB of weights, 1.61 GB of float32 KDA states a slot, 57 MB of conv
+    tails, a 0.29 GB latent pool): weights and the three pools are
+    arguments, every pool is aliased (a copy of the states is 1.6 GB a
+    step), no copy of the state pool or of one layer's slab of it
+    appears, temporaries are bounded, arguments plus temporaries stay
+    inside the chip, and the step runs one ``paged_mq_attention`` for its
+    one latent layer and one ``kda_step_pool`` for each of its six KDA
+    layers, with no layer's slab of the state pool ever cut out."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import ling
+    from multiverso_tpu.serving.programs import EngineSpec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "ling3-flash-ep4.json")) as fh:
+        cfg = ling.config_from_dict(json.load(fh), 1)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "closed128_p1024_gen768.json")) as fh:
+        t = json.load(fh)
+    S, Bs, C = t["slots"], 16, t["prefill_token_budget"]
+    T = t["max_prompt"] + t["max_new"]
+    M = -(-T // Bs)
+    import multiverso_tpu.ops  # noqa: F401  (registers the submodule)
+
+    # serving_programs asks the backend, the CPU here: the test answers
+    # for it, for both kernels of the step
+    for module in ("paged_attention", "kda"):
+        monkeypatch.setattr(sys.modules[f"multiverso_tpu.ops.{module}"],
+                            "_on_tpu", lambda: True)
+    lm = object.__new__(ling.LingLM)            # no weights drawn
+    lm.config = cfg
+    progs = lm.serving_programs(EngineSpec(
+        name="cell", slots=S, max_prompt=t["max_prompt"],
+        max_new=t["max_new"], cache_len=T, block_size=Bs, blocks_per_seq=M,
+        pool_blocks=S * M, budget=C, prefix=False, tp=1, mesh=None,
+        kv_quant="none", param_quant="none", spec_k=0, prefill_sp="none",
+        donate=True))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place,
+                          jax.eval_shape(lambda: ling.init_params(cfg)))
+    shapes = [shape for shape, _ in progs.pools]
+    assert shapes[:3] == [(1, S * M + 1, Bs, 640), (6, S, 32, 128, 128),
+                          (6, S, 3, 12288)]
+    assert progs.bytes_per_slot == 6 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+    pools = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in progs.pools]
+    bt = _ints(one_chip, S, M)
+    if program == "step":
+        args = (params, *pools, bt, _ints(one_chip, S), _ints(one_chip, S),
+                jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip))
+        compiled = progs.step.lower(*args).compile()
+    else:
+        args = (params, *pools, bt, _ints(one_chip), _ints(one_chip, C),
+                _ints(one_chip), _ints(one_chip))
+        compiled = progs.chunk.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    donated = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+                  for shape, dtype in progs.pools[:3])
+    assert donated > 1.9e9 and mem.alias_size_in_bytes >= donated
+    assert mem.temp_size_in_bytes < temp_mb * 10 ** 6
+    assert mem.argument_size_in_bytes > 12.4e9      # weights + pools
+    assert _fits_hbm(compiled, budget=15.75 * 2 ** 30)
+    text = compiled.as_text()
+    for copied in (f"copy(f32[6,{S},32,128,128]", f"copy(f32[{S},32,128,128]",
+                   f"copy(bf16[1,{S * M + 1},{Bs},640]"):
+        assert copied not in text, copied
+    if program == "step":
+        # one paged_mq_attention for the latent layer, one kda_step_pool
+        # a KDA layer: each state read once and written once, in place
+        assert _kernel_calls(compiled) == 1 + cfg.n_kda_layers == 7
+        assert f"f32[{S},32,128,128]" not in text   # no slab of the pool
+
+
 _COLLECTIVES = ("all-reduce(", "all-gather(", "all-to-all(",
                 "collective-permute(", "reduce-scatter(")
 
