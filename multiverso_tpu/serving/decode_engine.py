@@ -60,9 +60,10 @@ pool) and ONE admission path (chunked prefill):
 * **chunked, budget-bounded admission** — an arriving prompt prefills
   in fixed-size chunks (:func:`models.transformer.prefill_chunk_paged`,
   K/V written straight into its reserved blocks), AT MOST ONE chunk per
-  iteration interleaved with the fused decode step. Inter-token latency
-  for in-flight generations is therefore bounded by one budget-sized
-  chunk of work regardless of the arriving prompt's length (the
+  iteration interleaved with the fused decode step, and the loop at
+  most one iteration ahead of the device. Inter-token latency for
+  in-flight generations is therefore bounded by two budget-sized chunks
+  of work regardless of the arriving prompt's length (the
   Sarathi-Serve stall-free schedule), and a long prompt's TTFT
   amortizes across iterations instead of blocking the world. The chunk
   size is the ``prefill_token_budget`` config knob; its fixed shape
@@ -91,9 +92,17 @@ pool) and ONE admission path (chunked prefill):
   *before* speculation, preserving the prefix-cache one-write-site
   contract. ``spec_k=0`` is today's one-token path, bit-for-bit.
 * **iteration-granular completion** — a slot frees the moment its
-  sequence emits ``eos_id`` or reaches its per-request ``max_new``;
-  the finished tokens resolve the caller's Future immediately and the
-  slot is reusable on the next iteration.
+  sequence's ``eos_id`` or its per-request ``max_new``-th token is
+  booked; the finished tokens resolve the caller's Future immediately
+  and the slot is reusable on the next iteration.
+* **one pass ahead of the host** — a loop pass dispatches its step and
+  its chunk FIRST and only then syncs and books what the pass before
+  dispatched: the step takes its tokens from the last step's output on
+  the device, positions advance at dispatch, and the host's late syncs,
+  bookings and records run under device work. The depth (one, or zero
+  behind a drain) is decided each pass from state the loop holds;
+  outputs are token-identical to a drained loop (docs/SERVING.md "The
+  order inside one iteration").
 * **overload-graceful scheduling** (``-preempt``, default on) —
   requests carry a tenant ``priority`` class and an
   optional ``deadline_s``. The queue is a set of per-priority FIFO
@@ -639,7 +648,6 @@ class _StepInFlight(NamedTuple):
     spec_toks: Optional[np.ndarray]
     n_valid: Optional[np.ndarray]
     t0: float                       # monotonic, before growth and drafts
-    launch_ms: float                # growth, drafts and the dispatch
 
 
 class _ChunkInFlight(NamedTuple):
@@ -649,6 +657,8 @@ class _ChunkInFlight(NamedTuple):
     off: int
     n: int                          # real tokens of the chunk
     size: int                       # the program's chunk shape
+    index: int                      # which chunk of the prompt
+    final: bool                     # the prompt's last: the slot lands
     t0: float                       # monotonic at dispatch (tracing only)
 
 
@@ -857,6 +867,19 @@ class DecodeEngine:
         if self._chunk_fn is None:
             Log.fatal(f"DecodeEngine {name!r}: the model has no prefill "
                       f"chunk program")
+        # the engine's own program: a step's input tokens, merged on the
+        # device from the last step's output and the host's tokens of
+        # the slots that went live since (-1 elsewhere). Its output and
+        # a step's are the ONE kind of ``tok`` the step ever sees
+        # (warm-up included), so the step keeps one compiled trace
+        tok_target = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        if self._decode_mesh is not None:
+            tok_target = jax.sharding.NamedSharding(
+                self._decode_mesh, jax.sharding.PartitionSpec())
+        def merge_tokens(prev, host):
+            return jnp.where(host >= 0, host, prev)
+
+        self._merge_fn = jax.jit(merge_tokens, out_shardings=tok_target)
 
         # -- device state (owned by the loop thread after start) -------------
         # committed placement from birth: the programs' traces are
@@ -880,6 +903,20 @@ class DecodeEngine:
         self._tok = np.zeros(S, np.int32)
         self._pos = np.zeros(S, np.int32)
         self._active = np.zeros(S, bool)
+        # the last step's tokens, on the device, and the slots whose
+        # input token is newer on the host (``_tok``): those that went
+        # live since that step was dispatched
+        self._dev_tok = jax.device_put(jnp.zeros(S, jnp.int32), tok_target)
+        self._fresh = np.zeros(S, bool)
+        # steps a live slot may still be dispatched in: with spec_k == 0
+        # a step emits one token whatever it is, so a request that
+        # reaches max_new with steps in flight is known by count and
+        # left out of the next step without reading anything
+        self._left = np.zeros(S, np.int32)
+        # programs dispatched and not yet retired, oldest first: at most
+        # one step and one chunk between passes (the pass before's),
+        # two of each inside a pass
+        self._flight: Deque = collections.deque()
         # the one admission currently prefilling in chunks (its slot is
         # reserved — excluded from the free pool — but not yet live)
         self._pf: Optional[_Request] = None
@@ -1016,6 +1053,8 @@ class DecodeEngine:
         self._it_sp_chunks = 0
         self._it_live_blocks = -1
         self._it_behind = 0
+        self._it_ahead = 0
+        self._it_step_ms = 0.0
         self.completed = 0
         self.shed = 0
         self.tokens = 0
@@ -1055,6 +1094,16 @@ class DecodeEngine:
         # the bench window
         self.prefill_chunks = 0
         self.chunks_behind_step = 0
+        # steps dispatched, those of them dispatched with the step
+        # before still unread (its tokens taken from the device), and
+        # the times the loop retired what was in flight ahead of its
+        # turn, by cause; reset with the bench window
+        self.steps = 0
+        self.steps_ahead = 0
+        self.drains: Dict[str, int] = {}
+        # when the last step was booked: a step's ledger charge starts
+        # there or at its own dispatch, whichever is later
+        self._t_booked = 0.0
         # overload mirrors (the PREEMPTIONS/DEADLINE_DROPS counters
         # stay monotonic; these reset with the bench window):
         # preemption EVENTS, distinct requests preempted at least
@@ -1308,7 +1357,7 @@ class DecodeEngine:
             "params_stale": self._manager.params_stale(
                 stale_after, age_s=params_age),
             "live_seqs": int(self._active.sum())
-            + (1 if self._pf is not None else 0),
+            + (1 if self._pf is not None else 0) + len(self._landing()),
             "active_slots": int(self._active.sum()),
             "queue_depth": depth,
             "queue_age_s": age,
@@ -1337,7 +1386,8 @@ class DecodeEngine:
     def pool_drift(self) -> Optional[str]:
         """Paged-KV accounting sanity: allocator invariant violations,
         or live blocks held while NOTHING is alive to hold them (no
-        active slot, no admission mid-prefill, nothing queued).
+        active slot, no admission mid-prefill, nothing queued, no
+        program in flight).
         Refcounted sharing
         is NOT a leak: ``n_live`` counts blocks with holders exactly
         once however many sequences share them, and prefix-cached
@@ -1353,7 +1403,7 @@ class DecodeEngine:
         # lost reservation
         live_blocks = self._pool.n_live - len(self._squeezed)
         if (live_blocks > 0 and not self._active.any()
-                and self._pf is None and not self._q):
+                and self._pf is None and not self._q and not self._flight):
             return (f"{live_blocks} live block(s) with zero live "
                     f"sequences (leaked reservation)")
         return None
@@ -1466,22 +1516,28 @@ class DecodeEngine:
 
     def _loop(self) -> None:
         """The loop thread: wait for work, then one :meth:`_iteration`
-        a pass. Every program a pass dispatches is retired in that pass
-        (at most one step and one chunk in flight, the chunk queued
-        behind the step), so between passes, and wherever ``stop()``,
-        ``_maybe_refresh`` and ``_preempt`` run, nothing of the engine
-        is in flight."""
+        a pass. The loop keeps the device ONE PASS AHEAD: a pass
+        dispatches its step and its chunk first and only then retires
+        (syncs and books) what the pass before left in flight, so the
+        device has its next program queued when one ends, and the
+        host's syncs, bookings and records run under device work.
+        Between passes at most one step and one chunk are in flight
+        (``_flight``), inside a pass at most two of each. Wherever
+        ``stop()``, ``_maybe_refresh``, ``_preempt``, a KV splice and
+        the idle wait run, nothing a pass before dispatched is in
+        flight: :meth:`_drain` retired it first."""
         while True:
             splices: List[tuple] = []
             with self._cv:
                 while (not self._q and self._pf is None
-                       and not self._active.any()
+                       and not self._active.any() and not self._flight
                        and not self._loop_work
                        and not self._stop.is_set()):
                     with trace.phase("engine.wait"):
                         self._cv.wait()
                 if (self._stop.is_set() and not self._q
-                        and self._pf is None and not self._active.any()):
+                        and self._pf is None and not self._active.any()
+                        and not self._flight):
                     # release any splice waiters before the loop dies —
                     # a blocked replica drain thread must not hang on a
                     # transfer the loop will never apply
@@ -1495,14 +1551,15 @@ class DecodeEngine:
                     self._loop_work.clear()
             # Phases on the profiler's clock (trace.phase; the shared
             # NULL_SPAN with no session). With something live,
-            # prefilling or to splice the pass is sure of work and is
-            # ONE engine.iter, to the end of _record_iteration. From
-            # idle the queue decides: an arrival makes it an iteration
-            # (the pop itself, microseconds, is in neither), and a pass
-            # that finds only block-starved or expired waiters is an
-            # engine.wait
+            # prefilling, in flight or to splice the pass is sure of
+            # work and is ONE engine.iter, to the end of
+            # _record_iteration. From idle the queue decides: an arrival
+            # makes it an iteration (the pop itself, microseconds, is in
+            # neither), and a pass that finds only block-starved or
+            # expired waiters is an engine.wait
             arrival, expired = None, []
-            if not (splices or self._pf is not None or self._active.any()):
+            if not (splices or self._pf is not None or self._active.any()
+                    or self._flight):
                 if self._free_q:
                     arrival, expired = self._pop_admissible()
                 if arrival is None:
@@ -1522,32 +1579,43 @@ class DecodeEngine:
 
     def _iteration(self, splices: List[tuple],
                    arrival: Optional[_Request]) -> bool:
-        """One pass that does work. Nothing is in flight on entry and
-        nothing on exit; in between the device is handed its programs
-        back to back and the host's own work runs under them:
+        """One pass that does work: it hands the device THIS pass's
+        programs and then retires the LAST pass's, so everything the
+        host does between two dispatches runs under programs already
+        queued:
 
-        1. ``engine.step``: grow reservations (preempting under
-           pressure), propose drafts, dispatch the step (or verify
-           window) over the slots live NOW. No wait.
-        2. ``engine.admit``: KV splices, then pop and reserve: full
-           prefix hits go live (they join the NEXT pass's step), the
-           first admission that needs prefill becomes ``_pf``.
+        1. ``engine.step``: grow reservations, propose drafts, dispatch
+           the step (or verify window) over the slots live NOW that
+           their ``max_new`` has not counted out. The step's tokens are
+           the last step's output, still on the device, merged with the
+           host's for the slots that went live since. Positions and the
+           by-count budget advance here. No wait.
+        2. ``engine.admit``: pop and reserve: full prefix hits go live
+           (they join the NEXT pass's step), the first admission that
+           needs prefill becomes ``_pf``. (Work parked for the loop
+           thread, a KV splice or a warm-up, has an ``engine.admit`` of
+           its own BEFORE the step, behind a drain.)
         3. ``engine.prefill_chunk``: build and dispatch ONE budget-sized
-           chunk of ``_pf``, queued on the device behind the step. No
-           wait.
+           chunk of ``_pf``, queued on the device behind the step; the
+           prompt's offset advances here, and after its last chunk
+           ``_pf`` is free for the next admission. No wait.
         4. ``engine.step.sync`` then ``engine.step.book``: fetch the
-           step's tokens and book them against the slots it was
-           dispatched with, while the device runs the chunk.
-        5. ``engine.prefill_chunk.sync``: retire the chunk (its pools,
-           or a prompt's last chunk's logits), then the first token and
-           the slot going live.
+           tokens of the step the pass BEFORE dispatched and book them
+           against the slots it was dispatched with.
+        5. ``engine.prefill_chunk.sync``: retire the chunk the pass
+           before dispatched: prefix registration and, after a prompt's
+           last chunk, the first token and the slot going live (it
+           joins the next pass's step).
         6. ``engine.record``.
 
-        With nothing live there is no step and the chunk is dispatched
-        and retired at once; with nothing prefilling the step is
-        dispatched, synced and booked. A slot freed by the booking in 4
-        takes its next admission in the next pass. Returns False when
-        the pass failed and the loop must die."""
+        The depth is decided each pass from what the loop holds. A pass
+        starts with a :meth:`_drain` (4 and 5 come first, nothing of an
+        earlier pass is in flight from there) when
+        :meth:`_drain_cause` names a cause, and again when growth
+        would have to preempt; a ``spec_k`` engine, where acceptance
+        decides the positions, retires its own programs in 4 and 5 of
+        the same pass. Returns False when the pass failed and the loop
+        must die."""
         # the progress clock restarts when the loop picks work up:
         # last_iter_age_s then measures how long THIS pass has been
         # stuck, not how long the engine idled beforehand (an idle
@@ -1560,35 +1628,53 @@ class DecodeEngine:
         self._it_spec_proposed = self._it_spec_accepted = 0
         self._it_sp_chunks = 0
         self._it_live_blocks = -1
-        self._it_behind = 0
-        step_ms = 0.0
+        self._it_behind = self._it_ahead = 0
+        self._it_step_ms = 0.0
         arrivals = [] if arrival is None else [arrival]
+        worked = bool(self._flight or splices)
         try:
+            cause = self._drain_cause(splices) if self._flight else None
+            if cause is not None:
+                self._drain(cause)
+            if splices:
+                # inbound KV transfers (and a warm-up) apply OUTSIDE the
+                # engine lock on this (loop) thread — the only thread
+                # allowed to reassign the donated caches — with nothing
+                # in flight. A bad payload degrades (accounting says
+                # so); the waiter is released either way
+                with trace.phase("engine.admit"):
+                    for work, done, info in splices:
+                        try:
+                            info.update(work())
+                        except Exception as exc:    # pragma: no cover
+                            info["skipped"] = (f"failed on the loop "
+                                               f"thread: {exc}")
+                            info["error"] = exc
+                        finally:
+                            done.set()
+                    # a splice's own program has no step or chunk behind
+                    # it to be waited by: retired here (microseconds of
+                    # device work), so that an empty ``_flight`` means an
+                    # idle device
+                    jax.block_until_ready(self._pools)
             step = chunk = None
-            if self._active.any():
+            if self._running().any():
                 with trace.phase("engine.step"):
                     step = self._dispatch_step()
-            # the pools as the step's sync retires them (with no step:
-            # as the last pass left them, retired)
-            retired = self._pools
+                if step is None and self._flight:
+                    # growth met a dry pool with programs in flight:
+                    # their bookings may free blocks, and a victim's
+                    # emitted tokens are booked before it is requeued
+                    self._drain("preempt")
+                    with trace.phase("engine.step"):
+                        step = self._dispatch_step()
             with trace.phase("engine.admit"):
-                # inbound KV transfers apply OUTSIDE the engine lock on
-                # this (loop) thread — the only thread allowed to
-                # reassign the donated caches. A bad payload degrades
-                # (accounting says so); the waiter is released either way
-                for work, done, info in splices:
-                    try:
-                        info.update(work())
-                    except Exception as exc:    # pragma: no cover
-                        info["skipped"] = f"failed on the loop thread: {exc}"
-                        info["error"] = exc
-                    finally:
-                        done.set()
                 self._admit(arrivals)
             if self._pf is not None:
-                # AT MOST one budget-sized chunk per iteration: the
-                # stall an admission can add to every live generation's
-                # next token is one chunk of work
+                # AT MOST one budget-sized chunk per iteration, and at
+                # most one pass of programs ahead: what an admission can
+                # add to a live generation's next token is two chunks
+                # and two steps of device work, whatever the arrivals
                 with trace.phase("engine.prefill_chunk"):
                     chunk = self._dispatch_chunk()
                 if step is not None:
@@ -1596,28 +1682,71 @@ class DecodeEngine:
                     # its launch falls under the step's run
                     self.chunks_behind_step += 1
                     self._it_behind = 1
-            live = int(self._active.sum()) + (self._pf is not None)
+            live = (int(self._active.sum()) + (self._pf is not None)
+                    + len(self._landing()))
             if live > self.peak_live:
                 self.peak_live = live
-            if step is not None:
-                step_ms = self._retire_step(step)
-            if chunk is not None:
-                self._retire_chunk(chunk)
-            elif self._pools is not retired:
-                # a copy-on-write or a splice with no chunk after it to
-                # wait on: retired here too (microseconds of device
-                # work, long done when a step was booked meanwhile)
-                jax.block_until_ready(self._pools)
+            # what earlier passes left in flight, oldest first, under
+            # this pass's programs
+            self._retire((step is not None) + (chunk is not None))
+            if self._spec and self._flight:
+                self._drain("spec")
         except Exception as exc:          # pragma: no cover - defensive
             # arrivals are already popped from the queue but may not
             # be slotted yet — include them so their futures fail too
             self._fail_all(exc, arrivals)
             return False
-        if (step is not None or chunk is not None or splices
+        if (worked or step is not None or chunk is not None
                 or self._it_admitted):
             with trace.phase("engine.record"):
-                self._record_iteration(t_work0, step_ms)
+                self._record_iteration(t_work0)
         return True
+
+    def _drain_cause(self, splices: List[tuple]) -> Optional[str]:
+        """Why this pass may not dispatch ahead of what is in flight
+        (None: it may), from state the loop holds: work parked for the
+        loop thread (a KV splice, a warm-up), or nothing live and
+        nothing prefilling, so that what is in flight (a prompt's last
+        chunk, a step that ran its slots past an eos) is all the engine
+        has: it is retired before the loop stops, goes idle or admits
+        into an empty engine, where ``_maybe_refresh`` may move the
+        pin."""
+        if splices:
+            return "splice"
+        if not self._active.any() and self._pf is None:
+            return "empty"
+        return None
+
+    def _drain(self, cause: str) -> None:
+        """Retire everything in flight, oldest first: depth 0 from here
+        to the next dispatch."""
+        self.drains[cause] = self.drains.get(cause, 0) + 1
+        self._retire(0)
+
+    def _retire(self, keep: int) -> None:
+        """Retire the oldest programs in flight down to the ``keep``
+        newest. An item leaves ``_flight`` only once it is booked: a
+        device error surfaces at its sync, and a prompt's last chunk is
+        all that still names its request (off ``_pf``, slot not live),
+        so ``_fail_all`` must find it there."""
+        while len(self._flight) > keep:
+            item = self._flight[0]
+            if isinstance(item, _StepInFlight):
+                self._retire_step(item)
+            else:
+                self._retire_chunk(item)
+            self._flight.popleft()
+
+    def _running(self) -> np.ndarray:
+        """The slots the next step runs: live, and not counted out by
+        the steps already dispatched for them."""
+        return self._active & (self._left > 0)
+
+    def _landing(self) -> List[_Request]:
+        """Requests whose prompt's last chunk is in flight: off ``_pf``
+        already, their slot not live yet."""
+        return [f.req for f in list(self._flight)
+                if isinstance(f, _ChunkInFlight) and f.final]
 
     def _admit(self, arrivals: List[_Request]) -> None:
         """Admission onto the explicit free-slot set. One admission
@@ -1647,7 +1776,7 @@ class DecodeEngine:
                 # spinning here
                 break
 
-    def _record_iteration(self, t_work0: float, step_ms: float) -> None:
+    def _record_iteration(self, t_work0: float) -> None:
         """One iteration retired: bump the progress clock/counters and
         append the flight-recorder record. Reads of queue/pool state are
         intentionally lock-light — these are gauge samples for the black
@@ -1673,7 +1802,8 @@ class DecodeEngine:
         except (IndexError, RuntimeError):   # racing a concurrent submit
             oldest = None
         recorder.record((
-            self.iters_total, now, (now - t_work0) * 1e3, step_ms,
+            self.iters_total, now, (now - t_work0) * 1e3,
+            self._it_step_ms,
             int(self._active.sum()), 1 if self._pf is not None else 0,
             len(self._q),
             0.0 if oldest is None else (now - oldest) * 1e3,
@@ -1707,7 +1837,10 @@ class DecodeEngine:
             if self._it_live_blocks >= 0 else -1.0,
             # overlap tail (FIELDS append at the END): 1 when this
             # pass's chunk was dispatched behind its in-flight step
-            self._it_behind))
+            self._it_behind,
+            # run-ahead tail (FIELDS append at the END): 1 when this
+            # pass's step was dispatched with the step before unread
+            self._it_ahead))
 
     def _xfer_block_shape(self) -> tuple:
         """One block of the first pool as the transfer plane ships it:
@@ -1738,12 +1871,13 @@ class DecodeEngine:
         bit-identical under the SAME params the first life pinned — so
         preemption extends the pin's lifetime across the eviction gap,
         and the surfaced trade is staleness, never a mixed-version
-        generation."""
+        generation. Nor while a program is in flight: a pass that
+        could move the pin starts with a drain (:meth:`_drain_cause`)."""
         snap = self._snap
         if snap is None:
             snap = self._manager.current()
         elif (not hold and not self._active.any() and self._pf is None
-                and self._q.n_resumed == 0):
+                and self._q.n_resumed == 0 and not self._flight):
             snap = self._manager.ensure_fresh(self.config.max_staleness_s)
         if self._snap is not snap or self._pinned is None:
             # the decode copy memoizes on snapshot VERSION: a drain/
@@ -1952,10 +2086,7 @@ class DecodeEngine:
             # t_enq, a queued full hit would bleed its whole queue wait
             # into the ITL histogram (review-found, regression-tested)
             req.t_last = req.t_admit
-            self._slot_req[slot] = req
-            self._tok[slot] = int(req.prompt[-1])
-            self._pos[slot] = len(req.prompt) - 1
-            self._active[slot] = True
+            self._go_live(req, int(req.prompt[-1]), len(req.prompt) - 1)
             self._pf = None
             return
         # chunked prefill starts at the first UNCACHED token (block-
@@ -1968,45 +2099,41 @@ class DecodeEngine:
         req.sp = self._sp and len(req.prompt) >= self._sp_threshold
         self._pf = req
 
+    def _go_live(self, req: _Request, tok: int, pos: int) -> None:
+        """``req``'s slot joins the next step dispatched: its input
+        token is the host's (``_fresh``), and it may be dispatched in
+        as many steps as it has tokens left to emit."""
+        slot = req.slot
+        self._slot_req[slot] = req
+        self._tok[slot] = tok
+        self._fresh[slot] = True
+        self._pos[slot] = pos
+        self._left[slot] = req.max_new - len(req.out)
+        self._active[slot] = True
+
     def _dispatch_chunk(self) -> _ChunkInFlight:
         """Build and dispatch ONE budget-sized chunk of the in-flight
-        admission's prefill; no wait."""
+        admission's prefill; no wait. The prompt's offset and the
+        prefill accounting advance here; after the prompt's last chunk
+        ``_pf`` is free for the next admission, and the request lands
+        (first token, slot live) when the chunk is retired."""
         req = self._pf
-        C = self._sp_chunk if req.sp else self._budget
+        sp = req.sp
+        C = self._sp_chunk if sp else self._budget
         off = req.pf_off
         n = min(C, len(req.prompt) - off)
         toks = np.zeros(C, np.int32)
         toks[: n] = req.prompt[off: off + n]
         t0 = time.monotonic() if trace.enabled() else 0.0
-        chunk_fn = self._chunk_sp_fn if req.sp else self._chunk_fn
-        # the program gets its OWN block tables: the step's booking
-        # runs under the chunk and resets the rows of the slots it
-        # frees, and a dispatched program may read a host array late
+        chunk_fn = self._chunk_sp_fn if sp else self._chunk_fn
+        # the program gets its OWN block tables: bookings run under the
+        # chunk and reset the rows of the slots they free, and a
+        # dispatched program may read a host array late
         *pools, logits = chunk_fn(
             self._pinned, *self._pools, self._block_tables.copy(),
             np.int32(req.slot), toks, np.int32(off), np.int32(n))
         self._pools = tuple(pools)
         self.prefill_chunks += 1
-        return _ChunkInFlight(req, logits, off, n, C, t0)
-
-    def _retire_chunk(self, chunk: _ChunkInFlight) -> None:
-        """Wait for the chunk dispatched earlier in this pass and book
-        it; on the final chunk the first token falls out and the slot
-        goes live (or resolves immediately on eos-at-first-token, never
-        occupying the slot)."""
-        req, logits, off, n, C, t0 = chunk
-        sp = req.sp
-        tracing = trace.enabled()
-        # retired in the pass that dispatched it: letting chunk
-        # dispatches run ahead asynchronously looks free, but an
-        # idle->busy transition can queue several chunks on the device
-        # and the NEXT fused step's sync pays for all of them at once —
-        # exactly the unbounded ITL spike the budget exists to prevent
-        # (measured: p99 went from ~1 chunk+step to >100 ms under ramp).
-        # At most one step and one chunk are in flight, none between
-        # passes, which keeps the bound honest.
-        with trace.phase("engine.prefill_chunk.sync"):
-            jax.block_until_ready(self._pools[0])
         req.pf_off = off + n
         req.pf_chunks += 1
         if sp:
@@ -2024,6 +2151,37 @@ class DecodeEngine:
                 # preemption tax (still counted in prefill_tokens: the
                 # conservation identity tracks FLOPs actually spent)
                 req.usage.recompute_tokens += n
+        final = req.pf_off >= len(req.prompt)
+        if final:
+            self._pf = None
+        chunk = _ChunkInFlight(req, logits, off, n, C, req.pf_chunks - 1,
+                               final, t0)
+        self._flight.append(chunk)
+        return chunk
+
+    def _retire_chunk(self, chunk: _ChunkInFlight) -> None:
+        """Wait for a chunk dispatched a pass ago (by its own logits:
+        the pools have later programs queued on them) and book it: its
+        completed blocks gain their content identity, and after the
+        prompt's last chunk the first token falls out and the slot goes
+        live (or resolves immediately on eos-at-first-token, never
+        occupying the slot). Waiting for EVERY chunk, one pass after
+        its dispatch, is the in-flight bound: letting chunk dispatches
+        run on asynchronously looks free, but an idle->busy transition
+        can queue several chunks on the device and the next step's sync
+        pays for all of them at once — exactly the unbounded ITL spike
+        the budget exists to prevent (measured: p99 went from ~1
+        chunk+step to >100 ms under ramp). With at most one pass of
+        programs ahead, a live generation's next token waits for at
+        most two steps and two chunks."""
+        req, logits, off, n, C, index, final, t0 = chunk
+        tracing = trace.enabled()
+        with trace.phase("engine.prefill_chunk.sync"):
+            # final chunk: the prompt's last real position's logits are
+            # the first generated token (exactly a whole-prompt
+            # prefill's gather); a pf_only prompt's fall on the floor
+            logits = (np.asarray(logits) if final and not req.pf_only
+                      else jax.block_until_ready(logits))
         if self._prefix:
             # every prompt block this chunk COMPLETED gains its content
             # identity now, not at release: a concurrent same-prefix
@@ -2031,20 +2189,19 @@ class DecodeEngine:
             # (register no-ops when an identical block beat us to it)
             hashes = self._req_hashes(req)
             while (req.pf_reg < len(hashes)
-                   and (req.pf_reg + 1) * self._block_size <= req.pf_off):
+                   and (req.pf_reg + 1) * self._block_size <= off + n):
                 self._pool.register(req.blocks[req.pf_reg],
                                     hashes[req.pf_reg])
                 req.pf_reg += 1
-        final = req.pf_off >= len(req.prompt)
         if tracing and req.ctx is not None:
             # seqpar ENGINES annotate every chunk span (sp=0 marks a
             # below-threshold prompt on the single-lane program); off-sp
             # engines' spans stay flat — the metrics regression contract
-            sp_attrs = ({"sp": int(sp), "sp_backend": self._sp_backend}
+            sp_attrs = ({"sp": int(req.sp), "sp_backend": self._sp_backend}
                         if self._sp else {})
             trace.record_span(
                 "decode.prefill_chunk", req.ctx, t0, time.monotonic(),
-                slot=req.slot, offset=off, chunk=req.pf_chunks - 1,
+                slot=req.slot, offset=off, chunk=index,
                 tokens=n, budget=C, **sp_attrs)
         if not final:
             return
@@ -2054,13 +2211,8 @@ class DecodeEngine:
             # logits fall on the floor by design: the decode side
             # recomputes P-1 through its own full-hit CoW step, which
             # is what keeps disaggregated output bit-identical.
-            self._pf = None
             self._finish_prefill_only(req, chunks=req.pf_chunks)
             return
-        # final chunk: the prompt's last real position's logits are the
-        # first generated token (exactly a whole-prompt prefill's gather)
-        with trace.phase("engine.prefill_chunk.sync"):
-            logits = np.asarray(logits)
         tok0 = int(np.argmax(logits))
         now = time.monotonic()
         if req.resumed:
@@ -2099,7 +2251,6 @@ class DecodeEngine:
                 "decode.admit", req.ctx, req.t_admit, now, slot=req.slot,
                 prompt_len=len(req.prompt), chunks=req.pf_chunks,
                 budget=C, snapshot_version=req.version, **extra)
-        self._pf = None
         if self._finished(req, tok0):
             # slot never goes live; the inserted K/V is dead weight a
             # later admission overwrites (tested) — slot and blocks
@@ -2107,10 +2258,7 @@ class DecodeEngine:
             self._release_seq(req)
             self._resolve(req)
             return
-        self._slot_req[req.slot] = req
-        self._tok[req.slot] = tok0
-        self._pos[req.slot] = len(req.prompt)
-        self._active[req.slot] = True
+        self._go_live(req, tok0, len(req.prompt))
 
     def _finish_prefill_only(self, req: _Request, chunks: int) -> None:
         """Prefill-only admission complete (disaggregated stage 1): the
@@ -2312,7 +2460,7 @@ class DecodeEngine:
         reqs = [r for r in self._slot_req if r is not None]
         if self._pf is not None:
             reqs.append(self._pf)
-        return reqs
+        return reqs + self._landing()
 
     def _pick_victim(self, grower: _Request) -> Optional[_Request]:
         """Preemption victim policy: among admitted sequences (live
@@ -2405,10 +2553,10 @@ class DecodeEngine:
         with self._cv:
             self._q.appendleft(req)
 
-    def _ensure_growth(self, n_valid) -> None:
+    def _ensure_growth(self, n_valid) -> bool:
         """Optimistic admission's decode-time half: before the fused
-        step (or verify window) dispatches, every live slot's
-        reservation must cover the positions THIS iteration writes —
+        step (or verify window) dispatches, every slot it will run has
+        a reservation that covers the positions THIS step writes —
         ``pos .. pos + window - 1``. Growth is allocator work plus a
         block-table row append (traced data, never a shape). On pool
         exhaustion it preempts via :meth:`_pick_victim`; when no
@@ -2418,9 +2566,11 @@ class DecodeEngine:
         operation that is never the oldest, whose growth the floor
         guarantees. Growers run highest-class-oldest-first, so the
         important/old sequences claim blocks before the preemptible
-        ones."""
+        ones. Returns False, with nobody preempted, when it would have
+        to preempt while programs are in flight: the caller retires
+        them and calls again (what was grown stays grown)."""
         order = [s for s in range(self.config.slots)
-                 if self._slot_req[s] is not None]
+                 if self._slot_req[s] is not None and self._left[s] > 0]
         order.sort(key=lambda s: (-self._slot_req[s].priority,
                                   self._slot_req[s].t_enq))
         for s in order:
@@ -2444,43 +2594,54 @@ class DecodeEngine:
                     req.blocks.extend(blocks)
                     self._block_tables[s][base: base + grow] = blocks
                     break
+                if self._flight:
+                    return False
                 victim = self._pick_victim(req)
                 if victim is None:
                     self._preempt(req, why="yield: no admissible victim")
                     break
                 self._preempt(victim, why=f"growth for rid {req.rid}")
+        return True
 
     def _dispatch_step(self) -> Optional[_StepInFlight]:
-        """Grow every live reservation, propose drafts and dispatch the
-        fused step (or the verify window) over the slots live NOW; no
-        wait. None when growth preempted every live slot. The program
-        gets COPIES of the host arrays and the booking gets the slots'
-        requests as they stand here: admission runs while the step is in
-        flight and writes block tables, ``_tok``, ``_pos`` and
-        ``_active`` (a full prefix hit goes live at once), and a
-        dispatched program may read a host array late."""
+        """Grow every reservation the step needs, propose drafts and
+        dispatch the fused step (or the verify window) over the slots
+        live NOW whose ``max_new`` has not counted out; no wait. None
+        when growth preempted every such slot, or when it would have to
+        preempt with programs in flight (they are still in flight then:
+        the caller drains and calls again). The program gets COPIES of
+        the host arrays and the booking gets the slots' requests as
+        they stand here: admission and the bookings of earlier steps
+        run while this one is in flight and write block tables,
+        ``_tok``, ``_pos`` and ``_active``, and a dispatched program may
+        read a host array late. The input tokens never come to the
+        host first: they are the last step's output on the device, but
+        for the slots that went live since. With ``spec_k == 0`` a slot
+        moves one position a step whatever its token, so positions and
+        the by-count budget advance HERE; a slot may so run one step
+        past an eos the host has not read yet: that step's write falls
+        in the request's own reservation (grown above), dead weight a
+        later admission overwrites behind it in device order, and its
+        token is dropped at the booking."""
         t0 = time.monotonic()
         spec_toks = n_valid = None
         if self._spec:
             spec_toks, n_valid = self._propose_drafts()
-        if self._preempt_on:
-            # grow every live reservation to cover this iteration's
-            # writes, preempting under pool pressure; a yield can
-            # deactivate slots (incl. every drafted one), so re-check
-            self._ensure_growth(n_valid if spec_toks is not None
-                                else None)
-            if not self._active.any():
-                return None
-        # blocks this step's attention reads: every live slot's
+        # grow every reservation to cover this step's writes, preempting
+        # under pool pressure; a yield can deactivate slots (incl. every
+        # drafted one), so the slots are read after it
+        if self._preempt_on and not self._ensure_growth(
+                n_valid if spec_toks is not None else None):
+            return None
+        running = self._running()
+        if not running.any():
+            return None
+        # blocks this step's attention reads: every running slot's
         # positions <= pos (host state the loop already holds)
         self._it_live_blocks = int(np.sum(
-            self._pos[self._active] // self._block_size + 1))
+            self._pos[running] // self._block_size + 1))
         self._live_blocks_sum += self._it_live_blocks
-        # host state (tok/pos/active and the block tables)
-        # feeds the jit as plain numpy: the same aval signature warmup()
-        # uses, so the two share one trace
-        tables = self._block_tables.copy()
-        pos, active = self._pos.copy(), self._active.copy()
+        tables, pos = self._block_tables.copy(), self._pos.copy()
         if spec_toks is not None:
             # fused verify: ONE forward scores every window position;
             # acceptance is decided at the booking on the host from the
@@ -2489,32 +2650,52 @@ class DecodeEngine:
             self.spec_steps += 1
             *pools, nxt = self._verify_fn(
                 self._pinned, *self._pools, tables, spec_toks, pos,
-                active, n_valid)
+                running, n_valid)
         else:
+            tok = self._dev_tok
+            if self._fresh.any():
+                tok = self._merge_fn(
+                    tok, np.where(self._fresh, self._tok, np.int32(-1)))
+                self._fresh[:] = False
             *pools, nxt, _ = self._step_fn(
-                self._pinned, *self._pools, tables, self._tok.copy(),
-                pos, active)
+                self._pinned, *self._pools, tables, tok, pos, running)
+            self._dev_tok = nxt
         self._pools = tuple(pools)
-        return _StepInFlight(nxt, list(self._slot_req), spec_toks, n_valid,
-                             t0, (time.monotonic() - t0) * 1e3)
+        if not self._spec:
+            self._pos[running] += 1
+            self._left[running] -= 1
+        self.steps += 1
+        if any(isinstance(f, _StepInFlight) for f in self._flight):
+            self.steps_ahead += 1
+            self._it_ahead = 1
+        step = _StepInFlight(
+            nxt, [r if on else None
+                  for r, on in zip(self._slot_req, running)],
+            spec_toks, n_valid, t0)
+        self._flight.append(step)
+        self._it_step_ms += (time.monotonic() - t0) * 1e3
+        return step
 
-    def _retire_step(self, step: _StepInFlight) -> float:
-        """Fetch the in-flight step's tokens and book them; returns the
-        host's milliseconds on the step (its launch, this wait and the
-        booking: the flight recorder's ``step_ms``)."""
+    def _retire_step(self, step: _StepInFlight) -> None:
+        """Fetch an in-flight step's tokens and book them. The flight
+        recorder's ``step_ms`` is the host's milliseconds on steps in a
+        pass: the launch of the one it dispatched, the wait and the
+        booking of the one it retired."""
         t1 = time.monotonic()
         with trace.phase("engine.step.sync"):
             nxt = np.array(step.nxt)   # [S] or [S, K+1]; the host sync point
         with trace.phase("engine.step.book"):
             self._book_step(step, nxt)
-        return step.launch_ms + (time.monotonic() - t1) * 1e3
+        self._it_step_ms += (time.monotonic() - t1) * 1e3
 
     def _book_step(self, step: _StepInFlight, nxt) -> None:
         """What a step's synced tokens mean on the host: each slot that
-        was live AT ITS DISPATCH has its emissions booked, histograms
-        and ledger charged, finished requests released and resolved. A
-        slot that went live since (a full hit admitted under the step)
-        was not computed and is neither booked nor advanced."""
+        ran IN IT has its emissions booked, histograms and ledger
+        charged, finished requests released and resolved. A slot that
+        went live since (a full hit admitted under the step) was not
+        computed and is not booked; a request that left its slot since
+        (resolved at the step before: this one ran it one past its eos)
+        has this step's token dropped, never appended, never counted."""
         spec_toks, n_valid, t_it0 = step.spec_toks, step.n_valid, step.t0
         # ONE branch decides all per-iteration trace work: when tracing
         # is off this loop allocates nothing trace-related (guarded by
@@ -2525,16 +2706,19 @@ class DecodeEngine:
         self.steps_counter.inc()
         if ledger_on:
             # device time attributed by active-lane share: the step's
-            # wall (dispatch to sync, growth/drafting included) divides
-            # evenly over the sequences it served — charged BEFORE the
+            # wall (dispatch to sync, growth/drafting included; from
+            # the booking before where the step was dispatched ahead of
+            # it, so that no interval is charged twice) divides evenly
+            # over the sequences it served — charged BEFORE the
             # per-slot loop so a sequence completing this very step
             # still pays for it
             self.ledger.charge_step(
                 [r for r in step.reqs if r is not None],
-                (now - t_it0) * 1e3)
+                (now - max(t_it0, self._t_booked)) * 1e3)
+        self._t_booked = now
         n_active = 0
         for s, req in enumerate(step.reqs):
-            if req is None:
+            if req is None or self._slot_req[s] is not req:
                 continue
             n_active += 1
             if spec_toks is None:
@@ -2571,12 +2755,18 @@ class DecodeEngine:
                     self.spec_prop_counter.inc(proposed)
                 if accepted:
                     self.spec_acc_counter.inc(accepted)
-            # pos/tok mirror host-side (consumed inputs advance the
-            # position; rejected window positions are simply never
-            # consumed — the next window starts at the first unverified
-            # position and rewrites them before any mask reaches them)
-            self._pos[s] += len(emitted)
-            self._tok[s] = emitted[-1]
+            if self._spec:
+                # acceptance decides how far the slot moved, and the
+                # next window starts from the host's token (consumed
+                # inputs advance the position; rejected window positions
+                # are simply never consumed — the next window starts at
+                # the first unverified position and rewrites them before
+                # any mask reaches them)
+                self._pos[s] += len(emitted)
+                self._tok[s] = emitted[-1]
+                # a pass in which no slot drafts runs the plain step,
+                # whose tokens are the device's but for the fresh slots
+                self._fresh[s] = True
             # ITL is per EMITTED token: the step interval divides across
             # this iteration's emissions (spec_k=0 emits one token, so
             # the sample is exactly today's now - t_last)
@@ -2690,10 +2880,12 @@ class DecodeEngine:
                 _, done, info = self._loop_work.popleft()
                 info["skipped"] = "engine failed"
                 done.set()
-        live = [r for r in self._slot_req if r is not None]
-        if self._pf is not None:      # mid-prefill admission dies too
-            live.append(self._pf)
-            self._pf = None
+        # every pass in flight dies with this one: the live slots'
+        # requests, the mid-prefill admission and those whose last
+        # chunk was in flight
+        live = self._admitted_requests()
+        self._pf = None
+        self._flight.clear()
         # the dying requests' reservations go back too — including
         # arrivals popped but not yet slotted. The engine is stopped,
         # but stats()/gauges must not report phantom live blocks (the
@@ -2866,7 +3058,9 @@ class DecodeEngine:
                 params, *pools, tables,
                 np.zeros((S, self._spec + 1), np.int32), zeros,
                 np.zeros(S, bool), np.ones(S, np.int32))
-        *pools, nxt, _ = self._step_fn(params, *pools, tables, zeros,
+        # the step's tokens are the merge's output or the last step's
+        tok = self._merge_fn(self._dev_tok, zeros)
+        *pools, nxt, _ = self._step_fn(params, *pools, tables, tok,
                                        zeros, np.zeros(S, bool))
         jax.block_until_ready(nxt)
         return tuple(pools)
@@ -2900,6 +3094,9 @@ class DecodeEngine:
         self.seqpar_chunks = 0
         self.prefill_chunks = 0
         self.chunks_behind_step = 0
+        self.steps = 0
+        self.steps_ahead = 0
+        self.drains = {}
         self.preemptions = 0
         self.preempted = 0
         self.deadline_drops = 0
@@ -3104,6 +3301,9 @@ class DecodeEngine:
             "prefill_tokens": self.prefill_tokens,
             "prefill_chunks": self.prefill_chunks,
             "chunks_behind_step": self.chunks_behind_step,
+            "steps": self.steps,
+            "steps_ahead": self.steps_ahead,
+            "drains": dict(self.drains),
         }
 
     # -- lifecycle ----------------------------------------------------------

@@ -23,15 +23,17 @@ Record schema (:data:`FIELDS`, positional):
 ``it``                  iteration index (1-based, monotonic per engine)
 ``ts``                  ``time.monotonic()`` at record time (iteration end)
 ``busy_ms``             wall of this loop pass's work (admit + chunk + step)
-``step_ms``             the fused decode step's share of ``busy_ms``: its
-                        launch, the wait for its tokens and the booking
-                        (0 if the pass ran no step)
+``step_ms``             the fused decode steps' share of ``busy_ms``: the
+                        launch of the step this pass dispatched, the
+                        wait for the tokens of the one it retired (the
+                        pass before's, the loop runs one pass ahead)
+                        and their booking (0 if it did neither)
 ``live``                live slots after the pass
 ``reserved``            mid-prefill admissions (reserved-not-live slots)
 ``queue``               admission-queue depth after the pass
 ``queue_age_ms``        age of the OLDEST queued request (0 if empty)
-``prefill_toks``        prompt tokens prefilled THIS pass
-``decode_toks``         tokens emitted THIS pass (first tokens included)
+``prefill_toks``        prompt tokens of the chunk dispatched THIS pass
+``decode_toks``         tokens booked THIS pass (first tokens included)
 ``pool_free``           KV pool free blocks
 ``pool_live``           KV pool live blocks
 ``pool_shared``         prefix-cache shared blocks — live blocks held by
@@ -64,6 +66,9 @@ Record schema (:data:`FIELDS`, positional):
 ``chunks_behind_step``  1 when this pass's prefill chunk was dispatched
                         while its step was in flight (queued behind it
                         on the device), else 0
+``steps_ahead``         1 when this pass's step was dispatched with the
+                        step before still unread (its tokens taken from
+                        the device), else 0
 ======================  =====================================================
 
 Timestamps are monotonic; the recorder captures a wall/mono anchor at
@@ -104,7 +109,7 @@ FIELDS = ("it", "ts", "busy_ms", "step_ms", "live", "reserved", "queue",
           "pool_live", "pool_shared", "version", "admitted", "completed",
           "spec_proposed", "spec_accepted", "kv_quant",
           "quant_scale_blocks", "kv_block_s", "tenants_live", "sp_chunks",
-          "kv_live_block_share", "chunks_behind_step")
+          "kv_live_block_share", "chunks_behind_step", "steps_ahead")
 
 
 def window_digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:

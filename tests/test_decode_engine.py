@@ -925,29 +925,243 @@ def test_kv_live_block_share_counts_the_steps_live_blocks(mv_session):
         [b / (slots * M) for b in want])
 
 
-# -- the loop's order: the chunk queued behind the step, the booking under it -
+# -- the loop's order: one pass of programs ahead of the host -----------------
 
-def _pins_nothing_in_flight(engine):
-    """Wrap ``_record_iteration`` (the end of every pass that did work):
-    collects the passes at whose end a pool was still being written."""
-    late = []
+def _watch_in_flight(engine):
+    """The in-flight invariant, checked where it has to hold. Between
+    passes (the end of every pass that did work) at most one step and
+    one chunk are in flight; where ``_preempt`` runs, where work parked
+    for the loop thread (a splice, a warm-up) runs, where the pin may
+    move and where the loop waits idle, nothing is. That is the engine's
+    own book (``_flight``); where the loop waits idle the DEVICE is
+    asked too (``_device_idle``): a program dispatched and never booked
+    into ``_flight`` would show there. Returns the list the violations
+    are collected in."""
+    from multiverso_tpu.serving.decode_engine import (_ChunkInFlight,
+                                                      _StepInFlight)
+
+    broken = []
+
+    def in_flight():
+        return [type(f).__name__ for f in list(engine._flight)]
+
     record = engine._record_iteration
 
-    def checked(t_work0, step_ms):
-        if not all(p.is_ready() for p in engine._pools):
-            late.append(engine.iters_total + 1)
-        record(t_work0, step_ms)
+    def at_pass_end(t_work0):
+        names = in_flight()
+        if (names.count(_StepInFlight.__name__) > 1
+                or names.count(_ChunkInFlight.__name__) > 1):
+            broken.append(("pass end", names))
+        record(t_work0)
 
-    engine._record_iteration = checked
-    return late
+    engine._record_iteration = at_pass_end
+
+    def nothing_in_flight(where, fn, device=False):
+        def checked(*args, **kwargs):
+            if engine._flight:
+                broken.append((where, in_flight()))
+            elif device and not _device_idle(engine):
+                broken.append((where, "a pool is still being written"))
+            return fn(*args, **kwargs)
+        return checked
+
+    engine._preempt = nothing_in_flight("preempt", engine._preempt)
+    engine._apply_splice = nothing_in_flight("splice", engine._apply_splice)
+    engine._warm = nothing_in_flight("warm-up", engine._warm)
+    engine._manager.ensure_fresh = nothing_in_flight(
+        "refresh", engine._manager.ensure_fresh)
+    engine._cv.wait = nothing_in_flight("idle wait", engine._cv.wait,
+                                        device=True)
+    return broken
 
 
-def test_overlapped_loop_is_oracle_exact_under_churn(mv_session):
+def _device_idle(engine):
+    """No program of the engine is running: every step, chunk, splice
+    and copy-on-write writes the pools."""
+    return all(p.is_ready() for p in engine._pools)
+
+
+def _force_depth_zero(engine):
+    """The depth-0 reference: every pass first retires what the pass
+    before left in flight, so no step is dispatched ahead of another's
+    booking. Forced here, in the test, through the loop's own predicate:
+    the product has no knob for the order."""
+    engine._drain_cause = lambda splices: "forced"
+
+
+def _serve(srv, reqs, wave=5):
+    futs = []
+    for i, req in enumerate(reqs):
+        futs.append(srv.submit("lm", dict(req)))
+        if i % wave == wave - 1:
+            time.sleep(0.01)                # arrivals in waves
+    return [f.result(timeout=120) for f in futs]
+
+
+def _quiet_engine(engine, timeout_s=20.0):
+    """The loop thread is back in its idle wait."""
+    deadline = time.monotonic() + timeout_s
+    while (engine._flight or engine._active.any() or engine._pf is not None
+           or len(engine._q)):
+        assert time.monotonic() < deadline, "the engine never went quiet"
+        time.sleep(0.005)
+    time.sleep(0.02)
+
+
+def _reqs(rng, vocab, lens, news, n, **extra):
+    return [dict(prompt=rng.integers(1, vocab, lens[i % len(lens)])
+                 .astype(np.int32), max_new=news[i % len(news)], **extra)
+            for i in range(n)]
+
+
+_AHEAD_CASES = {
+    # plain greedy decode: one to three chunks a prompt, slot reuse
+    "greedy": dict(reqs=lambda rng, v: _reqs(rng, v, (3, 7, 11), (12,), 10)),
+    # eos hit mid-answer: the slot runs one step past it, whose token
+    # never appears and is never counted (the eos is picked below, from
+    # the answers themselves)
+    "eos": dict(reqs=lambda rng, v: _reqs(rng, v, (3, 7), (12,), 8),
+                eos_from_answers=True),
+    # max_new reached with the step in flight: the slot is left out of
+    # the next step by count (1: it never goes live at all)
+    "max_new": dict(engine=dict(slots=2),
+                    reqs=lambda rng, v: _reqs(rng, v, (3, 7),
+                                              (1, 2, 3, 5), 10)),
+    # a fully cached prompt goes live under a step in flight
+    "full_hit": dict(reqs=lambda rng, v: _reqs(rng, v, (8, 11, 7), (12,), 6),
+                     repeat_first=2),
+    # preemption under a squeezed pool: growth meets a dry pool with
+    # programs in flight, which are retired before anybody is requeued
+    "preempt": dict(reqs=lambda rng, v: [
+        dict(r, priority=i % 3) for i, r in enumerate(
+            _reqs(rng, v, (3, 7, 11), (12,), 12))], squeeze=0.6),
+    # speculation: acceptance decides the positions, so no step is ever
+    # dispatched ahead
+    "spec": dict(engine=dict(spec_k=2),
+                 reqs=lambda rng, v: _reqs(rng, v, (3, 7), (12,), 8)),
+    # the pin moves between two waves; the second wave's first request
+    # lands (its one chunk in flight) with the next one queued behind it
+    "refresh": dict(engine=dict(max_staleness_s=0.0), train_between=True,
+                    reqs=lambda rng, v: _reqs(rng, v, (3, 7), (12,), 6)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_AHEAD_CASES))
+def test_served_tokens_equal_a_drained_engines(mv_session, case):
+    """One engine serves the same requests twice: with every pass
+    forced to drain first (depth 0), then running one pass ahead. The
+    tokens are the same, token for token, and the per-request oracle's;
+    the in-flight invariant holds throughout; and the step, the chunk
+    and the token merge each keep ONE compiled trace though the step's
+    tokens come from the device in one run and (each pass drained, the
+    newcomers' from the host) in the other."""
+    from multiverso_tpu.models.transformer import TransformerLM
+    from multiverso_tpu.serving import InferenceServer
+
+    spec = _AHEAD_CASES[case]
+    cfg = _small_cfg()
+    lm = TransformerLM(cfg)
+    rng = np.random.default_rng(sum(map(ord, case)))
+    reqs = spec["reqs"](rng, cfg.vocab_size)
+    for i in range(spec.get("repeat_first", 0)):
+        reqs.insert(2 * i + 2, dict(reqs[0]))
+    params, _ = lm.snapshot_params()
+    eos = None
+    if spec.get("eos_from_answers"):
+        # the fourth token of the first answer: hit mid-answer for sure
+        eos = int(_oracle(cfg, params, reqs[0]["prompt"], 12)[3])
+    srv = InferenceServer("t")
+    kwargs = dict(slots=4, max_prompt=12, max_new=16, kv_block_size=4,
+                  prefill_token_budget=4, eos_id=eos)
+    kwargs.update(spec.get("engine", {}))
+    engine = srv.register_decoder("lm", lm, **kwargs)
+    broken = _watch_in_flight(engine)
+    engine.warmup()
+    loop_cause = engine._drain_cause
+    # every published snapshot's params by version (the pin moves in
+    # one case)
+    published = {engine.health()["snapshot_version"]: params}
+    publish = engine._manager.publish
+
+    def recording_publish():
+        snap = publish()
+        published[snap.version] = snap.value
+        return snap
+
+    engine._manager.publish = recording_publish
+
+    def run(forced):
+        engine._drain_cause = loop_cause
+        if forced:
+            _force_depth_zero(engine)
+        engine._pool.flush_cache()          # both runs start cold
+        engine.reset_stats()
+        if spec.get("squeeze"):
+            assert engine.squeeze_pool(spec["squeeze"]) > 0
+        half = len(reqs) // 2 if spec.get("train_between") else len(reqs)
+        replies = _serve(srv, reqs[:half])
+        if half < len(reqs):
+            _quiet_engine(engine)
+            lm.train_batch(rng.integers(
+                0, cfg.vocab_size, (2, 12)).astype(np.int32))
+            replies += _serve(srv, reqs[half:], wave=99)
+        _quiet_engine(engine)
+        engine.unsqueeze_pool()
+        return replies, engine.stats()
+
+    drained, stats0 = run(forced=True)
+    ahead, stats1 = run(forced=False)
+    for req, r0, r1 in zip(reqs, drained, ahead):
+        if not spec.get("train_between"):
+            np.testing.assert_array_equal(r1["result"], r0["result"])
+        for reply in (r0, r1):
+            np.testing.assert_array_equal(
+                reply["result"],
+                _oracle(cfg, published[reply["snapshot_version"]],
+                        req["prompt"], req["max_new"], eos))
+    assert broken == []
+    assert engine.pool_drift() is None
+    assert engine.step_cache_size() == 1
+    assert engine.prefill_cache_size() == 1
+    assert engine._merge_fn._cache_size() == 1
+    for stats, replies in ((stats0, drained), (stats1, ahead)):
+        assert stats["completed"] == len(reqs)
+        assert stats["tokens"] == sum(len(r["result"]) for r in replies)
+    # the forced run never dispatched a step ahead of a booking, and
+    # drained wherever something was in flight at a pass's start
+    assert stats0["steps_ahead"] == 0
+    if case == "spec":
+        assert stats1["steps_ahead"] == 0 and stats1["drains"]["spec"] > 0
+        assert stats1["spec_steps"] > 0
+    else:
+        assert 0 < stats1["steps_ahead"] < stats1["steps"]
+        assert stats0["drains"]["forced"] > 0
+        assert "forced" not in stats1["drains"]
+    if case == "eos":
+        assert any(len(r["result"]) < 12 and r["result"][-1] == eos
+                   for r in ahead)
+    if case == "max_new":
+        assert [len(r["result"]) for r in ahead] \
+            == [req["max_new"] for req in reqs]
+    if case == "full_hit":
+        assert stats1["cow_copies"] >= 1
+    if case == "preempt":
+        assert stats0["preemptions"] > 0 and stats1["preemptions"] > 0
+        assert stats1["drains"].get("preempt", 0) > 0
+    if case == "refresh":
+        assert len({r["snapshot_version"] for r in ahead}) == 2
+        assert stats1["drains"].get("empty", 0) > 0
+    srv.stop()
+    assert not engine._flight and _device_idle(engine) and broken == []
+
+
+def test_loop_runs_one_pass_ahead_under_churn(mv_session):
     """Staggered arrivals, prompts of one to three chunks, a full prefix
     hit (copy-on-write) and preemptions under a small pool: every answer
     is ``greedy_decode``'s, the step compiled once, every chunk that was
-    dispatched while something was live went out behind that pass's
-    step, and no pass ends with a program of the engine in flight."""
+    dispatched in a pass with a step went out behind it, the counters
+    of the order agree with the flight recorder's columns, and at most
+    one step and one chunk are in flight between passes."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
@@ -958,7 +1172,7 @@ def test_overlapped_loop_is_oracle_exact_under_churn(mv_session):
                                   max_new=16, kv_block_size=4,
                                   kv_pool_blocks=10, prefill_token_budget=4)
     engine.warmup()
-    late = _pins_nothing_in_flight(engine)
+    broken = _watch_in_flight(engine)
     params, _ = lm.snapshot_params()
     rng = np.random.default_rng(31)
     shared = rng.integers(1, cfg.vocab_size, 8).astype(np.int32)
@@ -979,30 +1193,36 @@ def test_overlapped_loop_is_oracle_exact_under_churn(mv_session):
             fut.result(timeout=120)["result"],
             _oracle(cfg, params, prompt, max_new),
             err_msg=f"prompt {prompt} max_new {max_new}")
+    _quiet_engine(engine)
     assert engine.step_cache_size() == 1
     assert engine.prefill_cache_size() == 1
     stats = engine.stats()
     assert stats["cow_copies"] >= 1 and stats["preemptions"] > 0
     assert engine.pool_drift() is None
     records = engine.recorder.records()
+    # a chunk's tokens are booked where it is dispatched
     chunks = [r for r in records if r["prefill_toks"] > 0]
-    # a pass dispatches its step first: a chunk went out behind a step
-    # exactly where the pass had both
-    behind = sum(r["step_ms"] > 0 for r in chunks)
     assert stats["prefill_chunks"] == len(chunks)
-    assert stats["chunks_behind_step"] == behind \
+    assert stats["chunks_behind_step"] \
         == sum(r["chunks_behind_step"] for r in records)
-    assert 0 < behind < len(chunks)         # from idle there is no step
-    assert late == []
+    assert 0 < stats["chunks_behind_step"] < len(chunks)
+    # a pass's step is ahead where the step before was still unread
+    assert stats["steps"] == sum(
+        r["kv_live_block_share"] >= 0 for r in records)
+    assert stats["steps_ahead"] == sum(r["steps_ahead"] for r in records)
+    assert 0 < stats["steps_ahead"] < stats["steps"]
+    assert stats["drains"].get("preempt", 0) > 0
+    assert broken == []
 
 
 def test_programs_in_flight_read_their_own_host_arrays(mv_session):
     """The aliasing guard. A dispatched program may read its numpy
     arguments late, and admission and booking write the engine's block
-    tables, ``_tok``, ``_pos`` and ``_active`` while a step or a chunk
-    is in flight: so every dispatch gets arrays of its own. Here each
-    dispatch is followed by garbage over the engine's arrays until the
-    program has run: the tokens stay the oracle's."""
+    tables, ``_tok``, ``_fresh``, ``_pos``, ``_left`` and ``_active``
+    while up to two passes of programs are in flight: so every dispatch
+    (the token merge's too) gets arrays of its own. Here each dispatch
+    is followed by garbage over the engine's arrays until the program
+    has run: the tokens stay the oracle's."""
     import jax
 
     from multiverso_tpu.models.transformer import TransformerLM
@@ -1016,7 +1236,7 @@ def test_programs_in_flight_read_their_own_host_arrays(mv_session):
                                   prefill_token_budget=4)
     engine.warmup()
     params, _ = lm.snapshot_params()
-    live = ("_block_tables", "_tok", "_pos", "_active")
+    live = ("_block_tables", "_tok", "_fresh", "_pos", "_left", "_active")
     scribbled = []
 
     def scribbling(fn):
@@ -1037,8 +1257,10 @@ def test_programs_in_flight_read_their_own_host_arrays(mv_session):
         return dispatch
 
     step_fn, chunk_fn = engine._step_fn, engine._chunk_fn
+    merge_fn = engine._merge_fn
     engine._step_fn, engine._chunk_fn = scribbling(step_fn), \
         scribbling(chunk_fn)
+    engine._merge_fn = scribbling(merge_fn)
     rng = np.random.default_rng(32)
     reqs = [(rng.integers(1, cfg.vocab_size, int(
         rng.integers(1, 13))).astype(np.int32), int(rng.integers(2, 11)))
@@ -1049,17 +1271,19 @@ def test_programs_in_flight_read_their_own_host_arrays(mv_session):
             fut.result(timeout=120)["result"],
             _oracle(cfg, params, prompt, max_new))
     engine._step_fn, engine._chunk_fn = step_fn, chunk_fn
-    assert step_fn in scribbled and chunk_fn in scribbled
+    engine._merge_fn = merge_fn
+    assert {step_fn, chunk_fn, merge_fn} <= set(scribbled)
     assert engine.step_cache_size() == 1
 
 
 def test_full_hit_admitted_under_a_step_is_booked_nothing_from_it(
         mv_session):
-    """A fully cached prompt goes live at admission, which now runs
-    while the pass's step is in flight: that step was dispatched without
-    the slot, so its booking walks the slots as they stood at the
-    dispatch and the newcomer's first token falls out of the NEXT
-    pass's step."""
+    """A fully cached prompt goes live at admission, which runs while
+    the pass's step is in flight: that step was dispatched without the
+    slot, so its booking (a pass later) walks the slots as they stood
+    at the dispatch, and the newcomer's first token falls out of the
+    NEXT pass's step, its input token taken from the host where every
+    other slot's comes from the device."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
 
@@ -1075,63 +1299,111 @@ def test_full_hit_admitted_under_a_step_is_booked_nothing_from_it(
     cached = rng.integers(1, cfg.vocab_size, 8).astype(np.int32)
     other = rng.integers(1, cfg.vocab_size, 11).astype(np.int32)
     srv.submit("lm", {"prompt": cached, "max_new": 2}).result(timeout=120)
+    _quiet_engine(engine)
     # the long one prefills in three chunks with the repeat queued behind
     # it (one admission prefills at a time): the repeat is admitted in
-    # the first pass that steps the long one
+    # the pass that lands the long one and dispatches its first step
     long_fut = srv.submit("lm", {"prompt": other, "max_new": 16})
     hit_fut = srv.submit("lm", {"prompt": cached, "max_new": 5})
     np.testing.assert_array_equal(long_fut.result(timeout=120)["result"],
                                   _oracle(cfg, params, other, 16))
     np.testing.assert_array_equal(hit_fut.result(timeout=120)["result"],
                                   _oracle(cfg, params, cached, 5))
+    _quiet_engine(engine)
     assert engine.stats()["cow_copies"] == 1
     records = engine.recorder.records()
     _, _, admit = [r for r in records if r["admitted"]]   # seed, long, hit
     (hit_rid,) = admit["admitted"]
-    # the pass that admitted it stepped the long one alone: one token
-    # booked, two slots live at its end; the next pass books two
+    # the pass that admitted it had the long one's last chunk to retire
+    # with nothing else live: drained first (the long one's first token,
+    # its slot live), then its first step went out alone, not ahead
     assert admit["step_ms"] > 0 and admit["prefill_toks"] == 0
     assert (admit["decode_toks"], admit["live"]) == (1, 2)
-    after = records[records.index(admit) + 1]
-    assert after["decode_toks"] == 2
+    assert admit["steps_ahead"] == 0
+    # the next pass dispatches a step over both, ahead, and books the
+    # first step: the long one's token alone; the pass after books two
+    at = records.index(admit)
+    assert [r["decode_toks"] for r in records[at + 1: at + 4]] == [1, 2, 2]
+    assert [r["steps_ahead"] for r in records[at + 1: at + 4]] == [1, 1, 1]
     done = [r for r in records if hit_rid in r["completed"]]
-    assert done[0]["it"] == admit["it"] + 5       # five tokens, five steps
+    # five tokens from five steps, the first dispatched a pass after the
+    # admission, each booked a pass after its dispatch
+    assert done[0]["it"] == admit["it"] + 6
+    # the seed's last chunk and the long one's were each all the engine
+    # had in flight, nothing live beside them
+    assert engine.stats()["drains"] == {"empty": 2}
 
 
-def test_failure_under_a_step_in_flight_fails_every_future(mv_session):
-    """An exception between a step's dispatch and the end of the pass
-    (here the chunk's dispatch) fails the live requests, the admission
+@pytest.mark.parametrize("where", ["dispatch", "sync"])
+def test_failure_under_two_passes_in_flight_fails_every_future(mv_session,
+                                                               where):
+    """An exception with two passes' programs in flight fails the live
+    requests, the one whose last chunk was in flight, the admission
     that was popped under the step and the queue, and returns every
-    block."""
+    block. ``dispatch``: raised at a chunk's dispatch behind a step, the
+    pass before's step and last chunk unretired. ``sync``: raised where
+    a device error surfaces, at the fetch of a LAST chunk's logits a
+    pass after its dispatch: by then only ``_flight`` names its request
+    (off ``_pf`` since the dispatch, its slot not live yet)."""
     from multiverso_tpu.models.transformer import TransformerLM
     from multiverso_tpu.serving import InferenceServer
+    from multiverso_tpu.serving.decode_engine import _ChunkInFlight
 
     cfg = _small_cfg()
     lm = TransformerLM(cfg)
     srv = InferenceServer("t")
-    engine = srv.register_decoder("lm", lm, slots=2, max_prompt=8,
+    engine = srv.register_decoder("lm", lm, slots=3, max_prompt=8,
                                   max_new=16, kv_block_size=4,
                                   prefill_token_budget=4)
     engine.warmup()
     chunk_fn = engine._chunk_fn
-    went = []
+    went, seen = [], []
+
+    class LostLogits:
+        """Stands for a chunk's logits whose program failed on the
+        device: the fetch raises."""
+
+        def __array__(self, *args, **kwargs):
+            flight = list(engine._flight)
+            seen.append([type(f).__name__ for f in flight])
+            raise RuntimeError("injected chunk failure")
 
     def boom(*args):
-        if engine._active.any():            # a step is in flight
+        flight = list(engine._flight)
+        landing = any(isinstance(f, _ChunkInFlight) and f.final
+                      for f in flight)
+        if (where == "dispatch" and engine._active.any() and landing
+                and len(flight) == 3):
+            # this pass's step, the pass before's step and the last
+            # chunk of the request before this one
+            seen.append([type(f).__name__ for f in flight])
             raise RuntimeError("injected chunk failure")
         went.append(1)
-        return chunk_fn(*args)
+        *pools, logits = chunk_fn(*args)
+        off, n = int(args[-2]), int(args[-1])
+        if (where == "sync" and engine._active.any() and off + n >= 6
+                and not seen):
+            # the second request's last chunk, behind the first's step
+            logits = LostLogits()
+        return (*pools, logits)
 
     engine._chunk_fn = boom
     rng = np.random.default_rng(34)
     futs = [srv.submit("lm", {"prompt": rng.integers(
         1, cfg.vocab_size, 6).astype(np.int32), "max_new": 16})
-        for _ in range(4)]
+        for _ in range(5)]
     for fut in futs:
         with pytest.raises(RuntimeError, match="injected chunk failure"):
             fut.result(timeout=60)
     engine._chunk_fn = chunk_fn
-    assert went                 # the first admission prefilled from idle
+    # dispatch: the step before's, the last chunk, this pass's step.
+    # sync: the lost chunk at the head, still in flight while it is
+    # fetched, this pass's step and the next request's chunk behind it
+    assert went and seen == [{
+        "dispatch": ["_StepInFlight", "_ChunkInFlight", "_StepInFlight"],
+        "sync": ["_ChunkInFlight", "_StepInFlight", "_ChunkInFlight"],
+    }[where]]
+    assert not engine._flight
     assert engine.stats()["kv_blocks_live"] == 0
     assert engine.pool_drift() is None
     engine._pool.check()

@@ -10,9 +10,11 @@ for the per-layer readers. Checked here, on the CPU:
   prefix is the one the reduction admits;
 * a toy engine under a real session leaves all nine, in the documented
   order (a pass's phases are children of ``engine.iter``, one after
-  another: the chunk's dispatch lies between the step's dispatch and the
-  step's sync) and counted as the flight recorder counts, and a pass
-  that finds only block-starved waiters is a wait and no iteration;
+  another: this pass's dispatches, then the syncs and the booking of
+  what the pass BEFORE dispatched; a drain puts those first) and counted
+  as the flight recorder counts, ``steps_ahead`` and ``drains`` as the
+  trace shows them, and a pass that finds only block-starved waiters is
+  a wait and no iteration;
 * the reduction books a device-idle gap to the engine's phase and not to
   the longer harness span around it;
 * each reader added with the phases computes its value from the summed
@@ -83,10 +85,12 @@ def _inside(child, parents):
                for p in parents)
 
 
-# the phases of one pass, in the order the loop enters them
-_PASS_ORDER = ("engine.step", "engine.admit", "engine.prefill_chunk",
-               "engine.step.sync", "engine.step.book",
-               "engine.prefill_chunk.sync", "engine.record")
+# the phases of one pass, in the order the loop enters them: what it
+# dispatches, then what it retires (dispatched by the pass before)
+_RETIRE = ("engine.step.sync", "engine.step.book",
+           "engine.prefill_chunk.sync")
+_PASS_ORDER = ("engine.step", "engine.admit", "engine.prefill_chunk") \
+    + _RETIRE + ("engine.record",)
 
 
 def test_toy_engine_leaves_all_nine_phases(mv_session, tmp_path):
@@ -106,6 +110,7 @@ def test_toy_engine_leaves_all_nine_phases(mv_session, tmp_path):
     srv.submit("lm", prompt).result(timeout=120)      # compiles
     _quiet(engine)
     total0 = engine.recorder.total
+    engine.reset_stats()
     assert trace.phase("engine.step") is trace.NULL_SPAN
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -119,8 +124,11 @@ def test_toy_engine_leaves_all_nine_phases(mv_session, tmp_path):
     finally:
         jax.profiler.stop_trace()
     records = [r for r in engine.recorder.records() if r["it"] > total0]
-    steps = sum(r["step_ms"] > 0 for r in records)
+    # a step was dispatched where the record has the blocks it read
+    steps = sum(r["kv_live_block_share"] >= 0 for r in records)
     assert len(records) == engine.recorder.total - total0 and steps > 0
+    stats = engine.stats()
+    assert stats["steps"] == steps
 
     rows, spans = _engine_spans(tmp_path)
     assert sorted(spans) == sorted(P + n for n in _documented_phases())
@@ -137,9 +145,8 @@ def test_toy_engine_leaves_all_nine_phases(mv_session, tmp_path):
         == n("engine.step.book") == steps
     chunks = sum(r["prefill_toks"] > 0 for r in records)
     assert n("engine.prefill_chunk") == chunks > 0
-    # a chunk is waited for once, a prompt's last chunk twice (its
-    # pools, then its logits)
-    assert chunks <= n("engine.prefill_chunk.sync") <= 2 * chunks
+    # every chunk is waited for once, by its own logits
+    assert n("engine.prefill_chunk.sync") == chunks
     by_name = {}
     for row in rows:
         by_name.setdefault(row[2][len(P):], []).append(row)
@@ -152,20 +159,37 @@ def test_toy_engine_leaves_all_nine_phases(mv_session, tmp_path):
     assert not any(_inside(w, by_name["engine.iter"])
                    for w in by_name["engine.wait"])
     # inside a pass they follow one another in the documented order and
-    # none overlaps another: so each .sync holds only a wait, and the
-    # chunk is dispatched between the step's dispatch and its sync
-    behind = 0
+    # none overlaps another: so each .sync holds only a wait. A pass
+    # dispatches first and retires afterwards: the step it syncs is the
+    # one the pass before dispatched, so its own step went out AHEAD. A
+    # drain puts the retiring phases first, and nothing is left to
+    # retire behind that pass's dispatches
+    behind = ahead = drains = 0
     for it in by_name["engine.iter"]:
         kids = sorted((r for name in _PASS_ORDER for r in by_name[name]
                        if _inside(r, [it])), key=lambda r: r[3])
         for a, b in zip(kids, kids[1:]):
             assert a[3] + a[4] <= b[3], (a[2], b[2])
         names = [r[2][len(P):] for r in kids]
-        assert names == [name for name in _PASS_ORDER
-                         for _ in range(names.count(name))], names
-        behind += {"engine.step", "engine.prefill_chunk"} <= set(names)
+        first = min(names.index(name) for name in _PASS_ORDER
+                    if name not in _RETIRE and name in names)
+        drained, rest = names[:first], names[first:]
+        assert drained == [name for name in _RETIRE
+                           for _ in range(drained.count(name))], names
+        assert rest == [name for name in _PASS_ORDER
+                        for _ in range(rest.count(name))], names
+        drains += bool(drained)
+        assert not (drained and set(rest) & set(_RETIRE)), names
+        behind += {"engine.step", "engine.prefill_chunk"} <= set(rest)
+        ahead += {"engine.step", "engine.step.sync"} <= set(rest)
     assert behind == sum(r["chunks_behind_step"] for r in records) > 0
-    assert behind == engine.stats()["chunks_behind_step"]
+    assert behind == stats["chunks_behind_step"]
+    assert ahead == sum(r["steps_ahead"] for r in records) > 0
+    assert ahead == stats["steps_ahead"] < steps
+    # a prompt's last chunk with nothing live beside it is retired
+    # before anything else is dispatched or admitted
+    assert drains == sum(stats["drains"].values()) > 0
+    assert set(stats["drains"]) == {"empty"}
 
 
 def _engine_spans(trace_dir):

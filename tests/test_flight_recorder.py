@@ -22,12 +22,13 @@ def _rec(it, ts, busy=1.0, step=0.5, live=1, reserved=0, queue=0,
          pool_shared=-1, version=0, admitted=(), completed=(),
          spec_proposed=-1, spec_accepted=-1, kv_quant=-1,
          quant_scale_blocks=-1, kv_block_s=-1.0, tenants_live=-1,
-         sp_chunks=-1, kv_live_block_share=-1.0, chunks_behind_step=0):
+         sp_chunks=-1, kv_live_block_share=-1.0, chunks_behind_step=0,
+         steps_ahead=0):
     return (it, ts, busy, step, live, reserved, queue, queue_age,
             prefill, decode, pool_free, pool_live, pool_shared, version,
             admitted, completed, spec_proposed, spec_accepted, kv_quant,
             quant_scale_blocks, kv_block_s, tenants_live, sp_chunks,
-            kv_live_block_share, chunks_behind_step)
+            kv_live_block_share, chunks_behind_step, steps_ahead)
 
 
 # -- ring ---------------------------------------------------------------------
@@ -244,7 +245,7 @@ def test_chunks_behind_step_column_and_older_tuple_tolerance():
     """The overlap flag rides the END of FIELDS: 1 where a pass's
     prefill chunk was dispatched while its step was in flight; a
     24-field tuple from before the column still reads cleanly."""
-    assert FIELDS[-1] == "chunks_behind_step"
+    assert FIELDS[24] == "chunks_behind_step"
     fr = FlightRecorder(capacity=8, name="eng")
     fr.record(_rec(1, time.monotonic(), prefill=4, chunks_behind_step=1))
     assert fr.records()[0]["chunks_behind_step"] == 1
@@ -255,6 +256,25 @@ def test_chunks_behind_step_column_and_older_tuple_tolerance():
     recs = older.records()
     assert "chunks_behind_step" not in recs[0]
     assert recs[0]["kv_live_block_share"] == 0.25
+    assert older.summary()["iterations"] == 1
+    older.chrome_counter_events()
+
+
+def test_steps_ahead_column_and_older_tuple_tolerance():
+    """The run-ahead flag rides the END of FIELDS: 1 where a pass's
+    step was dispatched with the step before still unread; a 25-field
+    tuple from before the column still reads cleanly."""
+    assert FIELDS[-1] == "steps_ahead"
+    fr = FlightRecorder(capacity=8, name="eng")
+    fr.record(_rec(1, time.monotonic(), steps_ahead=1))
+    assert fr.records()[0]["steps_ahead"] == 1
+    assert fr.summary()["iterations"] == 1
+
+    older = FlightRecorder(capacity=8, name="old")
+    older.record(_rec(1, time.monotonic(), chunks_behind_step=1)[:25])
+    recs = older.records()
+    assert "steps_ahead" not in recs[0]
+    assert recs[0]["chunks_behind_step"] == 1
     assert older.summary()["iterations"] == 1
     older.chrome_counter_events()
 
