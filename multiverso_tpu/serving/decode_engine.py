@@ -317,6 +317,21 @@ _CHAIN_ADVERT_CAP = 256
 # match without improving the greedy-verified acceptance contract.
 _SPEC_NGRAM = 2
 
+# The loop's phases (docs/OBSERVABILITY.md "Engine phases"): every
+# instant of the loop thread is in ``engine.wait``, in ``engine.iter``
+# (one pass) or in the loop gap between two passes, and a pass is
+# tiled by these eight leaves, entered one after another. The flight
+# recorder's ``phases`` column and ``stats()["slowest_pass"]`` keep a
+# pass's row in this order.
+_PASS_PHASES = ("engine.step", "engine.admit", "engine.prefill_chunk",
+                "engine.step.sync", "engine.step.book",
+                "engine.prefill_chunk.sync", "engine.prefill_chunk.book",
+                "engine.record")
+# the flight recorder's step_ms: the launch of the step a pass
+# dispatched, the wait for and the booking of the one it retired
+_STEP_PHASES = tuple(_PASS_PHASES.index(name) for name in (
+    "engine.step", "engine.step.sync", "engine.step.book"))
+
 
 class _PromptLookup:
     """Per-slot n-gram prompt-lookup index (Saxena, "Prompt Lookup
@@ -940,6 +955,10 @@ class DecodeEngine:
             f"SERVE_TTFT[{name}]")
         self.itl_hist = Dashboard.get_or_create_histogram(
             f"SERVE_ITL[{name}]")
+        # enqueue -> admission, one sample a request admitted: TTFT is
+        # this wait plus admission-to-first-token
+        self.qwait_hist = Dashboard.get_or_create_histogram(
+            f"SERVE_QWAIT[{name}]")
         self.tps_gauge = Dashboard.get_or_create_gauge(f"DECODE_TPS[{name}]")
         self.occ_gauge = Dashboard.get_or_create_gauge(f"SLOT_OCC[{name}]")
         # staleness-aware serving: seconds since the served source last
@@ -1025,6 +1044,18 @@ class DecodeEngine:
                 self.recorder.meta["kv_quant"] = self._kv_quant_mode
             if self._sp:
                 self.recorder.meta["prefill_sp"] = self._sp_backend
+            self.recorder.meta["phases"] = list(_PASS_PHASES)
+        # the loop's phases on the always-on host clock (trace.PhaseClock:
+        # the loop thread is its only writer); under a profiler session
+        # the same sites write the bench.engine.* annotations
+        self._clock = trace.PhaseClock(_PASS_PHASES, "engine.iter",
+                                       "engine.wait")
+        self._phase = self._clock.phase
+        # the longest pass since reset_stats(), kept outside the ring so
+        # that a wrap cannot lose it: (it, end, ns, gap before, live,
+        # queue, row)
+        self._slowest_ns = 0
+        self._slowest: Optional[tuple] = None
         # admit-span mesh annotation (trace_summary ships the column):
         # only sharded engines carry it, so replicated reports stay flat
         self._mesh_attrs = ({"decode_tp": self._tp} if self._tp > 1
@@ -1054,7 +1085,6 @@ class DecodeEngine:
         self._it_live_blocks = -1
         self._it_behind = 0
         self._it_ahead = 0
-        self._it_step_ms = 0.0
         self.completed = 0
         self.shed = 0
         self.tokens = 0
@@ -1533,7 +1563,7 @@ class DecodeEngine:
                        and not self._active.any() and not self._flight
                        and not self._loop_work
                        and not self._stop.is_set()):
-                    with trace.phase("engine.wait"):
+                    with self._phase("engine.wait"):
                         self._cv.wait()
                 if (self._stop.is_set() and not self._q
                         and self._pf is None and not self._active.any()
@@ -1549,8 +1579,8 @@ class DecodeEngine:
                 if self._loop_work:
                     splices = list(self._loop_work)
                     self._loop_work.clear()
-            # Phases on the profiler's clock (trace.phase; the shared
-            # NULL_SPAN with no session). With something live,
+            # Phases (trace.PhaseClock: the host's clock always, the
+            # profiler's too under a session). With something live,
             # prefilling, in flight or to splice the pass is sure of
             # work and is ONE engine.iter, to the end of
             # _record_iteration. From idle the queue decides: an arrival
@@ -1568,14 +1598,29 @@ class DecodeEngine:
                     # exhausted pessimistic re-admission, or a chaos-
                     # squeezed pool) or expired ones: yield briefly
                     # instead of hot-spinning until blocks free
-                    with trace.phase("engine.wait"):
+                    with self._phase("engine.wait"):
                         self._drop_expired(expired)
                         time.sleep(0.0005)
                     continue
-            with trace.phase("engine.iter"):
-                self._drop_expired(expired)
-                if not self._iteration(splices, arrival):
-                    return
+            with self._phase("engine.iter"):
+                if expired:
+                    with self._phase("engine.admit"):
+                        self._drop_expired(expired)
+                alive = self._iteration(splices, arrival)
+            if self._clock.pass_ns > self._slowest_ns:
+                self._keep_slowest()
+            if not alive:
+                return
+
+    def _keep_slowest(self) -> None:
+        """The pass just left is the longest since ``reset_stats()``:
+        keep its row, the gap before it and what the engine held."""
+        clock = self._clock
+        self._slowest_ns = clock.pass_ns
+        self._slowest = (self.iters_total, clock.pass_end_ns / 1e9,
+                         clock.pass_ns, clock.gap_ns,
+                         int(self._active.sum()), len(self._q),
+                         tuple(clock.row_ns))
 
     def _iteration(self, splices: List[tuple],
                    arrival: Optional[_Request]) -> bool:
@@ -1602,10 +1647,11 @@ class DecodeEngine:
         4. ``engine.step.sync`` then ``engine.step.book``: fetch the
            tokens of the step the pass BEFORE dispatched and book them
            against the slots it was dispatched with.
-        5. ``engine.prefill_chunk.sync``: retire the chunk the pass
-           before dispatched: prefix registration and, after a prompt's
-           last chunk, the first token and the slot going live (it
-           joins the next pass's step).
+        5. ``engine.prefill_chunk.sync`` then
+           ``engine.prefill_chunk.book``: wait for the chunk the pass
+           before dispatched and book it: prefix registration and,
+           after a prompt's last chunk, the first token and the slot
+           going live (it joins the next pass's step).
         6. ``engine.record``.
 
         The depth is decided each pass from what the loop holds. A pass
@@ -1620,8 +1666,7 @@ class DecodeEngine:
         # last_iter_age_s then measures how long THIS pass has been
         # stuck, not how long the engine idled beforehand (an idle
         # engine is not a stalled one — the watchdog's distinction)
-        t_work0 = time.monotonic()
-        self._last_progress = t_work0
+        self._last_progress = time.monotonic()
         self._it_admitted.clear()
         self._it_completed.clear()
         self._it_prefill = self._it_decode = 0
@@ -1629,7 +1674,6 @@ class DecodeEngine:
         self._it_sp_chunks = 0
         self._it_live_blocks = -1
         self._it_behind = self._it_ahead = 0
-        self._it_step_ms = 0.0
         arrivals = [] if arrival is None else [arrival]
         worked = bool(self._flight or splices)
         try:
@@ -1642,7 +1686,7 @@ class DecodeEngine:
                 # allowed to reassign the donated caches — with nothing
                 # in flight. A bad payload degrades (accounting says
                 # so); the waiter is released either way
-                with trace.phase("engine.admit"):
+                with self._phase("engine.admit"):
                     for work, done, info in splices:
                         try:
                             info.update(work())
@@ -1659,33 +1703,36 @@ class DecodeEngine:
                     jax.block_until_ready(self._pools)
             step = chunk = None
             if self._running().any():
-                with trace.phase("engine.step"):
+                with self._phase("engine.step"):
                     step = self._dispatch_step()
                 if step is None and self._flight:
                     # growth met a dry pool with programs in flight:
                     # their bookings may free blocks, and a victim's
                     # emitted tokens are booked before it is requeued
                     self._drain("preempt")
-                    with trace.phase("engine.step"):
+                    with self._phase("engine.step"):
                         step = self._dispatch_step()
-            with trace.phase("engine.admit"):
+            with self._phase("engine.admit"):
                 self._admit(arrivals)
+                # the sequences held at once: a prompt whose last chunk
+                # goes out below moves from _pf to landing, so the count
+                # stands from here to the end of the pass
+                live = (int(self._active.sum()) + (self._pf is not None)
+                        + len(self._landing()))
+                if live > self.peak_live:
+                    self.peak_live = live
             if self._pf is not None:
                 # AT MOST one budget-sized chunk per iteration, and at
                 # most one pass of programs ahead: what an admission can
                 # add to a live generation's next token is two chunks
                 # and two steps of device work, whatever the arrivals
-                with trace.phase("engine.prefill_chunk"):
+                with self._phase("engine.prefill_chunk"):
                     chunk = self._dispatch_chunk()
                 if step is not None:
                     # queued on the device behind the step in flight:
                     # its launch falls under the step's run
                     self.chunks_behind_step += 1
                     self._it_behind = 1
-            live = (int(self._active.sum()) + (self._pf is not None)
-                    + len(self._landing()))
-            if live > self.peak_live:
-                self.peak_live = live
             # what earlier passes left in flight, oldest first, under
             # this pass's programs
             self._retire((step is not None) + (chunk is not None))
@@ -1698,8 +1745,8 @@ class DecodeEngine:
             return False
         if (worked or step is not None or chunk is not None
                 or self._it_admitted):
-            with trace.phase("engine.record"):
-                self._record_iteration(t_work0)
+            with self._phase("engine.record"):
+                self._record_iteration()
         return True
 
     def _drain_cause(self, splices: List[tuple]) -> Optional[str]:
@@ -1776,12 +1823,18 @@ class DecodeEngine:
                 # spinning here
                 break
 
-    def _record_iteration(self, t_work0: float) -> None:
+    def _record_iteration(self) -> None:
         """One iteration retired: bump the progress clock/counters and
         append the flight-recorder record. Reads of queue/pool state are
         intentionally lock-light — these are gauge samples for the black
-        box, not accounting."""
+        box, not accounting. The pass's times are reads of the phase
+        clock: ``busy_ms`` from the pass's start to here, ``step_ms``
+        the row's three step phases."""
         now = time.monotonic()
+        clock = self._clock
+        row = clock.row_ns
+        busy_ms = (time.perf_counter_ns()
+                   - self._phase("engine.iter").t0_ns) / 1e6
         self.iters_total += 1
         self.iters_counter.inc()
         self._last_progress = now
@@ -1790,20 +1843,25 @@ class DecodeEngine:
             # KV residency integrates here: every admitted sequence is
             # charged reserved-blocks x this iteration's wall (host
             # floats only — same cost posture as the recorder itself)
-            dt = now - t_work0
+            dt = busy_ms / 1e3
             reqs = self._admitted_requests()
             self.ledger.charge_iteration(reqs, dt)
             it_block_s = dt * sum(len(r.blocks) for r in reqs)
         recorder = self.recorder
         if recorder is None:
             return
+        # the pass's row in ms; engine.record's entry (the last) is this
+        # call's time up to here
+        phases = [ns / 1e6 for ns in row]
+        phases[-1] = (time.perf_counter_ns()
+                      - self._phase("engine.record").t0_ns) / 1e6
         try:
             oldest = self._q.oldest_t_enq()
         except (IndexError, RuntimeError):   # racing a concurrent submit
             oldest = None
         recorder.record((
-            self.iters_total, now, (now - t_work0) * 1e3,
-            self._it_step_ms,
+            self.iters_total, now, busy_ms,
+            sum(row[i] for i in _STEP_PHASES) / 1e6,
             int(self._active.sum()), 1 if self._pf is not None else 0,
             len(self._q),
             0.0 if oldest is None else (now - oldest) * 1e3,
@@ -1840,7 +1898,11 @@ class DecodeEngine:
             self._it_behind,
             # run-ahead tail (FIELDS append at the END): 1 when this
             # pass's step was dispatched with the step before unread
-            self._it_ahead))
+            self._it_ahead,
+            # phase tail (FIELDS append at the END): the pass's row, in
+            # the order of meta["phases"], and the loop gap between the
+            # pass before and this one
+            phases, clock.gap_ns / 1e6))
 
     def _xfer_block_shape(self) -> tuple:
         """One block of the first pool as the transfer plane ships it:
@@ -2033,6 +2095,10 @@ class DecodeEngine:
             return
         req.pf_chunks = 0
         req.t_admit = time.monotonic()   # queue.wait ends here
+        if not req.resumed:
+            # a resumed request waited in its first life; t_enq is that
+            # life's, and the sample was taken there
+            self.qwait_hist.record((req.t_admit - req.t_enq) * 1e3)
         if req.usage is not None:
             req.usage.queue_wait_ms += (req.t_admit
                                         - req.usage.t_wait0) * 1e3
@@ -2174,14 +2240,22 @@ class DecodeEngine:
         chunk+step to >100 ms under ramp). With at most one pass of
         programs ahead, a live generation's next token waits for at
         most two steps and two chunks."""
-        req, logits, off, n, C, index, final, t0 = chunk
-        tracing = trace.enabled()
-        with trace.phase("engine.prefill_chunk.sync"):
+        with self._phase("engine.prefill_chunk.sync"):
             # final chunk: the prompt's last real position's logits are
             # the first generated token (exactly a whole-prompt
             # prefill's gather); a pf_only prompt's fall on the floor
-            logits = (np.asarray(logits) if final and not req.pf_only
-                      else jax.block_until_ready(logits))
+            logits = (np.asarray(chunk.logits)
+                      if chunk.final and not chunk.req.pf_only
+                      else jax.block_until_ready(chunk.logits))
+        with self._phase("engine.prefill_chunk.book"):
+            self._book_chunk(chunk, logits)
+
+    def _book_chunk(self, chunk: _ChunkInFlight, logits) -> None:
+        """What a synced chunk means on the host: its completed blocks'
+        content identity and, after a prompt's last chunk, the first
+        token, the histograms and the slot going live."""
+        req, _, off, n, C, index, final, t0 = chunk
+        tracing = trace.enabled()
         if self._prefix:
             # every prompt block this chunk COMPLETED gains its content
             # identity now, not at release: a concurrent same-prefix
@@ -2673,20 +2747,18 @@ class DecodeEngine:
                   for r, on in zip(self._slot_req, running)],
             spec_toks, n_valid, t0)
         self._flight.append(step)
-        self._it_step_ms += (time.monotonic() - t0) * 1e3
         return step
 
     def _retire_step(self, step: _StepInFlight) -> None:
         """Fetch an in-flight step's tokens and book them. The flight
         recorder's ``step_ms`` is the host's milliseconds on steps in a
-        pass: the launch of the one it dispatched, the wait and the
-        booking of the one it retired."""
-        t1 = time.monotonic()
-        with trace.phase("engine.step.sync"):
+        pass: the launch of the one it dispatched (``engine.step``), the
+        wait and the booking of the one it retired (these two
+        phases)."""
+        with self._phase("engine.step.sync"):
             nxt = np.array(step.nxt)   # [S] or [S, K+1]; the host sync point
-        with trace.phase("engine.step.book"):
+        with self._phase("engine.step.book"):
             self._book_step(step, nxt)
-        self._it_step_ms += (time.monotonic() - t1) * 1e3
 
     def _book_step(self, step: _StepInFlight, nxt) -> None:
         """What a step's synced tokens mean on the host: each slot that
@@ -3076,6 +3148,10 @@ class DecodeEngine:
         self._counters_base = self._model_counters()
         self.ttft_hist.reset()
         self.itl_hist.reset()
+        self.qwait_hist.reset()
+        self._clock.reset()
+        self._slowest_ns = 0
+        self._slowest = None
         self.completed = 0
         self.shed = 0
         self.tokens = 0
@@ -3122,6 +3198,8 @@ class DecodeEngine:
         elapsed = (time.monotonic() - t_first) if t_first else 0.0
         ttft = self.ttft_hist.percentiles((50, 99))
         itl = self.itl_hist.percentiles((50, 99))
+        qwait = self.qwait_hist.percentiles((50, 99))
+        slowest = self._slowest
         issued = self.completed + self.shed
         # KV pool occupancy: capacity is what bounds concurrency, so
         # the pool's free/live split (and the peak sequence count it
@@ -3286,6 +3364,23 @@ class DecodeEngine:
             "ttft_p99_ms": ttft[99],
             "itl_p50_ms": itl[50],
             "itl_p99_ms": itl[99],
+            # TTFT = queue wait + admission-to-first-token, each readable
+            "queue_wait_p50_ms": qwait[50],
+            "queue_wait_p99_ms": qwait[99],
+            # the loop's phases on the always-on host clock, since
+            # reset_stats() (docs/OBSERVABILITY.md "Engine phases"): the
+            # time under each phase, the loop gap between passes, the
+            # longest single engine.wait (a stalled CLIENT shows there,
+            # not in a pass) and the longest pass with its row
+            "phase_ms": self._clock.totals(),
+            "loop_gap_ms": self._clock.gap(),
+            "wait_ms_max": self._clock.wait_max_ns / 1e6,
+            "slowest_pass": None if slowest is None else {
+                "it": slowest[0], "ts": slowest[1],
+                "busy_ms": slowest[2] / 1e6,
+                "gap_before_ms": slowest[3] / 1e6,
+                "live": slowest[4], "queue": slowest[5],
+                "phases": self._clock.row_ms(slowest[6])},
             "slot_occupancy": (self._occ_sum / self._occ_n
                                if self._occ_n else 0.0),
             "kv_live_block_share": (

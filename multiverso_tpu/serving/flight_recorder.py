@@ -69,6 +69,15 @@ Record schema (:data:`FIELDS`, positional):
 ``steps_ahead``         1 when this pass's step was dispatched with the
                         step before still unread (its tokens taken from
                         the device), else 0
+``phases``              the pass's row of the engine's phase clock: the
+                        ms under each leaf phase of the pass, in the
+                        order the meta line's ``phases`` names them
+                        (``engine.record``'s entry ends where the record
+                        is built); ``busy_ms`` less their sum is the
+                        pass's time under no phase
+``gap_ms``              the loop gap before this pass: the loop thread's
+                        ms between the end of the pass before and the
+                        start of this one that no ``engine.wait`` covers
 ======================  =====================================================
 
 Timestamps are monotonic; the recorder captures a wall/mono anchor at
@@ -109,10 +118,12 @@ FIELDS = ("it", "ts", "busy_ms", "step_ms", "live", "reserved", "queue",
           "pool_live", "pool_shared", "version", "admitted", "completed",
           "spec_proposed", "spec_accepted", "kv_quant",
           "quant_scale_blocks", "kv_block_s", "tenants_live", "sp_chunks",
-          "kv_live_block_share", "chunks_behind_step", "steps_ahead")
+          "kv_live_block_share", "chunks_behind_step", "steps_ahead",
+          "phases", "gap_ms")
 
 
-def window_digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
+def window_digest(records: List[Dict[str, Any]],
+                  phases: Optional[List[str]] = None) -> Dict[str, Any]:
     """Whole-window utilization digest over dict records (oldest first) —
     the ONE copy of the wall/busy/gap math shared by
     :meth:`FlightRecorder.summary` and ``tools/engine_timeline.py``
@@ -121,12 +132,20 @@ def window_digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     The window opens when the first retained iteration's work began
     (``ts - busy_ms``) and closes at the last record. ``gaps`` lists
     every idle bubble — time between consecutive records net of the
-    later iteration's own work — sorted largest first."""
+    later iteration's own work — sorted largest first.
+
+    ``phases`` (the dump's meta line's) names the ``phases`` column:
+    ``phase_ms`` then sums it by phase over the records that carry it,
+    with the passes' time under no phase (``unphased``) and the loop
+    gaps between them (``loop_gap``), and ``slowest`` lists the three
+    longest passes with their rows. Records from before the column
+    leave both empty."""
     if not records:
         return {"wall_s": 0.0, "busy_frac": 0.0, "idle_frac": 0.0,
                 "prefill_tokens": 0, "decode_tokens": 0,
                 "prefill_share": 0.0, "steps": 0, "mean_step_ms": 0.0,
-                "max_idle_gap_ms": 0.0, "peak_live": 0, "gaps": []}
+                "max_idle_gap_ms": 0.0, "peak_live": 0, "gaps": [],
+                "phase_ms": {}, "slowest": []}
     t0 = records[0]["ts"] - records[0]["busy_ms"] / 1e3
     wall = max(records[-1]["ts"] - t0, 1e-9)
     busy_s = sum(r["busy_ms"] for r in records) / 1e3
@@ -142,6 +161,21 @@ def window_digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
                          "gap_ms": round(gap, 3),
                          "it": records[i]["it"]})
     gaps.sort(key=lambda g: g["gap_ms"], reverse=True)
+    phase_ms: Dict[str, float] = {}
+    slowest: List[Dict[str, Any]] = []
+    rowed = [r for r in records if r.get("phases")] if phases else []
+    if rowed:
+        for i, name in enumerate(phases):
+            phase_ms[name] = sum(r["phases"][i] for r in rowed)
+        phase_ms["unphased"] = (sum(r["busy_ms"] for r in rowed)
+                                - sum(phase_ms.values()))
+        phase_ms["loop_gap"] = sum(r.get("gap_ms", 0.0) for r in rowed)
+        slowest = [{"it": r["it"], "t_s": round(r["ts"] - t0, 6),
+                    "busy_ms": r["busy_ms"],
+                    "gap_ms": r.get("gap_ms", 0.0),
+                    "phases": dict(zip(phases, r["phases"]))}
+                   for r in sorted(rowed, key=lambda r: r["busy_ms"],
+                                   reverse=True)[:3]]
     return {
         "wall_s": wall,
         "busy_frac": min(1.0, busy_s / wall),
@@ -155,6 +189,8 @@ def window_digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         "max_idle_gap_ms": gaps[0]["gap_ms"] if gaps else 0.0,
         "peak_live": max(r["live"] + r["reserved"] for r in records),
         "gaps": gaps,
+        "phase_ms": phase_ms,
+        "slowest": slowest,
     }
 
 
@@ -220,10 +256,11 @@ class FlightRecorder:
             **self.meta,
         }
         digest = window_digest(recs)
-        # the per-bubble list is timeline_report's concern; the digest
-        # here rides in bench JSON lines, so keep it scalar-only
-        digest.pop("gaps")
-        digest.pop("peak_live")
+        # the per-bubble and per-phase lists are timeline_report's
+        # concern; the digest here rides in bench JSON lines, so keep it
+        # scalar-only
+        for key in ("gaps", "peak_live", "phase_ms", "slowest"):
+            digest.pop(key)
         out.update(digest)
         return out
 

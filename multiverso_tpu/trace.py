@@ -59,7 +59,7 @@ __all__ = [
     "enabled", "enable", "disable", "resume", "start_span", "span",
     "record_span", "current_span", "current_context", "export_chrome",
     "span_from_dict", "validate_chrome_events",
-    "PROFILER_PREFIX", "phase",
+    "PROFILER_PREFIX", "phase", "PhaseClock",
 ]
 
 # Span/trace ids: process-unique, allocation-cheap. itertools.count is
@@ -621,6 +621,174 @@ def phase(name: str):
     if not TraceAnnotation.is_enabled():
         return NULL_SPAN
     return TraceAnnotation(PROFILER_PREFIX + name)
+
+
+_now_ns = time.perf_counter_ns
+
+
+class _Phase:
+    """One phase of a :class:`PhaseClock`: a preallocated context
+    manager, entered and left on the clock's loop thread only, never
+    inside itself. One site, two clocks: the host's
+    ``perf_counter_ns`` always, and under a profiler session the same
+    enter and leave also write the ``bench.<name>`` annotation on the
+    profiler's clock (the stamps fall inside the annotation)."""
+
+    __slots__ = ("_clock", "_i", "_label", "_annotation", "_open", "t0_ns")
+
+    def __init__(self, clock: "PhaseClock", i: int, name: str,
+                 annotation) -> None:
+        self._clock, self._i = clock, i
+        self._label = PROFILER_PREFIX + name
+        self._annotation = annotation
+        self._open = None
+        self.t0_ns = 0          # the last enter, on the host's clock
+
+    def __enter__(self) -> "_Phase":
+        if self._annotation.is_enabled():
+            self._open = self._annotation(self._label)
+            self._open.__enter__()
+        self.t0_ns = _now_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        # a leaf: some ten of these a pass, so nothing is called here
+        dt = _now_ns() - self.t0_ns
+        clock, i = self._clock, self._i
+        clock.total_ns[i] += dt
+        clock.count[i] += 1
+        clock.row_ns[i] += dt
+        if self._open is not None:
+            ann, self._open = self._open, None
+            ann.__exit__(exc_type, exc, tb)
+        return False
+
+    def _leave(self, exc_type, exc, tb) -> int:
+        """The leave of a phase that is no leaf (no row entry): its
+        time, for what the subclass books from it."""
+        t1 = _now_ns()
+        self._clock.total_ns[self._i] += t1 - self.t0_ns
+        self._clock.count[self._i] += 1
+        if self._open is not None:
+            ann, self._open = self._open, None
+            ann.__exit__(exc_type, exc, tb)
+        return t1
+
+
+class _PassPhase(_Phase):
+    """The phase that is one loop pass: entering it opens the pass's
+    row and books the loop gap behind the pass before, leaving it
+    closes the pass."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Phase":
+        _Phase.__enter__(self)
+        clock, t0 = self._clock, self.t0_ns
+        clock.row_ns[:] = clock._zero_row
+        gap = 0
+        if clock.pass_end_ns:
+            gap = t0 - clock.pass_end_ns - clock._waited_ns
+            clock.gap_total_ns += gap
+            clock.gap_count += 1
+            if gap > clock.gap_max_ns:
+                clock.gap_max_ns = gap
+        clock.gap_ns = gap
+        clock._waited_ns = 0
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        clock = self._clock
+        clock.pass_end_ns = self._leave(exc_type, exc, tb)
+        clock.pass_ns = clock.pass_end_ns - self.t0_ns
+        return False
+
+
+class _WaitPhase(_Phase):
+    """The phase in which the loop has no work: out of every pass and
+    out of the loop gap."""
+
+    __slots__ = ()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dt = self._leave(exc_type, exc, tb) - self.t0_ns
+        clock = self._clock
+        clock._waited_ns += dt
+        if dt > clock.wait_max_ns:
+            clock.wait_max_ns = dt
+        return False
+
+
+class PhaseClock:
+    """The always-on host clock under one loop thread's phases.
+
+    ``clock.phase(name)`` hands out the phase's preallocated context
+    manager. Entering it stamps ``time.perf_counter_ns()`` (on Linux
+    ``time.monotonic()``'s clock, the flight recorder's) and, only
+    under a profiler session, opens the ``bench.<name>`` annotation as
+    :func:`phase` does; leaving it adds the nanoseconds to the phase's
+    running total and count and, for a leaf, to the current pass's row.
+    The loop thread is in the wait phase, in the pass phase, or between
+    the two: the time between the end of one pass and the start of the
+    next that no wait covers is the LOOP GAP (the loop's own glue, and
+    any stall of the thread there). Leaves are entered inside a pass,
+    one after another, so a pass's time under no leaf is its duration
+    less its row.
+
+    Integer lists allocated once, no object built a pass, no lock, no
+    I/O: the loop thread is the only writer. Other threads read
+    (``totals``, ``gap``), and :meth:`reset` moves a baseline instead
+    of zeroing what the loop thread adds to."""
+
+    def __init__(self, leaves, pass_name: str, wait_name: str) -> None:
+        from jax.profiler import TraceAnnotation
+
+        self.leaves = tuple(leaves)
+        self.names = self.leaves + (pass_name, wait_name)
+        self.total_ns = [0] * len(self.names)
+        self.count = [0] * len(self.names)
+        self._base_ns = list(self.total_ns)
+        self._base_count = list(self.count)
+        # the current pass: its leaves' nanoseconds and the gap behind
+        # the pass before; of the last pass left: its duration and its
+        # end
+        self.row_ns = [0] * len(self.leaves)
+        self._zero_row = tuple(self.row_ns)
+        self.pass_ns = self.pass_end_ns = 0
+        self.gap_ns = self.gap_total_ns = self.gap_count = 0
+        self.gap_max_ns = self.wait_max_ns = 0
+        self._gap_base = (0, 0)
+        self._waited_ns = 0
+        kinds = [_Phase] * len(self.leaves) + [_PassPhase, _WaitPhase]
+        self.phase = {name: kind(self, i, name, TraceAnnotation)
+                      for i, (name, kind) in enumerate(zip(self.names,
+                                                           kinds))
+                      }.__getitem__
+
+    def reset(self) -> None:
+        """Totals, counts and maxima start again from here (any
+        thread)."""
+        self._base_ns, self._base_count = list(self.total_ns), \
+            list(self.count)
+        self._gap_base = (self.gap_total_ns, self.gap_count)
+        self.gap_max_ns = self.wait_max_ns = 0
+
+    def totals(self) -> Dict[str, Dict[str, float]]:
+        """``{phase: {"ms", "n"}}`` since :meth:`reset`."""
+        ns, n = list(self.total_ns), list(self.count)
+        return {name: {"ms": (ns[i] - self._base_ns[i]) / 1e6,
+                       "n": n[i] - self._base_count[i]}
+                for i, name in enumerate(self.names)}
+
+    def gap(self) -> Dict[str, float]:
+        """The loop gap since :meth:`reset`: ``{"ms", "n", "max"}``."""
+        ns0, n0 = self._gap_base
+        return {"ms": (self.gap_total_ns - ns0) / 1e6,
+                "n": self.gap_count - n0, "max": self.gap_max_ns / 1e6}
+
+    def row_ms(self, row) -> Dict[str, float]:
+        """A copy of a pass's row by leaf name, in ms."""
+        return {name: ns / 1e6 for name, ns in zip(self.leaves, row)}
 
 
 def export_chrome(path: Optional[str] = None) -> dict:

@@ -947,12 +947,12 @@ def _watch_in_flight(engine):
 
     record = engine._record_iteration
 
-    def at_pass_end(t_work0):
+    def at_pass_end():
         names = in_flight()
         if (names.count(_StepInFlight.__name__) > 1
                 or names.count(_ChunkInFlight.__name__) > 1):
             broken.append(("pass end", names))
-        record(t_work0)
+        record()
 
     engine._record_iteration = at_pass_end
 

@@ -23,12 +23,13 @@ def _rec(it, ts, busy=1.0, step=0.5, live=1, reserved=0, queue=0,
          spec_proposed=-1, spec_accepted=-1, kv_quant=-1,
          quant_scale_blocks=-1, kv_block_s=-1.0, tenants_live=-1,
          sp_chunks=-1, kv_live_block_share=-1.0, chunks_behind_step=0,
-         steps_ahead=0):
+         steps_ahead=0, phases=(), gap_ms=0.0):
     return (it, ts, busy, step, live, reserved, queue, queue_age,
             prefill, decode, pool_free, pool_live, pool_shared, version,
             admitted, completed, spec_proposed, spec_accepted, kv_quant,
             quant_scale_blocks, kv_block_s, tenants_live, sp_chunks,
-            kv_live_block_share, chunks_behind_step, steps_ahead)
+            kv_live_block_share, chunks_behind_step, steps_ahead, phases,
+            gap_ms)
 
 
 # -- ring ---------------------------------------------------------------------
@@ -264,7 +265,7 @@ def test_steps_ahead_column_and_older_tuple_tolerance():
     """The run-ahead flag rides the END of FIELDS: 1 where a pass's
     step was dispatched with the step before still unread; a 25-field
     tuple from before the column still reads cleanly."""
-    assert FIELDS[-1] == "steps_ahead"
+    assert FIELDS[25] == "steps_ahead"
     fr = FlightRecorder(capacity=8, name="eng")
     fr.record(_rec(1, time.monotonic(), steps_ahead=1))
     assert fr.records()[0]["steps_ahead"] == 1

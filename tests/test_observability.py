@@ -463,7 +463,9 @@ def test_tracing_disabled_no_decode_hot_loop_overhead(mv_session,
     one event, or touch the collector — the hot loop's only tracing
     cost is the ``enabled()`` attribute read. The same holds for the
     loop's profiler phases: with no ``jax.profiler`` session running no
-    ``TraceAnnotation`` is built and every phase is ``NULL_SPAN``."""
+    ``TraceAnnotation`` is built, and the always-on phase clock under
+    the same sites builds no object a pass: its ten contexts are made
+    with the engine and entered again and again."""
     import jax
 
     from multiverso_tpu.models.transformer import (TransformerConfig,
@@ -472,7 +474,7 @@ def test_tracing_disabled_no_decode_hot_loop_overhead(mv_session,
 
     assert not trace.enabled()
     assert trace.phase("engine.step") is trace.NULL_SPAN
-    calls = {"span": 0, "record": 0, "annotation": 0}
+    calls = {"span": 0, "record": 0, "annotation": 0, "phase": 0}
     real_span_init = trace.Span.__init__
 
     def counting_init(self, *a, **kw):
@@ -495,6 +497,13 @@ def test_tracing_disabled_no_decode_hot_loop_overhead(mv_session,
     monkeypatch.setattr(jax.profiler.TraceAnnotation, "__init__",
                         counting_annotation)
     monkeypatch.setattr(trace.Span, "__init__", counting_init)
+    real_phase_init = trace._Phase.__init__
+
+    def counting_phase(self, *a, **kw):
+        calls["phase"] += 1
+        return real_phase_init(self, *a, **kw)
+
+    monkeypatch.setattr(trace._Phase, "__init__", counting_phase)
     monkeypatch.setattr(trace.TraceCollector, "record", counting_record)
 
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
@@ -502,10 +511,14 @@ def test_tracing_disabled_no_decode_hot_loop_overhead(mv_session,
     srv = InferenceServer("t")
     engine = srv.register_decoder("lm", TransformerLM(cfg), slots=2,
                                   max_prompt=8, max_new=8)
+    assert calls["phase"] == 10                  # made with the engine
+    calls["phase"] = 0
     out = srv.submit("lm", np.arange(1, 6, dtype=np.int32)).result(
         timeout=60)
     assert len(out["result"]) == 8               # 7 decode iterations ran
-    assert calls == {"span": 0, "record": 0, "annotation": 0}
+    assert calls == {"span": 0, "record": 0, "annotation": 0, "phase": 0}
+    # the clock ran (every pass entered its phases) and built nothing
+    assert engine.stats()["phase_ms"]["engine.iter"]["n"] >= 8
     assert trace.collector().spans() == []
     # the ALWAYS-ON flight recorder was live the whole time — proving
     # the zero-Span guarantee holds with black-box recording running —

@@ -14,6 +14,12 @@ post-hoc capacity questions the ring exists for:
   decode-only tail is the drain);
 * **what was the engine holding** — mean/peak live slots, queue depth
   and max queue age per bucket, pool occupancy when paged;
+* **which phase of the loop** — the engine's phase clock rides every
+  record (``phases``, ``gap_ms``; docs/OBSERVABILITY.md "Engine
+  phases"): the ms a pass under each leaf phase, under none, and
+  between passes, and the three slowest passes with their rows, so a
+  bubble has a phase or a gap to its name (dumps from before the
+  columns render without these lines);
 * **was speculation earning its keep** — drafts verified vs accepted
   per bucket as an acceptance-rate strip (spec engines only; pre-PR-11
   dumps and ``spec_k=0`` rings render without it).
@@ -79,14 +85,15 @@ def load_ring(path: str) -> Tuple[Dict[str, Any], List[Dict[str, Any]]]:
 
 
 def timeline_report(records: List[Dict[str, Any]], buckets: int = 40,
-                    top_gaps: int = 5) -> Dict[str, Any]:
+                    top_gaps: int = 5, phases=None) -> Dict[str, Any]:
     """Digest a record list into the report dict ``render`` prints.
 
     The window opens when the first retained iteration's work began
     (``ts - busy_ms``) and closes at the last record; every bucket
     aggregates the iterations whose record timestamp falls inside it.
+    ``phases`` is the meta line's list of the ``phases`` column's names.
     """
-    digest = window_digest(records)
+    digest = window_digest(records, phases)
     report = {"iterations": len(records), **digest,
               "gaps": digest["gaps"][:top_gaps], "buckets": []}
     report.pop("max_idle_gap_ms")
@@ -266,6 +273,19 @@ def render(report: Dict[str, Any], name: str = "") -> str:
         worst = ", ".join(f"{g['gap_ms']:.1f}ms@{g['t_s']:.3f}s"
                           for g in report["gaps"])
         lines.append(f"largest bubbles: {worst}")
+    if report.get("phase_ms"):
+        n = max(1, report["iterations"])
+        lines.append("phases (ms a pass): " + ", ".join(
+            f"{name.replace('engine.', '', 1)} {ms / n:.3f}"
+            for name, ms in report["phase_ms"].items()))
+        lines.append("slowest passes:")
+        for p in report["slowest"]:
+            top = max(p["phases"], key=p["phases"].get)
+            lines.append(
+                f"  it {p['it']} @{p['t_s']:.3f}s busy {p['busy_ms']:.3f}ms "
+                f"(gap before {p['gap_ms']:.3f}ms): {top} "
+                f"{p['phases'][top]:.3f}ms, under no phase "
+                f"{p['busy_ms'] - sum(p['phases'].values()):.3f}ms")
     if report["buckets"]:
         util = "".join(_bar(b["busy_frac"]) for b in report["buckets"])
         pf = "".join(_bar(b["prefill_share"]) for b in report["buckets"])
@@ -340,7 +360,8 @@ def main(argv=None) -> int:
     if not records:
         print("engine_timeline: dump holds no records", file=sys.stderr)
         return 2
-    report = timeline_report(records, buckets, args.top_gaps)
+    report = timeline_report(records, buckets, args.top_gaps,
+                             meta.get("phases"))
     print(render(report, meta.get("name", "")))
     return 0
 
