@@ -1,11 +1,13 @@
 """Model families: word2vec (skip-gram/CBOW), logistic regression/FTRL,
 the transformer LM parallelism showcase, and the serve-only LongCat-Flash,
-DeepSeek-V3-architecture and ``bailing_hybrid`` (Ling) shares; :func:`from_config` builds the LMs
-from a configuration dict."""
+DeepSeek-V3-architecture and ``bailing_hybrid`` (Ling) shares and the
+``mellum`` model; :func:`from_config` builds the LMs from a configuration
+dict."""
 
-from . import deepseek_v3, ling, longcat
+from . import deepseek_v3, ling, longcat, mellum
 from .deepseek_v3 import DeepSeekV3Config, DeepSeekV3LM
 from .ling import LingConfig, LingLM
+from .mellum import MellumConfig, MellumLM
 from .logreg import FTRLLogReg, LogReg, LogRegConfig, SparseLogReg
 from .longcat import LongCatConfig, LongCatLM
 from .transformer import TransformerConfig, TransformerLM
@@ -30,7 +32,7 @@ def from_config(cfg: dict, seed: int, **overrides):
             dtype=jnp.dtype(cfg["dtype"]),
             learning_rate=cfg["learning_rate"], momentum=cfg["momentum"],
             seed=int(seed), **overrides))
-    if kind in ("longcat_flash", "deepseek_v3", "bailing_hybrid"):
+    if kind in ("longcat_flash", "deepseek_v3", "bailing_hybrid", "mellum"):
         if overrides:
             raise TypeError(f"from_config: a {kind!r} model takes no "
                             f"overrides, got {sorted(overrides)}")
@@ -38,6 +40,8 @@ def from_config(cfg: dict, seed: int, **overrides):
             return LongCatLM(longcat.config_from_dict(cfg, seed))
         if kind == "bailing_hybrid":
             return LingLM(ling.config_from_dict(cfg, seed))
+        if kind == "mellum":
+            return MellumLM(mellum.config_from_dict(cfg, seed))
         return DeepSeekV3LM(deepseek_v3.config_from_dict(cfg, seed))
     raise ValueError(f"from_config: no model of kind {kind!r}")
 
@@ -50,6 +54,8 @@ __all__ = [
     "LingLM",
     "LongCatConfig",
     "LongCatLM",
+    "MellumConfig",
+    "MellumLM",
     "FTRLLogReg",
     "LogReg",
     "LogRegConfig",

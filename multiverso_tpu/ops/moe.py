@@ -244,20 +244,24 @@ def grouped_rows(group_sizes: jax.Array) -> jax.Array:
 
 
 def route_topk(u: jax.Array, router_w: jax.Array, router_bias: jax.Array,
-               top_k: int, scale: float) -> Tuple[jax.Array, jax.Array]:
+               top_k: int, scale: float, normalize: bool = False
+               ) -> Tuple[jax.Array, jax.Array]:
     """Softmax router over ALL of the layer's outputs, in float32.
 
     ``p = softmax(float32(u) @ router_w)``; the ``top_k`` largest of
     ``p + router_bias`` are chosen (the bias moves the choice, never the
-    gate); a chosen output's gate is ``scale * p`` and is not
-    renormalised. Returns ``(idx [T, k] int32, gates [T, k] float32)``.
-    No capacity: every token keeps all of its picks."""
+    gate); a chosen output's gate is ``scale * p``, not renormalised,
+    or with ``normalize`` ``scale * p / sum(p of the picks)`` (a softmax
+    over the picks). Returns ``(idx [T, k] int32, gates [T, k]
+    float32)``. No capacity: every token keeps all of its picks."""
     logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     p = jax.nn.softmax(logits, axis=-1)
     _, idx = jax.lax.top_k(p + router_bias.astype(jnp.float32), top_k)
-    gates = scale * jnp.take_along_axis(p, idx, axis=-1)
-    return idx.astype(jnp.int32), gates
+    gates = jnp.take_along_axis(p, idx, axis=-1)
+    if normalize:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), scale * gates
 
 
 def route_group_limited(u: jax.Array, router_w: jax.Array,

@@ -204,7 +204,8 @@ def paged_mq_attention(q: jax.Array, k_pool: jax.Array,
                        v_pool: Optional[jax.Array], tables: jax.Array,
                        lengths: jax.Array, *, scale: float, wv: int,
                        tile_blocks: int = _TILE_BLOCKS,
-                       interpret: Optional[bool] = None) -> jax.Array:
+                       interpret: Optional[bool] = None,
+                       name: str = "paged_mq_attention") -> jax.Array:
     """One query a slot against the slot's live pool blocks.
 
     ``q`` [S, H, W] in the pools' dtype; ``k_pool`` / ``v_pool``
@@ -214,23 +215,25 @@ def paged_mq_attention(q: jax.Array, k_pool: jax.Array,
     (the layer's base already added; entries past a slot's live blocks
     are never read); ``lengths`` [S] int32, live positions a slot
     (``pos + 1``; 0 for a dead lane, whose output is zeros). Scores are
-    ``q . k * scale`` in float32. Returns [S, H, wv] float32.
+    ``q . k * scale`` in float32. Returns [S, H, wv] float32. ``name``
+    is the kernel's op name in a profile: a model whose step reads two
+    kinds of cache with it tells the two apart by it.
     """
     if interpret is None:
         interpret = not _on_tpu()
     return _paged_mq_attention(
         q, k_pool, None if v_pool is k_pool else v_pool, tables, lengths,
         scale=float(scale), wv=wv, tile_blocks=tile_blocks,
-        interpret=interpret)
+        interpret=interpret, name=name)
 
 
 # jitted so that a step's calls, one a layer with the same shapes, are
 # traced and lowered to Mosaic ONCE (a call apiece cost ~0.3 s of every
 # set-up, compile cache warm or not: PERF.md, PR 29)
 @functools.partial(jax.jit, static_argnames=("scale", "wv", "tile_blocks",
-                                             "interpret"))
+                                             "interpret", "name"))
 def _paged_mq_attention(q, k_pool, v_pool, tables, lengths, *, scale, wv,
-                        tile_blocks, interpret):
+                        tile_blocks, interpret, name):
     S, H, W = q.shape
     shared = v_pool is None
     block_rows = k_pool.shape[1]
@@ -269,7 +272,7 @@ def _paged_mq_attention(q, k_pool, v_pool, tables, lengths, *, scale, wv,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_mq_attention",
+        name=name,
     )(lengths, tables.astype(jnp.int32).reshape(-1), chain, q,
       *((k_pool,) if shared else (k_pool, v_pool)))
     return out[:, :H]

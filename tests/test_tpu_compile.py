@@ -521,7 +521,7 @@ def _assert_held_experts(text, program, cfg, t):
     anew."""
     from multiverso_tpu.ops import moe
 
-    E = cfg.n_routed_experts
+    E = getattr(cfg, "n_routed_experts", None) or cfg.num_experts
     D = cfg.hidden_size
     F = getattr(cfg, "moe_intermediate_size", None) \
         or cfg.expert_ffn_hidden_size
@@ -625,6 +625,91 @@ def test_ling_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
         assert f"f32[{S},32,128,128]" not in text   # no slab of the pool
     else:       # three grouped products an expert layer, nothing else
         assert _kernel_calls(compiled) == 3 * cfg.n_expert_layers == 18
+
+
+@pytest.mark.parametrize("program,temp_mb", [("step", 50), ("chunk", 700)])
+def test_mellum_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
+                                           temp_mb):
+    """The two programs of ``serve_mellum2_closed128``
+    (``mellum2-12b-l8.json`` under ``closed128_p3072_gen1024.json``: 7.59
+    GB of weights, the full layers' 2.15 GB block pool, the sliding
+    layers' 1.61 GB of rings): weights and both pools are arguments, both
+    pools are aliased (16 and 575 MB of temporaries on the compile, PR
+    38), no copy of either pool and no ``[slots, T, 1024]`` view of the
+    full layers appears, arguments plus temporaries stay inside the
+    chip, and the step runs one ``paged_mq_attention`` a full layer and
+    one under the sliding layers' own name a sliding layer, reading each
+    ring where it lies."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import mellum
+    from multiverso_tpu.serving.programs import EngineSpec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mellum2-12b-l8.json")) as fh:
+        cfg = mellum.config_from_dict(json.load(fh), 1)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "closed128_p3072_gen1024.json")) as fh:
+        t = json.load(fh)
+    S, Bs, C = t["slots"], 16, t["prefill_token_budget"]
+    T = t["max_prompt"] + t["max_new"]
+    M = -(-T // Bs)
+    import multiverso_tpu.ops  # noqa: F401  (registers the submodule)
+
+    for module in ("paged_attention", "moe"):
+        monkeypatch.setattr(sys.modules[f"multiverso_tpu.ops.{module}"],
+                            "_on_tpu", lambda: True)
+    lm = object.__new__(mellum.MellumLM)        # no weights drawn
+    lm.config = cfg
+    progs = lm.serving_programs(EngineSpec(
+        name="cell", slots=S, max_prompt=t["max_prompt"],
+        max_new=t["max_new"], cache_len=T, block_size=Bs, blocks_per_seq=M,
+        pool_blocks=S * M, budget=C, prefix=False, tp=1, mesh=None,
+        kv_quant="none", param_quant="none", spec_k=0, prefill_sp="none",
+        donate=True))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place,
+                          jax.eval_shape(lambda: mellum.init_params(cfg)))
+    shapes = [shape for shape, _ in progs.pools]
+    assert shapes[:2] == [(2, S * M + 1, Bs, 1024), (6, S, 1024, 1024)]
+    assert progs.bytes_per_slot == 6 * 1024 * 1024 * 2      # 12.58 MB
+    pools = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in progs.pools]
+    bt = _ints(one_chip, S, M)
+    if program == "step":
+        args = (params, *pools, bt, _ints(one_chip, S), _ints(one_chip, S),
+                jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip))
+        compiled = progs.step.lower(*args).compile()
+    else:
+        args = (params, *pools, bt, _ints(one_chip), _ints(one_chip, C),
+                _ints(one_chip), _ints(one_chip))
+        compiled = progs.chunk.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    donated = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+                  for shape, dtype in progs.pools[:2])
+    assert donated > 3.7e9 and mem.alias_size_in_bytes >= donated
+    assert mem.temp_size_in_bytes < temp_mb * 10 ** 6
+    assert mem.argument_size_in_bytes > 11.3e9      # weights + pools
+    assert _fits_hbm(compiled, budget=15.75 * 2 ** 30)
+    text = compiled.as_text()
+    for copied in (f"copy(bf16[2,{S * M + 1},{Bs},1024]",
+                   f"copy(bf16[6,{S},1024,1024]",
+                   f"copy(bf16[{S},1024,1024]"):
+        assert copied not in text, copied
+    _assert_held_experts(text, program, cfg, t)
+    if program == "step":
+        calls = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\([^\n]*"
+                           r"custom_call_target=\"tpu_custom_call\"", text)
+        assert sorted(calls) == ["paged_mq_attention"] * 2 \
+            + [mellum.SWA_KERNEL] * 6
+        assert f"bf16[{S},{T},1024]" not in text    # no view of the pool
+    else:       # three grouped products an expert layer, nothing else
+        assert _kernel_calls(compiled) == 3 * cfg.num_hidden_layers == 24
 
 
 _COLLECTIVES = ("all-reduce(", "all-gather(", "all-to-all(",
