@@ -1,11 +1,12 @@
 """Model families: word2vec (skip-gram/CBOW), logistic regression/FTRL,
 the transformer LM parallelism showcase, and the serve-only LongCat-Flash,
-DeepSeek-V3-architecture and ``bailing_hybrid`` (Ling) shares and the
-``mellum`` model; :func:`from_config` builds the LMs from a configuration
-dict."""
+DeepSeek-V3-architecture, ``bailing_hybrid`` (Ling) and
+``glm_moe_dsa`` (GLM-5) shares and the ``mellum`` model;
+:func:`from_config` builds the LMs from a configuration dict."""
 
-from . import deepseek_v3, ling, longcat, mellum
+from . import deepseek_v3, glm5, ling, longcat, mellum
 from .deepseek_v3 import DeepSeekV3Config, DeepSeekV3LM
+from .glm5 import GLM5Config, GLM5LM
 from .ling import LingConfig, LingLM
 from .mellum import MellumConfig, MellumLM
 from .logreg import FTRLLogReg, LogReg, LogRegConfig, SparseLogReg
@@ -32,7 +33,8 @@ def from_config(cfg: dict, seed: int, **overrides):
             dtype=jnp.dtype(cfg["dtype"]),
             learning_rate=cfg["learning_rate"], momentum=cfg["momentum"],
             seed=int(seed), **overrides))
-    if kind in ("longcat_flash", "deepseek_v3", "bailing_hybrid", "mellum"):
+    if kind in ("longcat_flash", "deepseek_v3", "bailing_hybrid", "mellum",
+                "glm_moe_dsa"):
         if overrides:
             raise TypeError(f"from_config: a {kind!r} model takes no "
                             f"overrides, got {sorted(overrides)}")
@@ -42,6 +44,8 @@ def from_config(cfg: dict, seed: int, **overrides):
             return LingLM(ling.config_from_dict(cfg, seed))
         if kind == "mellum":
             return MellumLM(mellum.config_from_dict(cfg, seed))
+        if kind == "glm_moe_dsa":
+            return GLM5LM(glm5.config_from_dict(cfg, seed))
         return DeepSeekV3LM(deepseek_v3.config_from_dict(cfg, seed))
     raise ValueError(f"from_config: no model of kind {kind!r}")
 
@@ -50,6 +54,8 @@ __all__ = [
     "from_config",
     "DeepSeekV3Config",
     "DeepSeekV3LM",
+    "GLM5Config",
+    "GLM5LM",
     "LingConfig",
     "LingLM",
     "LongCatConfig",

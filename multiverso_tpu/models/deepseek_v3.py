@@ -16,8 +16,9 @@ its own gain at each use, layer ``l`` is::
   form in the decode step, the same ``[c, k_rope]`` cache row in a pool
   of 640-wide rows). What differs comes from this configuration: no
   ``mla_scale_*`` factors, YaRN rotary frequencies
-  (``rope_scaling``), and a softmax scale of ``(dn + dr)^-0.5 m^2``
-  with ``m = 0.1 mscale_all_dim ln(factor) + 1``.
+  (``rope_scaling``; plain rotary where it is None), and a softmax
+  scale of ``(dn + dr)^-0.5 m^2`` with ``m = 0.1 mscale_all_dim
+  ln(factor) + 1`` (1 under plain rotary).
 * **The router** (:func:`ops.moe.route_group_limited`): sigmoid scores,
   a token limited to ``topk_group`` of ``n_group`` groups of experts,
   ``num_experts_per_tok`` picks inside them by score plus
@@ -84,7 +85,7 @@ class DeepSeekV3Config(longcat.LatentCacheSizes):
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     # YaRN: factor, original_max_position_embeddings, beta_fast,
-    # beta_slow, mscale, mscale_all_dim (required: plain rotary is refused)
+    # beta_slow, mscale, mscale_all_dim; None: plain rotary
     rope_scaling: Optional[Dict[str, Any]] = None
     dtype: Any = jnp.bfloat16
     seed: int = 0
@@ -105,9 +106,11 @@ class DeepSeekV3Config(longcat.LatentCacheSizes):
     @property
     def softmax_divisor(self) -> float:
         """Scores are divided by ``sqrt(dn + dr) / m^2``: YaRN's
-        ``mscale_all_dim`` term, squared, on the softmax scale."""
+        ``mscale_all_dim`` term, squared, on the softmax scale (``m`` 1
+        under plain rotary)."""
         y = self.rope_scaling
-        m = 0.1 * y["mscale_all_dim"] * math.log(y["factor"]) + 1.0
+        m = 1.0 if y is None else \
+            0.1 * y["mscale_all_dim"] * math.log(y["factor"]) + 1.0
         return math.sqrt(self.qk_nope_head_dim
                          + self.qk_rope_head_dim) / (m * m)
 
@@ -377,11 +380,13 @@ class DeepSeekV3LM:
         who = "DeepSeekV3LM"
         check_share(who, c)
         y = c.rope_scaling
-        if y is None or y.get("type", "yarn") != "yarn" \
-                or y["factor"] <= 1 or y["mscale"] != y["mscale_all_dim"]:
-            Log.fatal(f"{who}: rope_scaling is not supported but as YaRN "
-                      "with a factor over 1 and mscale == mscale_all_dim "
-                      f"(the rotation itself unscaled), got {y}")
+        if y is not None and (y.get("type", "yarn") != "yarn"
+                              or y["factor"] <= 1
+                              or y["mscale"] != y["mscale_all_dim"]):
+            Log.fatal(f"{who}: rope_scaling is not supported but as None "
+                      "(plain rotary) or YaRN with a factor over 1 and "
+                      "mscale == mscale_all_dim (the rotation itself "
+                      f"unscaled), got {y}")
         if not c.norm_topk_prob:
             Log.fatal(f"{who}: norm_topk_prob false is not supported: the "
                       "picks' gates are normalised to sum 1")

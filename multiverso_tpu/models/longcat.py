@@ -308,12 +308,23 @@ def rope(x, pos, inv):
                      axis=-1).reshape(x.shape)
 
 
-def mla_project(cfg: LongCatConfig, w, x, pos, width: int = 0):
+def query_latent(cfg: LongCatConfig, w, x):
+    """The query latent ``c_q = N(x W_qa)`` [T, rq] of the normed ``x``
+    [T, D], in the model's dtype: what :func:`mla_project` multiplies by
+    ``W_qb`` (and a sparse attention's indexer reads)."""
+    sq = math.sqrt(cfg.hidden_size / cfg.q_lora_rank) \
+        if cfg.mla_scale_q_lora else 1.0
+    return rmsnorm(jnp.dot(x, w["w_qa"], preferred_element_type=jnp.float32),
+                   w["q_norm"] * sq, cfg.rms_norm_eps, cfg.dtype)
+
+
+def mla_project(cfg: LongCatConfig, w, x, pos, width: int = 0, c_q=None):
     """Queries and the cache rows of tokens ``x`` [T, D] (already
     normed) at positions ``pos``: ``(q_nope [T, H, dn], q_rope [T, H,
     dr], row [T, rkv + dr])``, all in the model's dtype; ``row`` is what
     the cache holds: ``[c, k_rope]`` after norm, scale and rotation
-    (padded with zeros to ``width``, the pool's, where given)."""
+    (padded with zeros to ``width``, the pool's, where given). ``c_q``
+    hands in :func:`query_latent`'s where the caller has it."""
     f32, dt = jnp.float32, cfg.dtype
     H, dn, dr = cfg.num_attention_heads, cfg.qk_nope_head_dim, \
         cfg.qk_rope_head_dim
@@ -323,9 +334,8 @@ def mla_project(cfg: LongCatConfig, w, x, pos, width: int = 0):
     if rq is None:      # no query latent: one projection
         q = jnp.dot(x, w["w_q"], preferred_element_type=f32)
     else:
-        sq = math.sqrt(D / rq) if cfg.mla_scale_q_lora else 1.0
-        c_q = rmsnorm(jnp.dot(x, w["w_qa"], preferred_element_type=f32),
-                      w["q_norm"] * sq, cfg.rms_norm_eps, dt)
+        if c_q is None:
+            c_q = query_latent(cfg, w, x)
         q = jnp.dot(c_q, w["w_qb"], preferred_element_type=f32)
     q = q.reshape(T, H, dn + dr)
     q_rope = rope(q[..., dn:], pos, rope_frequencies(cfg)).astype(dt)
@@ -558,14 +568,23 @@ def chunk_rows(pool, block_tables, slot, chunk: int, offset, length,
     return bt_row, pos_ix, valid, blk_ix, off, mask
 
 
-def chunk_attend(cfg, w, x, pool, sub: int, rows, t_logical: int):
+def chunk_attend(cfg, w, x, pool, sub: int, rows, t_logical: int,
+                 select=None):
     """MLA sublayer ``sub`` of a prefill chunk on the normed ``x``
     [C, D]: the chunk's cache rows written at ``rows``
     (:func:`chunk_rows`), then its queries attend the slot's rows
-    EXPANDED to per-head keys and values. Returns ``(pool, out [C, D]
-    float32)``."""
+    EXPANDED to per-head keys and values. ``select(pool, c_q) -> (pool,
+    chosen [C, T])`` (a learned sparse attention, from the query latent
+    :func:`query_latent`) keeps what it needs in the pool and says which
+    positions each query reads: ANDed into the causal mask. Returns
+    ``(pool, out [C, D] float32)``."""
     bt_row, pos_ix, _, blk_ix, off, mask = rows
-    q_nope, q_rope, new = mla_project(cfg, w, x, pos_ix, pool.shape[-1])
+    c_q = None
+    if select is not None:
+        c_q = query_latent(cfg, w, x)
+        pool, chosen = select(pool, c_q)
+        mask = mask & chosen
+    q_nope, q_rope, new = mla_project(cfg, w, x, pos_ix, pool.shape[-1], c_q)
     pool = pool.at[sub, blk_ix, off].set(new)
     view = _view(pool, sub, bt_row, t_logical)
     return pool, mla_expanded(cfg, w, q_nope, q_rope, view, mask,

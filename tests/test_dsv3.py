@@ -413,7 +413,8 @@ def test_unsupported_features_refused_at_construction(mv_session, feature,
     (dict(num_experts_per_tok=17), "picks"),
     (dict(first_k_dense_replace=4), "first_k_dense_replace"),
     (dict(rope_scaling=dict(TOY["rope_scaling"], mscale=0.5)), "mscale"),
-    (dict(rope_scaling=None), "rope_scaling"),
+    (dict(rope_scaling=dict(TOY["rope_scaling"], type="linear")),
+     "rope_scaling"),
     (dict(rope_scaling=dict(TOY["rope_scaling"], factor=1)), "factor"),
     (dict(norm_topk_prob=False), "norm_topk_prob"),
     (dict(n_shared_experts=0), "n_shared_experts"),

@@ -712,6 +712,90 @@ def test_mellum_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
         assert _kernel_calls(compiled) == 3 * cfg.num_hidden_layers == 24
 
 
+@pytest.mark.parametrize("program,temp_mb", [("step", 100), ("chunk", 1400)])
+def test_glm5_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
+                                         temp_mb):
+    """The two programs of ``serve_glm5_ep16_closed16`` (``glm5-ep16.json``
+    under ``closed16_p8192_gen512.json``: 7.83 GB of weights, a 1.07 GB
+    pool of 5 latent rows and one row of index keys a token, 16 slots of
+    8,704 positions in 64-row blocks): weights and pool are arguments,
+    the pool is aliased and never copied, arguments plus temporaries stay
+    inside the chip (47 MB and 1.25 GB of temporaries on the compile: the
+    chunk's masked attention holds ``[64, 512, 8704]`` float32 scores).
+    The step runs one ``dsa_index_scores`` Mosaic call a layer and no
+    other kernel, gathers no ``[slots, T, 640]`` view, and attends over
+    the ``[slots, 2048, 640]`` rows its selection gathered. The held
+    experts: see :func:`_assert_held_experts`."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import glm5
+    from multiverso_tpu.ops import sparse_attention
+    from multiverso_tpu.serving.programs import EngineSpec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm5-ep16.json")) as fh:
+        cfg = glm5.config_from_dict(json.load(fh), 1)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "closed16_p8192_gen512.json")) as fh:
+        t = json.load(fh)
+    S, C = t["slots"], t["prefill_token_budget"]
+    Bs = t["engine"]["kv_block_size"]
+    T = t["max_prompt"] + t["max_new"]
+    M = -(-T // Bs)
+    for module in ("paged_attention", "moe"):
+        monkeypatch.setattr(sys.modules[f"multiverso_tpu.ops.{module}"],
+                            "_on_tpu", lambda: True)
+    monkeypatch.setattr(sparse_attention, "_on_tpu", lambda: True)
+    lm = object.__new__(glm5.GLM5LM)            # no weights drawn
+    lm.config = cfg
+    progs = lm.serving_programs(EngineSpec(
+        name="cell", slots=S, max_prompt=t["max_prompt"],
+        max_new=t["max_new"], cache_len=T, block_size=Bs, blocks_per_seq=M,
+        pool_blocks=S * M, budget=C, prefix=False, tp=1, mesh=None,
+        kv_quant="none", param_quant="none", spec_k=0, prefill_sp="none",
+        donate=True))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place,
+                          jax.eval_shape(lambda: glm5.init_params(cfg)))
+    (pshape, pdtype), (cshape, cdtype) = progs.pools
+    assert pshape == (6, S * M + 1, Bs, 640) and cshape == (5, 23)
+    pool = jax.ShapeDtypeStruct(pshape, pdtype, sharding=one_chip)
+    counters = jax.ShapeDtypeStruct(cshape, cdtype, sharding=one_chip)
+    bt = _ints(one_chip, S, M)
+    if program == "step":
+        compiled = progs.step.lower(
+            params, pool, counters, bt, _ints(one_chip, S),
+            _ints(one_chip, S),
+            jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)).compile()
+    else:
+        compiled = progs.chunk.lower(
+            params, pool, counters, bt, _ints(one_chip), _ints(one_chip, C),
+            _ints(one_chip), _ints(one_chip)).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pshape)) * 2
+    assert pool_bytes > 1.06e9 and mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < temp_mb * 10 ** 6
+    assert mem.argument_size_in_bytes > 8.8e9       # weights + pool
+    assert _fits_hbm(compiled, budget=15.75 * 2 ** 30)
+    text = compiled.as_text()
+    assert f"copy(bf16[6,{S * M + 1},{Bs},640]" not in text
+    _assert_held_experts(text, program, cfg, t)
+    if program == "step":
+        calls = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\([^\n]*"
+                           r"custom_call_target=\"tpu_custom_call\"", text)
+        assert sorted(calls) == [sparse_attention.DSA_KERNEL] * 5
+        assert _kernel_calls(compiled) == 5
+        assert f"bf16[{S},{T},640]" not in text     # no view of the pool
+        assert f"= bf16[{S},{cfg.index_topk},640]" in text
+    else:       # three grouped products an expert layer, nothing else
+        assert _kernel_calls(compiled) == 3 * cfg.n_expert_layers == 12
+
+
 _COLLECTIVES = ("all-reduce(", "all-gather(", "all-to-all(",
                 "collective-permute(", "reduce-scatter(")
 
