@@ -42,10 +42,12 @@ A prefill chunk scores its queries against the slot's keys and ANDs the
 exact top-k mask (:func:`ops.sparse_attention.top_k_mask`) into the
 expanded attention's causal mask (``longcat.chunk_attend``'s
 ``select``), as DeepSeek's own prefill runs DSA as masked dense
-attention. :meth:`GLM5LM.attended_positions` runs the two functions
-once more over one sequence and hands out the positions a step's
-attention read in each layer, so that a check can hold the selection
-itself, and not only the tokens it yields, to a reference.
+attention, over the key tiles up to the chunk's last valid row
+(``longcat.mla_expanded_tiled``). :meth:`GLM5LM.attended_positions`
+runs the two functions once more over one sequence and hands out the
+positions a step's attention read in each layer, so that a check can
+hold the selection itself, and not only the tokens it yields, to a
+reference.
 
 Departures from the published inference code: the indexer runs on
 bfloat16 operands with float32 accumulation where that code casts to

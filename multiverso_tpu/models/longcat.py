@@ -27,8 +27,9 @@ a second, pool-major layout and copies between the two: 3.8 GB of
 temporaries a step where this one has 0.57 GB, and the chunk is refused
 for memory, so that width was never timed on the chip).
 A prefill chunk expands the slot's rows to per-head keys and values
-(``c W_kvb``) and attends causally; the decode step attends IN THE
-LATENT: ``W_kvb``'s key half is absorbed into the query
+(``c W_kvb``) and attends causally, a key tile at a time over the tiles
+its rows can reach (:func:`mla_expanded_tiled`); the decode step attends
+IN THE LATENT: ``W_kvb``'s key half is absorbed into the query
 (``q~_h = q_nope_h W_kvb[k,h]^T``) and its value half into the output
 (``(P c) W_kvb[v,h]``), so a step reads 576 values a token and not
 ``heads x (192 + 128)``. :func:`mla_expanded` and :func:`mla_latent` are
@@ -69,6 +70,9 @@ from ..ops import paged_attention as paged_kernel
 from ..ops.moe import COUNT_SCALARS, held_expert_layer, route_topk, swiglu
 
 _NEG_INF = -1e30
+# positions a key tile of a prefill chunk's expanded attention: it
+# divides every served cell's logical cache length
+KEY_TILE = 256
 
 
 class LatentCacheSizes:
@@ -375,8 +379,21 @@ def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask,
     is :func:`head_gate`'s. Returns the sublayer's output [C, D] in
     float32."""
     f32, dt = jnp.float32, cfg.dtype
+    s, v = _expanded_scores(cfg, _kvb(cfg, w), q_nope, q_rope, rows)
+    p = jax.nn.softmax(jnp.where(mask[None], s, _NEG_INF), axis=-1)
+    o = _gated(jnp.einsum("hqt,thd->qhd", p.astype(dt), v,
+                          preferred_element_type=f32), gate).astype(dt)
+    return jnp.dot(o.reshape(o.shape[0], -1), w["w_o"],
+                   preferred_element_type=f32)
+
+
+def _expanded_scores(cfg: LongCatConfig, kvb, q_nope, q_rope, rows):
+    """``rows`` [T, >= rkv + dr] expanded by ``kvb`` (:func:`_kvb`'s) to
+    per-head values ``v`` [T, H, dv] and keys, and the queries' float32
+    scores over them ``s`` [H, C, T]: ``(s, v)``."""
+    f32, dt = jnp.float32, cfg.dtype
     rkv = cfg.kv_lora_rank
-    wk, wv = _kvb(cfg, w)
+    wk, wv = kvb
     c, k_rope = rows[:, :rkv], rows[:, rkv:cfg.cache_width]
     k_nope = jnp.einsum("tc,chd->thd", c, wk,
                         preferred_element_type=f32).astype(dt)
@@ -386,11 +403,53 @@ def mla_expanded(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask,
                     preferred_element_type=f32)
          + jnp.einsum("qhr,tr->hqt", q_rope, k_rope,
                       preferred_element_type=f32)) / cfg.softmax_divisor
-    p = jax.nn.softmax(jnp.where(mask[None], s, _NEG_INF), axis=-1)
-    o = _gated(jnp.einsum("hqt,thd->qhd", p.astype(dt), v,
-                          preferred_element_type=f32), gate).astype(dt)
-    return jnp.dot(o.reshape(o.shape[0], -1), w["w_o"],
-                   preferred_element_type=f32)
+    return s, v
+
+
+def mla_expanded_tiled(cfg: LongCatConfig, w, q_nope, q_rope, rows, mask,
+                       reach, gate=None):
+    """:func:`mla_expanded` over :data:`KEY_TILE`-position tiles (or one
+    tile of T) of ``rows`` [T, >= rkv + dr], visiting only the tiles
+    before ``reach`` (traced: the positions the last query can see) with
+    an online softmax: no ``[H, C, T]`` array is formed. Each tile's
+    rows are expanded and scored as :func:`mla_expanded` does; its
+    probabilities are zeroed under ``mask`` [C, T] and cast to the
+    model's dtype before the value product. A query that no visited
+    tile lets it see gets 0. Returns [C, D] float32."""
+    f32, dt = jnp.float32, cfg.dtype
+    kvb = _kvb(cfg, w)
+    C, H = q_nope.shape[:2]
+    tk = min(KEY_TILE, rows.shape[0])
+    n = -(-rows.shape[0] // tk)
+    pad = n * tk - rows.shape[0]
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        mask = jnp.pad(mask, ((0, 0), (0, pad)))
+
+    def tile(j, carry):
+        m, l, acc = carry
+        r = jax.lax.dynamic_slice_in_dim(rows, j * tk, tk)
+        live = jax.lax.dynamic_slice_in_dim(mask, j * tk, tk, axis=1)[None]
+        s, v = _expanded_scores(cfg, kvb, q_nope, q_rope, r)
+        s = jnp.where(live, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # a row masked over every tile so far has m_new = _NEG_INF, where
+        # exp(s - m_new) = 1: the mask zeroes it
+        p = jnp.where(live, jnp.exp(s - m_new[..., None]), 0.0)
+        scale = jnp.exp(m - m_new)
+        pv = jnp.einsum("hqt,thd->hqd", p.astype(dt), v,
+                        preferred_element_type=f32)
+        return (m_new, scale * l + jnp.sum(p, axis=-1),
+                scale[..., None] * acc + pv)
+
+    trips = jnp.clip((reach + tk - 1) // tk, 0, n)
+    _, l, acc = jax.lax.fori_loop(
+        0, trips, tile, (jnp.full((H, C), _NEG_INF, f32),
+                         jnp.zeros((H, C), f32),
+                         jnp.zeros((H, C, kvb[1].shape[-1]), f32)))
+    o = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    o = _gated(jnp.swapaxes(o, 0, 1), gate).astype(dt)
+    return jnp.dot(o.reshape(C, -1), w["w_o"], preferred_element_type=f32)
 
 
 def latent_query(cfg: LongCatConfig, w, q_nope, q_rope, width: int):
@@ -573,12 +632,13 @@ def chunk_attend(cfg, w, x, pool, sub: int, rows, t_logical: int,
     """MLA sublayer ``sub`` of a prefill chunk on the normed ``x``
     [C, D]: the chunk's cache rows written at ``rows``
     (:func:`chunk_rows`), then its queries attend the slot's rows
-    EXPANDED to per-head keys and values. ``select(pool, c_q) -> (pool,
-    chosen [C, T])`` (a learned sparse attention, from the query latent
-    :func:`query_latent`) keeps what it needs in the pool and says which
-    positions each query reads: ANDed into the causal mask. Returns
-    ``(pool, out [C, D] float32)``."""
-    bt_row, pos_ix, _, blk_ix, off, mask = rows
+    EXPANDED to per-head keys and values, over the key tiles up to the
+    last valid row's position alone (:func:`mla_expanded_tiled`).
+    ``select(pool, c_q) -> (pool, chosen [C, T])`` (a learned sparse
+    attention, from the query latent :func:`query_latent`) keeps what it
+    needs in the pool and says which positions each query reads: ANDed
+    into the causal mask. Returns ``(pool, out [C, D] float32)``."""
+    bt_row, pos_ix, valid, blk_ix, off, mask = rows
     c_q = None
     if select is not None:
         c_q = query_latent(cfg, w, x)
@@ -587,8 +647,9 @@ def chunk_attend(cfg, w, x, pool, sub: int, rows, t_logical: int,
     q_nope, q_rope, new = mla_project(cfg, w, x, pos_ix, pool.shape[-1], c_q)
     pool = pool.at[sub, blk_ix, off].set(new)
     view = _view(pool, sub, bt_row, t_logical)
-    return pool, mla_expanded(cfg, w, q_nope, q_rope, view, mask,
-                              head_gate(cfg, w, x))
+    return pool, mla_expanded_tiled(cfg, w, q_nope, q_rope, view, mask,
+                                    pos_ix[0] + jnp.sum(valid),
+                                    head_gate(cfg, w, x))
 
 
 def greedy_next(cfg, params, h, tok, pos, active):

@@ -1124,6 +1124,12 @@ class DecodeEngine:
         # the bench window
         self.prefill_chunks = 0
         self.chunks_behind_step = 0
+        # over the prefill chunks dispatched, the positions each chunk's
+        # last row can see (offset + length), summed: over chunks x the
+        # programs' logical cache length a slot, the share of the keys a
+        # chunk program is compiled for that chunks could reach; resets
+        # with the bench window
+        self.chunk_reach = 0
         # steps dispatched, those of them dispatched with the step
         # before still unread (its tokens taken from the device), and
         # the times the loop retired what was in flight ahead of its
@@ -2200,6 +2206,7 @@ class DecodeEngine:
             np.int32(req.slot), toks, np.int32(off), np.int32(n))
         self._pools = tuple(pools)
         self.prefill_chunks += 1
+        self.chunk_reach += off + n
         req.pf_off = off + n
         req.pf_chunks += 1
         if sp:
@@ -3170,6 +3177,7 @@ class DecodeEngine:
         self.seqpar_chunks = 0
         self.prefill_chunks = 0
         self.chunks_behind_step = 0
+        self.chunk_reach = 0
         self.steps = 0
         self.steps_ahead = 0
         self.drains = {}
@@ -3395,6 +3403,10 @@ class DecodeEngine:
             "prefill_token_budget": self._budget,
             "prefill_tokens": self.prefill_tokens,
             "prefill_chunks": self.prefill_chunks,
+            "chunk_context_share": (
+                self.chunk_reach / (self.prefill_chunks
+                                    * self._blocks_per_seq * self._block_size)
+                if self.prefill_chunks else 0.0),
             "chunks_behind_step": self.chunks_behind_step,
             "steps": self.steps,
             "steps_ahead": self.steps_ahead,

@@ -925,6 +925,66 @@ def test_kv_live_block_share_counts_the_steps_live_blocks(mv_session):
         [b / (slots * M) for b in want])
 
 
+def test_one_tiled_chunk_program_serves_every_offset(mv_session,
+                                                     monkeypatch):
+    """A latent-pool model's chunk attends 4-position key tiles up to its
+    last valid row (``longcat.mla_expanded_tiled``, the trip count
+    traced): prompts cut into chunks at offsets 0 to 32, long and short,
+    go through ONE compiled chunk program (``prefill_traces`` 1), every
+    served token is the best logit of the model's dense forward pass at
+    its position, and ``chunk_context_share`` is exactly the chunks'
+    ``sum(offset + length)`` over ``chunks x M x Bs``, ``M x Bs`` 48
+    where the programs' cache is 45 positions."""
+    import jax
+
+    from multiverso_tpu.models import from_config, longcat
+    from multiverso_tpu.serving import InferenceServer
+
+    monkeypatch.setattr(longcat, "KEY_TILE", 4)
+    toy = dict(
+        model="longcat_flash", vocab_size=128, hidden_size=32,
+        ffn_hidden_size=64, expert_ffn_hidden_size=16, num_layers=1,
+        num_attention_heads=2, kv_lora_rank=16, q_lora_rank=16,
+        qk_rope_head_dim=8, v_head_dim=8, qk_nope_head_dim=8,
+        routed_scaling_factor=6, n_routed_experts=4, expert_offset=0,
+        max_position_embeddings=64, zero_expert_num=4, moe_topk=2,
+        dtype="float32", published={"n_routed_experts": 8})
+    # 45 positions: the rows padded to whole tiles
+    budget, Bs, max_prompt, max_new = 8, 4, 40, 5
+    with jax.default_matmul_precision("highest"):
+        lm = from_config(toy, 3)
+        srv = InferenceServer("t")
+        engine = srv.register_decoder(
+            "lm", lm, slots=2, max_prompt=max_prompt, max_new=max_new,
+            kv_block_size=Bs, prefill_token_budget=budget,
+            prefix_cache=False)
+        engine.warmup()
+        engine.reset_stats()
+        rng = np.random.default_rng(2)
+        lengths = (5, 8, 19, 40)
+        for plen in lengths:
+            prompt = rng.integers(1, 128, plen).astype(np.int32)
+            out = srv.submit("lm", {"prompt": prompt, "max_new": max_new}
+                             ).result(timeout=120)["result"]
+            # one causal pass over a common length: the tail pads
+            # change no row before them
+            seq = np.zeros(max_prompt + max_new, np.int32)
+            seq[:plen + len(out)] = np.concatenate([prompt, out])
+            logits = np.asarray(lm.logits(seq))
+            for i, tok in enumerate(out):
+                row = logits[plen - 1 + i]
+                assert row.max() - row[tok] <= 2e-4, (plen, i)
+        stats = engine.stats()
+        srv.stop()
+    reach = [min(off + budget, p) for p in lengths
+             for off in range(0, p, budget)]
+    M = -(-(max_prompt + max_new) // Bs)
+    assert stats["prefill_traces"] == 1 and stats["step_traces"] == 1
+    assert stats["prefill_chunks"] == len(reach)
+    assert stats["chunk_context_share"] == pytest.approx(
+        sum(reach) / (len(reach) * M * Bs))
+
+
 # -- the loop's order: one pass of programs ahead of the host -----------------
 
 def _watch_in_flight(engine):

@@ -141,6 +141,39 @@ def test_chunk_program_logits_are_the_references(ref, n):
     np.testing.assert_allclose(np.asarray(logits), want, atol=TOL)
 
 
+@pytest.mark.parametrize("offset,n", [(0, 16), (40, 16), (64, 16),
+                                      (80, 9)])
+def test_chunk_with_selection_is_the_dense_form(monkeypatch, offset, n):
+    """A chunk with the selection on, its attention over 8-position key
+    tiles (12 over the 96-position slot), against the same chunk whose
+    attention is ``longcat.mla_expanded`` over every position under the
+    same mask: at offsets 0, 40 (the selection biting), 64 and the last
+    chunk, short, the logits and every row the chunk wrote in every
+    layer (so every valid row of every layer's input) agree."""
+    lm = from_config(TOY, SEED)
+    monkeypatch.setattr(longcat, "KEY_TILE", 8)
+
+    def dense(cfg, w, q_nope, q_rope, rows, mask, reach, gate=None):
+        return longcat.mla_expanded(cfg, w, q_nope, q_rope, rows, mask, gate)
+
+    def run():
+        progs, pools, tables = _programs(lm)
+        pools, _ = _prefill(progs, lm.params, pools, tables, 1,
+                            _tokens(99, 89))
+        toks = np.zeros(16, np.int32)
+        toks[:n] = _tokens(offset, n)
+        *pools, logits = progs.chunk(lm.params, *pools, tables,
+                                     np.int32(1), toks, np.int32(offset),
+                                     np.int32(n))
+        return np.asarray(pools[0])[:, tables[1]], np.asarray(logits)
+
+    pool, logits = run()
+    monkeypatch.setattr(longcat, "mla_expanded_tiled", dense)
+    want_pool, want_logits = run()
+    np.testing.assert_allclose(logits, want_logits, atol=1e-5)
+    np.testing.assert_allclose(pool, want_pool, atol=1e-5)
+
+
 def test_step_program_tokens_are_the_references(ref):
     """Prefill, then 16 decode steps over three slots at once (one dead
     lane among them at first): every served token is within ``TOL`` of

@@ -3,8 +3,9 @@ CPU, seeded random weights, float32 (``benchmarks/reference/
 longcat-flash-ep32.py`` imports nothing of the program): the full
 forward pass; chunked prefill and decode THROUGH ``DecodeEngine`` (logits,
 not tokens: the gap of each served token in the reference's logits); the
-two MLA forms; the expert layer's shares, picks and planted imbalance;
-and what the model refuses at engine construction."""
+two MLA forms, and the prefill chunk's tiled form against the expanded
+one; the expert layer's shares, picks and planted imbalance; and what
+the model refuses at engine construction."""
 
 import numpy as np
 import pytest
@@ -168,6 +169,82 @@ def test_mla_latent_form_equals_expanded_form():
         jnp.stack([g[1] for g in got]), jnp.stack([g[2] for g in got]), pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(jnp.stack(want)),
                                atol=TOL)
+
+
+# -- the chunk's tiled form -------------------------------------------------------
+TILE, POOL_T, CHUNK = 8, 64, 12
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("offset,length,select", [
+    (0, CHUNK, False), (TILE - 1, CHUNK, False), (TILE, CHUNK, False),
+    (30, CHUNK, False), (POOL_T - CHUNK, CHUNK, False),
+    (POOL_T - TILE, 5, False), (30, 5, False),
+    (TILE, CHUNK, True), (30, CHUNK, True), (POOL_T - TILE, 5, True)],
+    ids=["offset_0", "offset_tile_less_1", "offset_tile", "mid_pool",
+         "last_tiles", "last_tile_short", "mid_pool_short",
+         "select_offset_tile", "select_mid_pool", "select_last_short"])
+def test_tiled_chunk_form_is_the_expanded_form(monkeypatch, dtype, offset,
+                                               length, select):
+    """``mla_expanded_tiled`` over ``TILE``-position tiles against
+    ``mla_expanded`` over the whole pool, the same rows and queries: a
+    chunk of ``CHUNK`` rows at ``offset`` whose first ``length`` are
+    valid, under the causal mask, and with ``select`` under a selection
+    that also empties the first two tiles for every other row (a row
+    whose visited tiles are all masked so far: the online softmax's
+    finite -1e30 must not count them). The valid rows agree to 1e-5 in
+    float32 and to one rounding in bfloat16; the pad rows are finite."""
+    monkeypatch.setattr(longcat, "KEY_TILE", TILE)
+    cfg = longcat.config_from_dict(dict(TOY, dtype=dtype), 5)
+    w = longcat.init_params(cfg)["blocks"][0]["mla"][1]
+    rng = np.random.default_rng(offset + 100 * length)
+    x = jnp.asarray(rng.standard_normal((POOL_T, cfg.hidden_size)),
+                    cfg.dtype)
+    q_nope, q_rope, rows = longcat.mla_project(
+        cfg, w, x, jnp.arange(POOL_T), cfg.pool_width)
+    pos = offset + np.arange(CHUNK)
+    at = np.minimum(pos, POOL_T - 1)
+    mask = np.arange(POOL_T)[None, :] <= pos[:, None]
+    if select:
+        chosen = rng.random((CHUNK, POOL_T)) < 0.5
+        chosen[::2, :2 * TILE] = False
+        chosen[np.arange(CHUNK), at] = True     # a query sees itself
+        mask &= chosen
+    mask = jnp.asarray(mask)
+    want = longcat.mla_expanded(cfg, w, q_nope[at], q_rope[at], rows, mask)
+    got = jax.jit(lambda *a: longcat.mla_expanded_tiled(cfg, w, *a))(
+        q_nope[at], q_rope[at], rows, mask, jnp.int32(offset + length))
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all()
+    tol = 1e-5 if dtype == "float32" \
+        else 2.0 ** -8 * np.abs(want[:length]).max()
+    np.testing.assert_allclose(got[:length], want[:length], atol=tol)
+
+
+def test_tiled_chunk_form_visits_only_the_reachable_tiles(monkeypatch):
+    """Rows past the reach change nothing, and a reach of none gives 0:
+    the loop's trip count is the traced ``reach`` over the tile. A query
+    that sees nothing in the visited tiles gets a finite 0, where the
+    finite -1e30 of a masked score would give each of its keys a
+    weight of 1."""
+    monkeypatch.setattr(longcat, "KEY_TILE", TILE)
+    cfg = longcat.config_from_dict(TOY, 5)
+    w = longcat.init_params(cfg)["blocks"][0]["mla"][1]
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (POOL_T, cfg.hidden_size)), jnp.float32)
+    q_nope, q_rope, rows = longcat.mla_project(
+        cfg, w, x, jnp.arange(POOL_T), cfg.pool_width)
+    mask = jnp.ones((CHUNK, POOL_T), bool).at[0].set(False)
+    run = jax.jit(lambda r, n: longcat.mla_expanded_tiled(
+        cfg, w, q_nope[:CHUNK], q_rope[:CHUNK], r, mask, n))
+    garbage = rows.at[2 * TILE:].set(1e4)
+    np.testing.assert_array_equal(np.asarray(run(rows, jnp.int32(13))),
+                                  np.asarray(run(garbage, jnp.int32(13))))
+    assert np.abs(np.asarray(run(garbage, jnp.int32(17)))
+                  - np.asarray(run(rows, jnp.int32(17)))).max() > 1.0
+    np.testing.assert_array_equal(np.asarray(run(rows, jnp.int32(0))), 0.0)
+    np.testing.assert_array_equal(np.asarray(run(rows, jnp.int32(40)))[0],
+                                  0.0)
 
 
 # -- the expert layer -----------------------------------------------------------

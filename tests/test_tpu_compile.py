@@ -509,6 +509,19 @@ def test_latent_cell_programs_fit_the_chip(one_chip, monkeypatch, cell,
             assert view not in text, view
     else:       # three grouped products an expert layer, nothing else
         assert _kernel_calls(compiled) == 3 * n_expert_layers
+        _assert_key_tiles(text, cfg, t["prefill_token_budget"], T)
+
+
+def _assert_key_tiles(text, cfg, chunk, t_logical):
+    """A latent model's chunk attends a key tile at a time
+    (``longcat.mla_expanded_tiled``): its float32 scores are ``[heads,
+    chunk, KEY_TILE]`` in a loop, never ``[heads, chunk, t_logical]``."""
+    from multiverso_tpu.models import longcat
+
+    H = cfg.num_attention_heads
+    assert f"f32[{H},{chunk},{t_logical}]" not in text
+    assert f"f32[{H},{chunk},{longcat.KEY_TILE}]" in text
+    assert " while(" in text
 
 
 def _assert_held_experts(text, program, cfg, t):
@@ -625,6 +638,7 @@ def test_ling_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
         assert f"f32[{S},32,128,128]" not in text   # no slab of the pool
     else:       # three grouped products an expert layer, nothing else
         assert _kernel_calls(compiled) == 3 * cfg.n_expert_layers == 18
+        _assert_key_tiles(text, cfg, C, T)
 
 
 @pytest.mark.parametrize("program,temp_mb", [("step", 50), ("chunk", 700)])
@@ -712,7 +726,7 @@ def test_mellum_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
         assert _kernel_calls(compiled) == 3 * cfg.num_hidden_layers == 24
 
 
-@pytest.mark.parametrize("program,temp_mb", [("step", 100), ("chunk", 1400)])
+@pytest.mark.parametrize("program,temp_mb", [("step", 100), ("chunk", 200)])
 def test_glm5_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
                                          temp_mb):
     """The two programs of ``serve_glm5_ep16_closed16`` (``glm5-ep16.json``
@@ -720,11 +734,12 @@ def test_glm5_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
     pool of 5 latent rows and one row of index keys a token, 16 slots of
     8,704 positions in 64-row blocks): weights and pool are arguments,
     the pool is aliased and never copied, arguments plus temporaries stay
-    inside the chip (47 MB and 1.25 GB of temporaries on the compile: the
-    chunk's masked attention holds ``[64, 512, 8704]`` float32 scores).
-    The step runs one ``dsa_index_scores`` Mosaic call a layer and no
-    other kernel, gathers no ``[slots, T, 640]`` view, and attends over
-    the ``[slots, 2048, 640]`` rows its selection gathered. The held
+    inside the chip (47 MB and 149 MB of temporaries on the compile; the
+    chunk's 1.25 GB until its attention ran over key tiles, when it held
+    ``[64, 512, 8704]`` float32 scores, which it no longer forms). The
+    step runs one ``dsa_index_scores`` Mosaic call a layer and no other
+    kernel, gathers no ``[slots, T, 640]`` view, and attends over the
+    ``[slots, 2048, 640]`` rows its selection gathered. The held
     experts: see :func:`_assert_held_experts`."""
     import json
 
@@ -794,6 +809,7 @@ def test_glm5_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
         assert f"= bf16[{S},{cfg.index_topk},640]" in text
     else:       # three grouped products an expert layer, nothing else
         assert _kernel_calls(compiled) == 3 * cfg.n_expert_layers == 12
+        _assert_key_tiles(text, cfg, C, T)
 
 
 _COLLECTIVES = ("all-reduce(", "all-gather(", "all-to-all(",
