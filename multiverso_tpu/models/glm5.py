@@ -251,10 +251,16 @@ def index_location(cfg: GLM5Config, l: int) -> Tuple[int, int]:
 
 def write_index_keys(cfg: GLM5Config, pool, l: int, keys, block, offset):
     """Layer ``l``'s index keys [n, dI] written at ``(block, offset)``
-    [n] of the pool."""
+    [n] of the pool. The write is a read-modify-write of whole rows:
+    the rows are gathered, the layer's lanes set, and the rows scattered
+    back, which XLA:TPU lowers as one scatter; a scatter of the lane
+    window alone it expands into a loop of one-row updates of the whole
+    pool. Other layers' lanes are read back and kept; rows that coincide
+    (pad rows and dead slots, all in scratch block 0) are never read."""
     sub, lane = index_location(cfg, l)
-    return pool.at[sub, block, offset,
-                   lane:lane + cfg.index_head_dim].set(keys)
+    rows = pool[sub, block, offset]
+    rows = jax.lax.dynamic_update_slice_in_dim(rows, keys, lane, axis=1)
+    return pool.at[sub, block, offset].set(rows)
 
 
 def index_keys(cfg: GLM5Config, pool, l: int, tables, t: int):

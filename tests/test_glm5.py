@@ -8,7 +8,8 @@ hit among them whose index keys come with the copied block; the decode
 step's selected positions against the reference's, and the positions
 the served programs' probe reports (the cell's check); the layer at
 contexts within the top-k equal to dense MLA; the indexer's kernel
-(interpreted) against its gathered form; the exact top-k mask against
+(interpreted) against its gathered form; the index keys' whole-row
+write against a write of their lanes alone; the exact top-k mask against
 ``jax.lax.top_k``; the one-group router; the shares of an expert layer;
 and what the model refuses.
 
@@ -317,6 +318,48 @@ def test_index_kernel_is_the_gathered_form(Bs, lane):
         assert np.isneginf(got[s, n:]).all()
         np.testing.assert_allclose(got[s, :n], want[:n], rtol=1e-5,
                                    atol=1e-4)
+
+
+@pytest.mark.parametrize("l", range(5))
+def test_write_index_keys_sets_only_its_lanes(l):
+    """The cell's layout (``glm5-ep16.json``: five layers' 128-wide keys
+    side by side in one 640-wide row after the five latent rows): layers
+    0..``l`` write their keys in turn to the same rows, pad rows among
+    them sent to scratch row (0, 0) four times, jitted as the programs
+    run it. Bitwise, each layer's lanes of the named rows hold its keys,
+    every other lane and row is as it was, and everything outside the
+    scratch row is what the lane-window write of NumPy leaves."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm5-ep16.json")) as fh:
+        cfg = glm5.config_from_dict(json.load(fh), 1)
+    dI = cfg.index_head_dim
+    rng = np.random.default_rng(l)
+    pool0 = jnp.asarray(rng.standard_normal((cfg.n_sublayers, 9, 4,
+                                             cfg.pool_width)), jnp.bfloat16)
+    live = rng.permutation(8 * 4)[:8]
+    block = np.insert(1 + live // 4, [2, 5, 5, 8], 0).astype(np.int32)
+    offset = np.insert(live % 4, [2, 5, 5, 8], 0).astype(np.int32)
+    pool, want = pool0, np.asarray(pool0).copy()
+    for j in range(l + 1):
+        keys = jnp.asarray(rng.standard_normal((block.size, dI)),
+                           jnp.bfloat16)
+        pool = jax.jit(lambda p, k, b, o, j=j: glm5.write_index_keys(
+            cfg, p, j, k, b, o))(pool, keys, block, offset)
+        sub, lane = glm5.index_location(cfg, j)
+        assert sub == cfg.num_hidden_layers and lane == j * dI
+        for i in range(block.size):
+            want[sub, block[i], offset[i], lane:lane + dI] = keys[i]
+    bits = lambda a: np.asarray(a).view(np.uint16)
+    got, before = bits(pool), bits(pool0)
+    np.testing.assert_array_equal(got[:, 1:], bits(want)[:, 1:])
+    np.testing.assert_array_equal(got[:, 0, 1:], before[:, 0, 1:])
+    np.testing.assert_array_equal(got[:-1, 0, 0], before[:-1, 0, 0])
+    np.testing.assert_array_equal(got[-1, 0, 0, (l + 1) * dI:],
+                                  before[-1, 0, 0, (l + 1) * dI:])
 
 
 def test_top_k_mask_is_lax_top_k():

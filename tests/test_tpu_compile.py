@@ -726,6 +726,19 @@ def test_mellum_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
         assert _kernel_calls(compiled) == 3 * cfg.num_hidden_layers == 24
 
 
+def _assert_pool_rows_scattered(text, pool, writes):
+    """Every write of the pool (type ``pool``) is one native scatter of
+    whole rows, ``writes`` of them: no ``dynamic-update-slice`` of the
+    whole pool, and no ``while`` that carries it: XLA:TPU expands a
+    scatter whose window is a lane slice of a row into such a loop of
+    one-row updates (the form ``glm5.write_index_keys`` avoids)."""
+    ty = re.escape(pool)
+    assert not re.search(rf"= {ty}\S* dynamic-update-slice\(", text)
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert not [ln for ln in loops if pool in ln.split(" while(")[0]]
+    assert len(re.findall(rf"= {ty}\S* scatter\(", text)) == writes
+
+
 @pytest.mark.parametrize("program,temp_mb", [("step", 100), ("chunk", 200)])
 def test_glm5_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
                                          temp_mb):
@@ -733,7 +746,9 @@ def test_glm5_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
     under ``closed16_p8192_gen512.json``: 7.83 GB of weights, a 1.07 GB
     pool of 5 latent rows and one row of index keys a token, 16 slots of
     8,704 positions in 64-row blocks): weights and pool are arguments,
-    the pool is aliased and never copied, arguments plus temporaries stay
+    the pool is aliased and never copied, its ten writes (latent rows and
+    index keys, a layer each) are each one scatter of whole rows
+    (:func:`_assert_pool_rows_scattered`), arguments plus temporaries stay
     inside the chip (47 MB and 149 MB of temporaries on the compile; the
     chunk's 1.25 GB until its attention ran over key tiles, when it held
     ``[64, 512, 8704]`` float32 scores, which it no longer forms). The
@@ -799,6 +814,8 @@ def test_glm5_cell_programs_fit_the_chip(one_chip, monkeypatch, program,
     assert _fits_hbm(compiled, budget=15.75 * 2 ** 30)
     text = compiled.as_text()
     assert f"copy(bf16[6,{S * M + 1},{Bs},640]" not in text
+    _assert_pool_rows_scattered(text, f"bf16[6,{S * M + 1},{Bs},640]",
+                                2 * cfg.num_hidden_layers)
     _assert_held_experts(text, program, cfg, t)
     if program == "step":
         calls = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\([^\n]*"
